@@ -23,7 +23,7 @@ update path, aggregated onto the flat comm buckets — the measuring stick
 for ROADMAP item 4's single-pass fused update (target: 1 read / 1 write).
 
 And the **HBM-bytes metric** (``program.hbm-bytes``): every reduce
-collective (``psum``/``psum2``) gets a dtype-width-weighted wire-bytes
+collective (``psum``/``psum_invariant``) gets a dtype-width-weighted wire-bytes
 row.  A quantized all-reduce accumulates on wide lanes for exactness
 (int8 payload sums on int32, fp8 on f32 — see ``psum_compressed``), so
 the collective's own operand dtype overstates the wire: the auditor
@@ -60,10 +60,7 @@ import numpy as np
 from .. import profiler
 from .findings import Finding, Report
 
-try:  # jax >= 0.4.16 spells it jax.extend.core
-    from jax.extend import core as _jex_core
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as _jex_core
+from jax.extend import core as _jex_core
 from jax.interpreters import mlir as _mlir
 
 __all__ = [
@@ -124,10 +121,11 @@ STREAM_ONCE_PRIMS = frozenset({
     "pallas_call", "mxtpu_fused_update",
 })
 
-#: reduce collectives whose operands cross the interconnect (psum at the
-#: jax API level; psum2 is what shard_map jaxprs spell it on this jax)
+#: reduce collectives whose operands cross the interconnect (``psum`` at
+#: the jax API level and under ``check_vma=False``; a vma-checked
+#: shard_map jaxpr spells the same reduction ``psum_invariant``)
 REDUCE_COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "all_reduce", "reduce_scatter",
+    "psum", "psum_invariant", "all_reduce", "reduce_scatter",
 })
 
 _64BIT_KINDS = ("f", "i", "u", "c")

@@ -1,7 +1,8 @@
 """Compile-management: persistent program cache + bucket canonicalization.
 
-Kill the cold start.  BENCH_r05 put XLA compile time at 41-61 s per train
-program against a ~24-110 ms steady-state step; on a preemptible fleet
+Kill the cold start.  XLA takes tens of seconds to compile a train
+program whose steady-state step takes tens of milliseconds (an earlier
+round recorded 41-61 s against ~24-110 ms); on a preemptible fleet
 (PR 3's auto-resume restarts often) compilation is the dominant
 wall-clock tax, and ``BucketingModule`` multiplies it by one
 shape-specialized program per bucket.  Three levers live here:
@@ -12,10 +13,12 @@ shape-specialized program per bucket.  Three levers live here:
   :func:`program_key` (graph fingerprint, avals, shardings, donation
   set, mesh, backend, jax/jaxlib version).  A restarted trainer
   re-attaches to yesterday's programs in milliseconds.
-* :func:`enable_persistent_cache` — wires jax's own
-  ``jax_compilation_cache_dir`` (the HLO-keyed XLA cache) under the
-  same root, so even programs that bypass our keyed store (tracing
-  through plain ``jax.jit``) skip the XLA backend compile on re-run.
+* :func:`enable_persistent_cache` — turns on jax's own HLO-keyed XLA
+  cache, so even programs that bypass our keyed store (tracing through
+  plain ``jax.jit``) skip the XLA backend compile on re-run.  It lives
+  where ``JAX_COMPILATION_CACHE_DIR`` says when that is set, else under
+  the same root (``<dir>/xla``); the chip entry points (``chip_smoke.py``,
+  ``bench.py``) default it to ``<checkout>/.jax_cache``.
 * :class:`BucketPolicy` / :func:`plan_shape_buckets` — geometric
   shape-bucket canonicalization: dozens of dynamic sequence lengths
   round up into ~4-8 padded buckets, collapsing per-length programs.
@@ -26,7 +29,8 @@ shape-specialized program per bucket.  Three levers live here:
 Env knobs (see docs/env_vars.md):
 
 * ``MXNET_TPU_CACHE_DIR`` — enables the on-disk layer (and jax's
-  persistent cache under ``<dir>/xla``) at first use.
+  persistent cache under ``<dir>/xla``, unless
+  ``JAX_COMPILATION_CACHE_DIR`` already places it) at first use.
 * ``MXNET_TPU_CACHE=0`` — disables all program caching (memory too).
 * ``MXNET_TPU_CACHE_MAX_ENTRIES`` — in-process LRU capacity (default 64).
 * ``MXNET_TPU_BUCKET_POLICY`` — default bucket ladder as
@@ -50,14 +54,15 @@ import jax
 
 from .base import MXNetError
 
-__all__ = ["ProgramCache", "CacheKey", "program_key", "describe_avals",
-           "mesh_fingerprint", "get_cache", "configure",
+__all__ = ["ProgramCache", "CacheKey", "AotProgram", "program_key",
+           "describe_avals", "mesh_fingerprint", "get_cache", "configure",
            "enable_persistent_cache", "BucketPolicy", "plan_shape_buckets",
            "bucket_for", "pad_to_bucket"]
 
 _log = logging.getLogger(__name__)
 
 ENV_CACHE_DIR = "MXNET_TPU_CACHE_DIR"
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_CACHE = "MXNET_TPU_CACHE"
 ENV_CACHE_MAX_ENTRIES = "MXNET_TPU_CACHE_MAX_ENTRIES"
 ENV_BUCKET_POLICY = "MXNET_TPU_BUCKET_POLICY"
@@ -293,9 +298,14 @@ class ProgramCache:
         try:
             from jax.experimental import serialize_executable
             with open(binp, "rb") as f:
-                payload, in_tree, out_tree = pickle.load(f)
+                payload, in_tree, out_tree, device_ids = pickle.load(f)
+            # load onto the devices the program was compiled for — the
+            # default is EVERY local device, which mis-shards a program
+            # built for one chip (or a sub-mesh) of a multi-chip host
+            by_id = {d.id: d for d in jax.devices()}
             return serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             self._bump_stat("disk_errors")
             _log.warning("program cache: failed to load %s (%s) — treating "
@@ -311,7 +321,10 @@ class ProgramCache:
             from jax.experimental import serialize_executable
             payload, in_tree, out_tree = serialize_executable.serialize(
                 compiled)
-            _atomic_write(binp, pickle.dumps((payload, in_tree, out_tree)))
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            _atomic_write(binp, pickle.dumps(
+                (payload, in_tree, out_tree, device_ids)))
             import json
             meta = {"digest": key.digest, "label": label,
                     "compile_seconds": round(compile_seconds, 4),
@@ -383,6 +396,45 @@ class ProgramCache:
         return removed
 
 
+class AotProgram:
+    """An AOT-compiled executable with a jit fallback, shared by
+    :meth:`Executor.warmup` and the serving engine.
+
+    An aval mismatch (the call's shapes/dtypes differ from what the
+    program was lowered for) raises BEFORE the executable consumes
+    donated buffers, so re-dispatching through ``jit_fn`` is safe — but
+    it retraces and recompiles, so every fallback is logged and counted
+    (``compile_cache.aot_fallbacks`` plus ``stats["fallbacks"]`` when the
+    owner passes its counter): a warm path expects zero.
+    """
+
+    __slots__ = ("_compiled", "_jit_fn", "label", "_stats")
+
+    def __init__(self, compiled, jit_fn, label: str = "program",
+                 stats: Optional[Dict[str, int]] = None):
+        self._compiled = compiled
+        self._jit_fn = jit_fn
+        self.label = label
+        self._stats = stats
+
+    @property
+    def compiled(self):
+        """The ``jax.stages.Compiled`` behind this program."""
+        return self._compiled
+
+    def __call__(self, *args):
+        try:
+            return self._compiled(*args)
+        except (TypeError, ValueError) as e:
+            if self._stats is not None:
+                self._stats["fallbacks"] += 1
+            from . import telemetry
+            telemetry.counter("compile_cache.aot_fallbacks").inc()
+            _log.warning("AOT program %r does not match this call (%s); "
+                         "falling back to jit", self.label, e)
+            return self._jit_fn(*args)
+
+
 # ---------------------------------------------------------------------------
 # Global cache singleton + jax persistent-cache wiring
 # ---------------------------------------------------------------------------
@@ -438,20 +490,34 @@ def notify_lowering(label: str, traced: Any) -> None:
                                fn, label)
 
 
-def enable_persistent_cache(cache_dir: str) -> None:
-    """Point jax's own HLO-keyed compilation cache at
-    ``<cache_dir>/xla`` and drop the size/time thresholds so every
-    program persists (CPU compiles are fast but the restart still pays
-    them without this)."""
-    xla_dir = os.path.join(cache_dir, "xla")
-    os.makedirs(xla_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", xla_dir)
-    for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # knob absent on this jax version
-            pass
+def enable_persistent_cache(default_dir: str) -> str:
+    """Turn on jax's own HLO-keyed compilation cache and return the
+    directory it uses — the ONE place this codebase decides where that
+    cache lives.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: jax reads it itself
+    and nothing here (or anywhere else) assigns another directory.
+    Otherwise the cache goes to ``default_dir``, which callers must
+    derive from something stable (the cache root, the checkout) and never
+    from a temp name, pid or time — the path is part of what a later run
+    has to find again.  Size/time thresholds are dropped so every
+    program persists (a restart still pays the small ones without this).
+    """
+    cache_dir = os.environ.get(ENV_JAX_CACHE_DIR)
+    if not cache_dir:
+        cache_dir = default_dir
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
+
+
+def _wire_jax_cache(cache_root: str) -> None:
+    try:
+        enable_persistent_cache(os.path.join(cache_root, "xla"))
+    except OSError as e:
+        _log.warning("could not enable jax persistent cache: %s", e)
 
 
 def configure(cache_dir: Optional[str] = None,
@@ -460,7 +526,8 @@ def configure(cache_dir: Optional[str] = None,
               wire_jax_cache: bool = True) -> ProgramCache:
     """(Re)build the global :class:`ProgramCache`.  With ``cache_dir``
     the disk layer turns on and (unless ``wire_jax_cache=False``) jax's
-    persistent cache is pointed under the same root."""
+    persistent cache is enabled too — under the same root, or wherever
+    ``JAX_COMPILATION_CACHE_DIR`` already places it."""
     with _glock:
         cur = _global["cache"]
         cache = ProgramCache(
@@ -469,10 +536,7 @@ def configure(cache_dir: Optional[str] = None,
                          else (cur.max_entries if cur else 64)),
             enabled=(enabled if enabled is not None else True))
         if cache_dir and wire_jax_cache and cache.enabled:
-            try:
-                enable_persistent_cache(cache_dir)
-            except Exception as e:
-                _log.warning("could not enable jax persistent cache: %s", e)
+            _wire_jax_cache(cache_dir)
         _global["cache"] = cache
         return cache
 
@@ -487,11 +551,7 @@ def get_cache() -> ProgramCache:
             cache = ProgramCache(cache_dir=cache_dir if enabled else None,
                                  max_entries=max_entries, enabled=enabled)
             if enabled and cache_dir:
-                try:
-                    enable_persistent_cache(cache_dir)
-                except Exception as e:
-                    _log.warning("could not enable jax persistent cache: %s",
-                                 e)
+                _wire_jax_cache(cache_dir)
             _global["cache"] = cache
         return _global["cache"]
 

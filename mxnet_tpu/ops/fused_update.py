@@ -23,12 +23,14 @@ bias-corrected ``lr_t`` for adam, the guard verdict ``ok``) is computed
 once OUTSIDE the primitive; everything elementwise rides inside it, so
 each bucket streams through VMEM exactly once.
 
-Why a primitive and not a ``platform_dependent`` cpu/tpu branch: on the
-pinned jax (< 0.5) ``platform_dependent`` selects the branch at TRACE
-time, which would inline the jnp reference into the jaxpr on CPU and the
-static HBM-pass auditor (``analysis/program.py``) could no longer see
-the fusion boundary.  A primitive keeps one opaque eqn in the jaxpr on
-every platform and picks the lowering per backend:
+Why a primitive and not a ``platform_dependent`` cpu/tpu branch:
+``jax.lax.platform_dependent`` traces EVERY branch into the jaxpr (a
+``cond`` over ``platform_index``) and prunes only at lowering, so the
+jnp reference's elementwise chain would sit in the jaxpr on every
+platform and the static HBM-pass auditor (``analysis/program.py``),
+which reads the jaxpr, could no longer see the fusion boundary.  A
+primitive keeps one opaque eqn in the jaxpr on every platform and picks
+the lowering per backend:
 
 - default (cpu/gpu): ``mlir.lower_fun`` of the jnp reference — XLA fuses
   the elementwise chain itself, and the reference IS the bitwise spec;
@@ -53,12 +55,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .._compat import enable_x64, pallas_tpu_compiler_params
-
-try:  # jax >= 0.4.16 keeps the extension surface under jax.extend
-    from jax.extend import core as _jex_core
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as _jex_core
+from jax.extend import core as _jex_core
 from jax.interpreters import mlir as _mlir
 
 __all__ = ["fused_update", "fused_update_p", "reference_update",
@@ -302,6 +299,7 @@ def _make_kernel(*, kind, momentum, beta1, beta2, epsilon, wd,
 
 def _pallas_apply(args, params, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     kind = params["kind"]
     n_state = params["n_state"]
@@ -345,9 +343,10 @@ def _pallas_apply(args, params, interpret):
     sc_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
 
     kernel = _make_kernel(**params)
-    with enable_x64(False):  # Mosaic rejects i64 index types
+    with jax.enable_x64(False):  # Mosaic rejects i64 index types
         outs = pl.pallas_call(
             kernel,
+            name="mxtpu_fused_update",
             out_shape=[jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)
                        ] * n_out,
             grid=(rows // brows,),
@@ -356,7 +355,7 @@ def _pallas_apply(args, params, interpret):
             # w and each state operand are consumed exactly once -> alias
             # them onto the outputs so the update is in-place in HBM
             input_output_aliases={1 + k: k for k in range(n_out)},
-            compiler_params=pallas_tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(*arrays, *smalls)
@@ -375,22 +374,41 @@ def _abstract_eval(*avals, n_state, **_):
     return [avals[1]] + [avals[2 + k] for k in range(n_state)]
 
 
+def _pallas_lowering(*args, mesh, **params):
+    """TPU lowering.  A Mosaic kernel is an opaque custom call GSPMD
+    cannot partition (jax refuses to lower one outside a fully manual
+    region), so under a multi-device ``mesh`` the kernel runs inside a
+    ``shard_map``: the fused path only ever sees replicated buckets, and
+    every device applies the same update to its own copy."""
+    def apply(*a):
+        return _pallas_apply(a, params, interpret=False)
+    if mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+        apply = jax.shard_map(apply, mesh=mesh, in_specs=P(), out_specs=P(),
+                              check_vma=False)
+    return apply(*args)
+
+
+# ``mesh`` only matters to the TPU lowering; it is a primitive param (not
+# a trace-time wrapper) so the jaxpr keeps ONE opaque eqn on every mesh
 fused_update_p.def_abstract_eval(_abstract_eval)
-fused_update_p.def_impl(lambda *args, **params: _reference(*args, **params))
+fused_update_p.def_impl(
+    lambda *args, mesh, **params: _reference(*args, **params))
 
 _mlir.register_lowering(
     fused_update_p,
-    _mlir.lower_fun(_materialized_reference, multiple_results=True))
+    _mlir.lower_fun(lambda *args, mesh, **params: _materialized_reference(
+        *args, **params), multiple_results=True))
 _mlir.register_lowering(
     fused_update_p,
-    _mlir.lower_fun(lambda *args, **params: _pallas_apply(
-        args, params, interpret=False), multiple_results=True),
+    _mlir.lower_fun(_pallas_lowering, multiple_results=True),
     platform="tpu")
 
 
 def fused_update(g, w, state=(), scalars=(), *, kind, mult=None, ok=None,
                  wd_vec=None, momentum=0.0, beta1=0.0, beta2=0.0,
-                 epsilon=0.0, wd=0.0, rescale_grad=1.0, clip_gradient=None):
+                 epsilon=0.0, wd=0.0, rescale_grad=1.0, clip_gradient=None,
+                 mesh=None):
     """Bind one fused update over a flat f32 bucket.
 
     Returns ``(new_w, *new_state)``.  ``scalars`` is the kind's combined
@@ -403,6 +421,8 @@ def fused_update(g, w, state=(), scalars=(), *, kind, mult=None, ok=None,
     effective weight decay (``wd * wd_mult`` per param segment); when
     present it replaces the scalar ``wd``, and for adamw ``scalars``
     must be ``(lr_t, lr_eff)`` — the kernel forms ``lr_eff * wd_vec``.
+    ``mesh``: the mesh the enclosing jit partitions over, when it spans
+    more than one device (operands must be replicated on it).
     """
     if kind not in SUPPORTED_KINDS:
         raise ValueError(f"unsupported fused kind {kind!r}")
@@ -430,7 +450,7 @@ def fused_update(g, w, state=(), scalars=(), *, kind, mult=None, ok=None,
         clip_gradient=(None if clip_gradient is None
                        else float(clip_gradient)),
         has_mult=mult is not None, has_ok=ok is not None,
-        has_wdvec=wd_vec is not None, n_state=len(state)))
+        has_wdvec=wd_vec is not None, n_state=len(state), mesh=mesh))
 
 
 def reference_update(g, w, state=(), scalars=(), *, kind, mult=None,

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
-from .._compat import enable_x64, platform_dependent
 from .registry import OpDef, OpParam, elemwise_shape, register_op
 
 __all__ = []  # ops land in the registry
@@ -865,9 +864,10 @@ def _pallas_softmax_rows(x, block=None):
 
     # Mosaic rejects i64 index types, so trace the kernel with x64 off
     # (the package enables jax_enable_x64 globally)
-    with enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             body,
+            name="mxtpu_softmax_rows",
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             grid=(n // block,),
             in_specs=[pl.BlockSpec((block, c), lambda i: (i, 0),
@@ -888,13 +888,23 @@ def _softmax_rows(x):
     if (_DISABLE_PALLAS or x.ndim != 2 or x.shape[-1] > 16384
             or x.dtype not in (jnp.float32, jnp.bfloat16)):
         return jax.nn.softmax(x, axis=-1)
-    block = _softmax_row_block(x.shape[0], x.shape[1], x.dtype.itemsize)
-    if block is None:
+    if _softmax_row_block(x.shape[0], x.shape[1], x.dtype.itemsize) is None:
         return jax.nn.softmax(x, axis=-1)
-    return platform_dependent(
-        x,
-        cpu=lambda v: jax.nn.softmax(v, axis=-1),
-        default=lambda v: _pallas_softmax_rows(v, block=block))
+    kernel = _pallas_softmax_rows
+    from ..parallel.mesh import DATA_AXIS, current_mesh, in_manual_region
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1 and not in_manual_region():
+        # GSPMD cannot partition a Mosaic kernel (jax refuses to lower
+        # one outside a fully manual region).  Rows are independent, so
+        # every device runs the kernel on its own rows of the batch.
+        from jax.sharding import PartitionSpec as P
+        ndata = dict(mesh.shape).get(DATA_AXIS, 1)
+        spec = P(DATA_AXIS if ndata > 1 and x.shape[0] % ndata == 0
+                 else None, None)
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=spec,
+                               out_specs=spec, check_vma=False)
+    return jax.lax.platform_dependent(
+        x, cpu=lambda v: jax.nn.softmax(v, axis=-1), default=kernel)
 
 
 def _softmax_output_core(data, label, grad_scale, ignore_label, multi_output,

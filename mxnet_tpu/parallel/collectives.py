@@ -48,7 +48,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
 from .. import quant
-from .._compat import shard_map
 
 __all__ = ["allreduce_sum", "allreduce_mean", "distinct_devices",
            "psum_compressed", "count_collectives", "CollectiveStats",
@@ -247,7 +246,7 @@ def _allreduce_prog(devices, mean: bool, compression: Optional[str],
         s = psum_compressed(x, "dev", compression, block=block)
         return s / n if mean else s
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P("dev"),
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("dev"),
                              out_specs=P("dev"))), mesh
 
 

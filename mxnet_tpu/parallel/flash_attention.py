@@ -29,8 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .._compat import (enable_x64, pallas_tpu_compiler_params,
-                       platform_dependent)
 
 NEG_INF = -1e30
 
@@ -39,10 +37,7 @@ def _sds(shape, dtype, like):
     """ShapeDtypeStruct for a pallas_call output, inheriting the
     varying-manual-axes set of operand ``like`` so the kernels lower
     inside ``shard_map`` regions (ring attention) under check_vma."""
-    try:
-        vma = jax.typeof(like).vma
-    except AttributeError:
-        vma = None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -234,15 +229,16 @@ def _flash_fwd_pallas(q, k, v, causal, scale, bq, bk, interpret=False,
             pltpu.VMEM((bq, 128), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),     # accumulator
         ]
-    with enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             kern,
+            name="mxtpu_flash_fwd",
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch,
-            compiler_params=pallas_tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q, k, v)
@@ -443,15 +439,16 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, scale, bq, bk,
     nh = h if blhd else 0
     dq_scr = (pltpu.VMEM((h, bq, d), jnp.float32) if blhd
               else pltpu.VMEM((bq, d), jnp.float32))
-    with enable_x64(False):
+    with jax.enable_x64(False):
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, causal, scale, bq, bk, d, nh),
+            name="mxtpu_flash_dq",
             grid=grid_dq,
             in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
             out_specs=[qspec],
             out_shape=[dq_shape],
             scratch_shapes=[dq_scr],
-            compiler_params=pallas_tpu_compiler_params(dimension_semantics=sem),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
             interpret=interpret,
         )(q, k, v, do, lse8, delta8)[0]
 
@@ -485,12 +482,13 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, scale, bq, bk,
                         pltpu.VMEM((bk, d), jnp.float32)))
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, causal, scale, bq, bk, d, nh),
+            name="mxtpu_flash_dkdv",
             grid=grid_kv,
             in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
             out_specs=[kspec2, kspec2],
             out_shape=[dk_shape, dv_shape],
             scratch_shapes=list(kv_scr),
-            compiler_params=pallas_tpu_compiler_params(dimension_semantics=sem),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
             interpret=interpret,
         )(q, k, v, do, lse8, delta8)
     return dq, dk, dv
@@ -525,35 +523,25 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def _wrap_for_mesh(pallas_path, q, blhd=False):
     """GSPMD guard (advisor r4 medium): a ``pallas_call`` inside an
     auto-sharded (dp/tp mesh) jit is an opaque custom call XLA cannot
-    partition — it would replicate the kernel behind all-gathers.  When
+    partition — jax refuses to lower it on TPU.  When
     a default mesh is active and we are NOT already inside a manual
     (shard_map) region, wrap the kernel in shard_map over the batch
     (``data``) and head (``model``) dims so every device runs it on its
     local shard.  Attention is batch- and head-local, so this is exact."""
     from jax.sharding import PartitionSpec as P
-    from .._compat import shard_map
-    from .mesh import DATA_AXIS, MODEL_AXIS, current_mesh
+    from .mesh import (DATA_AXIS, MODEL_AXIS, current_mesh,
+                       in_manual_region)
 
-    try:
-        manual = bool(jax.typeof(q).vma)
-    except AttributeError:
-        # old jax has no varying-manual-axes on the tracer type; a
-        # shard_map region shows up as bound names in the axis env
-        try:
-            from jax._src.core import get_axis_env
-            manual = bool(get_axis_env().axis_sizes)
-        except Exception:
-            manual = False
     mesh = current_mesh()
-    if manual or mesh is None:
+    if mesh is None or in_manual_region():
         return pallas_path
     b = q.shape[0]
     h = q.shape[2] if blhd else q.shape[1]
 
     def _spec_axes(dim_index):
         # candidate axes for a dim, best first: what the operand's OWN
-        # sharding says (modern jax carries it on the tracer type), then
-        # the canonical mesh axis name for that role
+        # sharding says (carried on the tracer type), then the canonical
+        # mesh axis name for that role
         cands = []
         try:
             entry = jax.typeof(q).sharding.spec[dim_index]
@@ -574,26 +562,22 @@ def _wrap_for_mesh(pallas_path, q, blhd=False):
     baxis = _pick(b, _spec_axes(0))
     haxis = _pick(h, _spec_axes(2 if blhd else 1), used=(baxis,))
     if baxis is None and haxis is None:
-        if mesh.size > 1:
-            # a >1-device mesh with no recognizable batch/head axis:
-            # the kernel will run replicated behind all-gathers — loud
-            # hint instead of silent perf loss on nonstandard meshes
-            logging.getLogger(__name__).warning(
-                "flash_attention: active mesh %s has no axis usable to "
-                "shard batch=%d or heads=%d (canonical names %r/%r); "
-                "running the kernel unpartitioned", dict(mesh.shape), b,
-                h, DATA_AXIS, MODEL_AXIS)
-        return pallas_path
+        if mesh.size == 1:
+            return pallas_path
+        # a >1-device mesh with no recognizable batch/head axis: every
+        # device runs the whole kernel (still inside a shard_map — jax
+        # refuses to lower a Mosaic kernel GSPMD would have to
+        # partition) — loud hint instead of silent perf loss
+        logging.getLogger(__name__).warning(
+            "flash_attention: active mesh %s has no axis usable to "
+            "shard batch=%d or heads=%d (canonical names %r/%r); "
+            "running the kernel replicated on every device",
+            dict(mesh.shape), b, h, DATA_AXIS, MODEL_AXIS)
     spec = (P(baxis, None, haxis, None) if blhd
             else P(baxis, haxis, None, None))
-    try:
-        return shard_map(pallas_path, mesh=mesh,
+    return jax.shard_map(pallas_path, mesh=mesh,
                          in_specs=(spec, spec, spec), out_specs=spec,
                          check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        return shard_map(pallas_path, mesh=mesh,
-                         in_specs=(spec, spec, spec), out_specs=spec,
-                         check_rep=False)
 
 
 def flash_attention_stats(q, k, v, *, causal=False, scale=None,
@@ -633,7 +617,7 @@ def flash_attention_stats(q, k, v, *, causal=False, scale=None,
 
     if interpret:
         return pallas_path(q, k, v)
-    return platform_dependent(q, k, v,
+    return jax.lax.platform_dependent(q, k, v,
                                       cpu=ref_path, default=pallas_path)
 
 
@@ -722,7 +706,7 @@ def flash_attention_block_bwd(q, k, v, out, lse, do, *, causal=False,
 
     if interpret:
         return pallas_path(q, k, v, out, lse, do)
-    return platform_dependent(q, k, v, out, lse, do,
+    return jax.lax.platform_dependent(q, k, v, out, lse, do,
                                       cpu=ref_path, default=pallas_path)
 
 
@@ -816,5 +800,5 @@ def flash_attention(q, k, v, *, causal=False, scale=None,
     pallas_path = _wrap_for_mesh(pallas_path, q, blhd=blhd)
     if interpret:
         return pallas_path(q, k, v)
-    return platform_dependent(q, k, v,
+    return jax.lax.platform_dependent(q, k, v,
                                       cpu=ref_path, default=pallas_path)

@@ -39,6 +39,11 @@ def _env_for(role: str, num_workers: int, num_servers: int,
         "MXTPU_NUM_WORKER": str(num_workers),
         "MXTPU_NUM_SERVER": str(num_servers),
     })
+    if role != "worker":
+        # scheduler and servers only move bytes over TCP; pinned to the
+        # host platform they can never take a chip a worker needs (a
+        # chip belongs to one process at a time)
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -51,7 +56,10 @@ def launch_local(cmd: Sequence[str], num_workers: int, num_servers: int = 1,
 
     Server/scheduler processes run the SAME command: their
     ``kvstore.create('dist*')`` call becomes the blocking server loop
-    (reference ``kvstore_server._init_kvstore_server_module``).  Returns
+    (reference ``kvstore_server._init_kvstore_server_module``).  They
+    are started with ``JAX_PLATFORMS=cpu``; workers inherit the caller's
+    platform, so on a chip host run ONE worker per chip (give each its
+    chip through ``worker_env``) — two processes cannot share one.  Returns
     the max worker exit code — or, with ``return_codes=True``, the full
     per-worker exit-code list (worker index order), which elastic chaos
     harnesses need: a deliberately killed worker's nonzero code must be
@@ -121,9 +129,12 @@ def launch_ssh(cmd: Sequence[str], hosts: Sequence[str], num_workers: int,
     procs: List[Tuple[str, subprocess.Popen]] = []
 
     def spawn(host: str, role: str, extra: Optional[Dict[str, str]] = None):
+        # only the job's own variables cross ssh — plus the cpu pin of
+        # the scheduler/server roles (a worker's platform is its host's)
         env = {k: v for k, v in _env_for(
             role, num_workers, num_servers, root_uri, root_port).items()
-            if k.startswith("MXTPU_")}
+            if k.startswith("MXTPU_")
+            or (k == "JAX_PLATFORMS" and role != "worker")}
         env["MXTPU_JOB_ID"] = job_id
         env.update(extra or {})
         kv = " ".join(f"{k}={shlex.quote(v)}" for k, v in sorted(env.items()))

@@ -29,7 +29,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..base import MXNetError
 
 __all__ = ["make_mesh", "data_parallel_mesh", "current_mesh", "default_mesh",
-           "replicated", "batch_sharding", "param_sharding",
+           "in_manual_region", "replicated", "batch_sharding",
+           "param_sharding",
            "DATA_AXIS", "MODEL_AXIS", "SEQ_AXIS", "PIPE_AXIS", "EXPERT_AXIS"]
 
 DATA_AXIS = "data"
@@ -99,6 +100,15 @@ def default_mesh(mesh: Mesh):
 
 def current_mesh() -> Optional[Mesh]:
     return _mesh_stack[-1] if _mesh_stack else None
+
+
+def in_manual_region() -> bool:
+    """True while tracing inside a ``shard_map`` body.  Mosaic (Pallas
+    TPU) kernels only lower in a fully manual region — GSPMD cannot
+    partition the opaque custom call — so kernel wrappers ask this
+    before opening one of their own.  Read off the trace context, not
+    the operands: under ``check_vma=False`` no value carries a vma."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -140,7 +140,6 @@ def moe_ffn_ep(x, gate_w, w1, b1, w2, b2, mesh, k: int = 2,
     """
     from functools import partial
 
-    from .._compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     ep = mesh.shape[expert_axis]
@@ -153,7 +152,7 @@ def moe_ffn_ep(x, gate_w, w1, b1, w2, b2, mesh, k: int = 2,
     tok_spec = P(tok_axes, None)
 
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(tok_spec, P(),
                   P(expert_axis, None, None), P(expert_axis, None),
                   P(expert_axis, None, None), P(expert_axis, None)),

@@ -16,7 +16,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .._compat import shard_map
 
 from .mesh import PIPE_AXIS
 
@@ -103,13 +102,6 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh, *,
     pspec = jax.tree.map(lambda _: P(pipe_axis), stage_params)
     body = functools.partial(_pipeline_sharded, stage_fn=stage_fn,
                              axis_name=pipe_axis)
-    kw = dict(mesh=mesh, in_specs=(pspec, P()), out_specs=P())
-    try:
-        out_mb = shard_map(body, **kw)(stage_params, x_mb)
-    except Exception as e:  # pragma: no cover - jax 0.4.x rep checker
-        # old shard_map's replication checker cannot type the
-        # stage-varying cond in tick(); it asks for check_rep=False
-        if "check_rep" not in str(e):
-            raise
-        out_mb = shard_map(body, check_rep=False, **kw)(stage_params, x_mb)
+    out_mb = jax.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
+                           out_specs=P())(stage_params, x_mb)
     return out_mb.reshape((b,) + out_mb.shape[2:])

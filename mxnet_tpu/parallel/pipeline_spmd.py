@@ -62,7 +62,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from .._compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
@@ -122,12 +121,8 @@ def _shard_map(fn, mesh, in_specs, out_specs):
     """shard_map with replication checking off (the program mixes
     per-axis psum/pmean with out-specs that drop axes; correctness is
     pinned by the equivalence tests, not the vma checker)."""
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
 
 
 class _FlatSpec:

@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .._compat import shard_map
 
 from .mesh import SEQ_AXIS
 
@@ -384,7 +383,7 @@ def ring_self_attention(q, k, v, mesh: Mesh, *, seq_axis: str = SEQ_AXIS,
     b_axis = batch_axis if (batch_axis and batch_axis in mesh.axis_names) \
         else None
     spec = P(b_axis, None, seq_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis,
                           causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)

@@ -325,7 +325,8 @@ class ShardedTrainer:
         # back to the jit path on any aval mismatch (a mismatch raises
         # BEFORE donated buffers are consumed, so fallback is safe)
         self._aot: Dict[str, Any] = {}
-        self.aot_stats: Dict[str, int] = {"hits": 0, "fallbacks": 0}
+        self.aot_stats: Dict[str, int] = {"hits": 0, "fallbacks": 0,
+                                          "compile_errors": 0}
         self.compile_info: List[Dict[str, Any]] = []
 
     def _multiproc(self) -> bool:
@@ -710,7 +711,6 @@ class ShardedTrainer:
         becomes exactly the quantization error just committed
         (collectives.psum_compressed).
         """
-        from .._compat import shard_map
         from .collectives import plan_buckets, psum_compressed
         daxis = self.data_axis
         comp = self.grad_compression
@@ -800,10 +800,7 @@ class ShardedTrainer:
                           + ef_spec,
                           out_specs=(P(), P(self.data_axis), P())
                           + ef_spec)
-        try:
-            return shard_map(body, check_vma=False, **kwargs)
-        except TypeError:
-            return shard_map(body, check_rep=False, **kwargs)
+        return jax.shard_map(body, check_vma=False, **kwargs)
 
     def _compile(self):
         sym, opt = self.symbol, self.optimizer
@@ -838,8 +835,8 @@ class ShardedTrainer:
             f_clip = hyper.get("clip_gradient")
 
         # per-step RNG keys fold from the update counter INSIDE the
-        # program (no per-step host->device key transfer — each one is a
-        # round-trip on tunneled backends), and the base key is a PROGRAM
+        # program (no per-step host->device key transfer to wait on
+        # before dispatch), and the base key is a PROGRAM
         # ARGUMENT rather than a closure constant: restore_state swaps
         # ``self._base_key`` without retracing (the jit cache keys on the
         # key's shape/dtype/sharding, which _set_base_key pins), and a
@@ -949,7 +946,8 @@ class ShardedTrainer:
                         wd=wd_common, rescale_grad=self._rescale_grad,
                         clip_gradient=f_clip,
                         wd_vec=(None if wd_uniform
-                                else opt_state[f"fusedwd:{i}"]))
+                                else opt_state[f"fusedwd:{i}"]),
+                        mesh=self.mesh)
                     new_w_buckets.append(res[0])
                     new_opt[f"fused:{i}"] = jax.tree_util.tree_unflatten(
                         treedef, list(res[1:]))
@@ -1422,6 +1420,9 @@ class ShardedTrainer:
                     try:
                         compile_one(kind, b_avals)
                     except Exception:
+                        # the step still runs (through jit), so count
+                        # the failure where callers can assert on it
+                        self.aot_stats["compile_errors"] += 1
                         self.logger.exception(
                             "background AOT compile of %r failed", kind)
             th = threading.Thread(target=run, daemon=True,

@@ -172,23 +172,6 @@ class EngineConfig:
         return impl
 
 
-class _AotProgram:
-    """AOT executable with automatic jit fallback (mirrors
-    ``executor._AotProgram``)."""
-
-    __slots__ = ("_compiled", "_jit_fn")
-
-    def __init__(self, compiled, jit_fn):
-        self._compiled = compiled
-        self._jit_fn = jit_fn
-
-    def __call__(self, *args):
-        try:
-            return self._compiled(*args)
-        except (TypeError, ValueError):
-            return self._jit_fn(*args)
-
-
 def _sample_row(logits, key, temp, topk, pos):
     """Greedy / temperature / top-k sampling for one row.
 
@@ -433,7 +416,7 @@ class Engine:
             self.prompt_buckets = tuple(policy._ladder(config.max_seq_len))
         self.decode_buckets = config.resolved_decode_buckets()
         self._base_key = jax.random.PRNGKey(config.seed)
-        self._programs: Dict[Tuple[str, int], _AotProgram] = {}
+        self._programs: Dict[Tuple[str, int], cc.AotProgram] = {}
         self.trace_counts = collections.Counter()
         self.aot_stats = collections.Counter()
         self.requests: Dict[int, Request] = {}
@@ -798,7 +781,11 @@ class Engine:
             ckey, lambda: jit_fn.lower(*avals).compile(),
             label=f"serve.{kind}.{bucket}")
         self.aot_stats[info["source"]] += 1
-        self._programs[pkey] = _AotProgram(compiled, jit_fn)
+        # a fallback here means the engine built arguments its own
+        # program rejects — counted so warm-path checks can pin it at 0
+        self._programs[pkey] = cc.AotProgram(
+            compiled, jit_fn, f"serve.{kind}.{bucket}",
+            stats=self.aot_stats)
         return dict(info, kind=kind, bucket=bucket)
 
     def warmup(self) -> List[Dict[str, Any]]:

@@ -40,7 +40,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .._compat import enable_x64, pallas_tpu_compiler_params
 from ..base import MXNetError
 from ..parallel.flash_attention import NEG_INF
 from .kvcache import QuantPool, is_quantized
@@ -84,34 +83,37 @@ def _decode_kernel(*refs, bps: int, block_size: int, quantized: bool,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # Single-query attention has no matmul worth the MXU (M = 1 per
+    # head), and Mosaic only lowers dots whose batch dims lead; the
+    # [BS, H, hd] block keeps (H, hd) on (sublanes, lanes) exactly as
+    # the pool stores it, so both contractions run on the VPU: a lane
+    # reduction for q.k and a leading-dim reduction for p.v.  Every
+    # statistic stays in the keepdims "column" form ([.., H, 1]) so no
+    # value ever has to move between lanes and sublanes.
     q = q_ref[...].astype(jnp.float32)                      # [H, hd]
     k = k_ref[...].astype(jnp.float32)                      # [BS, H, hd]
     v = v_ref[...].astype(jnp.float32)
     if quantized:
-        k = k * kscale_ref[...][0][:, None, None]
-        v = v * vscale_ref[...][0][:, None, None]
+        k = k * kscale_ref[...]                             # [BS, 1, 1]
+        v = v * vscale_ref[...]
 
-    s = jnp.einsum("hd,khd->hk", q, k,
-                   preferred_element_type=jnp.float32) * scale  # [H, BS]
+    s = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale  # [BS, H, 1]
 
     # logical block index of this grid step -> absolute positions
     j = pl.program_id(1) * bps + p
     pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)                       # [1, BS]
+        jnp.int32, s.shape, 0)                               # [BS, H, 1]
     valid = pos < lengths_ref[b]
     # f32-typed constants: weak python-float literals re-materialize at
     # lowering time and can widen to f64 under an ambient x64 context.
     s = jnp.where(valid, s, np.float32(NEG_INF))
 
-    m_prev = m_ref[...]                                      # [1, H]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[None, :])
-    alpha = jnp.exp(m_prev - m_new)                          # [1, H]
-    pmat = jnp.where(valid, jnp.exp(s - jnp.transpose(m_new)),
-                     np.float32(0.0))
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(pmat, axis=-1)[None, :]
-    acc_ref[...] = (acc_ref[...] * jnp.transpose(alpha)
-                    + jnp.einsum("hk,khd->hd", pmat, v,
-                                 preferred_element_type=jnp.float32))
+    m_prev = m_ref[...]                                      # [H, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+    alpha = jnp.exp(m_prev - m_new)                          # [H, 1]
+    pmat = jnp.where(valid, jnp.exp(s - m_new[None]), np.float32(0.0))
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(pmat, axis=0)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(pmat * v, axis=0)
     m_ref[...] = m_new
 
 
@@ -163,8 +165,8 @@ def flash_decode_attention(q, k_pool, v_pool, tables, lengths, *,
 
     def scale_spec():
         return pl.BlockSpec(
-            (1, bs),
-            lambda bi, si, pi, tref, lref: (tref[bi, si * bps + pi], 0))
+            (None, bs, 1, 1),
+            lambda bi, si, pi, tref, lref: (tref[bi, si * bps + pi], 0, 0, 0))
 
     in_specs = [
         pl.BlockSpec((None, h, hd), lambda bi, si, pi, tref, lref: (bi, 0, 0)),
@@ -173,20 +175,23 @@ def flash_decode_attention(q, k_pool, v_pool, tables, lengths, *,
     operands = [q, kp, vp]
     if quantized:
         in_specs += [scale_spec(), scale_spec()]
-        operands += [k_pool.scale, v_pool.scale]
+        # one scale per cached position, shaped to broadcast against the
+        # [BS, H, hd] payload block with BS on the untiled leading dim
+        operands += [k_pool.scale[:, :, None, None],
+                     v_pool.scale[:, :, None, None]]
 
     out_specs = [
         pl.BlockSpec((None, None, h, hd),
                      lambda bi, si, pi, tref, lref: (bi, si, 0, 0)),
-        pl.BlockSpec((None, None, 1, h),
+        pl.BlockSpec((None, None, h, 1),
                      lambda bi, si, pi, tref, lref: (bi, si, 0, 0)),
-        pl.BlockSpec((None, None, 1, h),
+        pl.BlockSpec((None, None, h, 1),
                      lambda bi, si, pi, tref, lref: (bi, si, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, splits, h, hd), jnp.float32),
-        jax.ShapeDtypeStruct((b, splits, 1, h), jnp.float32),
-        jax.ShapeDtypeStruct((b, splits, 1, h), jnp.float32),
+        jax.ShapeDtypeStruct((b, splits, h, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, splits, h, 1), jnp.float32),
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -195,12 +200,13 @@ def flash_decode_attention(q, k_pool, v_pool, tables, lengths, *,
         in_specs=in_specs,
         out_specs=out_specs,
     )
-    with enable_x64(False):
+    with jax.enable_x64(False):
         acc, m, l = pl.pallas_call(
             kernel,
+            name="mxtpu_flash_decode",
             grid_spec=grid_spec,
             out_shape=out_shape,
-            compiler_params=pallas_tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
@@ -208,8 +214,8 @@ def flash_decode_attention(q, k_pool, v_pool, tables, lengths, *,
     # split-K combine: reweight each partition's partial by its distance
     # to the global running max, then one normalized sum.  Empty
     # partitions carry (m=NEG_INF, l=0, acc=0) and contribute nothing.
-    m = m[:, :, 0]                                   # [B, S, H]
-    l = l[:, :, 0]
+    m = m[..., 0]                                    # [B, S, H]
+    l = l[..., 0]
     m_star = jnp.max(m, axis=1)                      # [B, H]
     w = jnp.exp(m - m_star[:, None, :])              # [B, S, H]
     l_star = jnp.maximum(jnp.sum(l * w, axis=1), 1e-30)

@@ -11,24 +11,11 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-def _force_cpu_devices(n):
-    """2 virtual CPU devices before first backend use, on any jax: the
-    config flag where it exists, XLA_FLAGS (replacing any inherited
-    device-count flag, e.g. the test harness's =8) where it doesn't."""
-    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-             if not f.startswith("--xla_force_host_platform_device_count")]
-    flags.append(f"--xla_force_host_platform_device_count={n}")
-    os.environ["XLA_FLAGS"] = " ".join(flags)
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:  # old jax: XLA_FLAGS alone does the job
-        pass
-    return jax
+import jax
 
-
-jax = _force_cpu_devices(2)
+# 2 virtual CPU devices, set before first backend use
+jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_num_cpu_devices", 2)
 
 import numpy as np
 

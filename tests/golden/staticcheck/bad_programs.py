@@ -105,15 +105,14 @@ def hbm_bytes_widened():
     """The r9 regression class: a trainer configured for quantized grad
     reduction whose bucket silently re-widened — the psum payload is
     full-width f32, so every step moves 4x the contracted wire bytes."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = _data_mesh()
 
     def step(g):
         def body(gl):
             return jax.lax.psum(gl, "data")     # f32 on the wire
-        return shard_map(body, mesh=mesh, in_specs=P("data"),
-                         out_specs=P())(g)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                             out_specs=P())(g)
     n = 512 * len(jax.devices())
     tr = jax.jit(step).trace(_SDS((n,), jnp.float32))
     return tr, {"expect_wire_itemsize": 1}
@@ -124,7 +123,6 @@ def hbm_bytes_quantized():
     the block-quantized fp8 reduction, so the narrowest same-shape value
     in the psum's cone is the 1-byte payload and the audit stays
     silent."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.parallel.collectives import psum_compressed
     mesh = _data_mesh()
@@ -132,8 +130,8 @@ def hbm_bytes_quantized():
     def step(g):
         def body(gl):
             return psum_compressed(gl, "data", "fp8")
-        return shard_map(body, mesh=mesh, in_specs=P("data"),
-                         out_specs=P())(g)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                             out_specs=P())(g)
     n = 512 * len(jax.devices())
     tr = jax.jit(step).trace(_SDS((n,), jnp.float32))
     return tr, {"expect_wire_itemsize": 1}

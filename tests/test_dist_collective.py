@@ -10,8 +10,6 @@ import socket
 import subprocess
 import sys
 
-import pytest
-
 
 def _free_port():
     s = socket.socket()
@@ -22,9 +20,6 @@ def _free_port():
 
 
 def test_two_process_collective_trainer():
-    import jax
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        pytest.skip("jax<0.5 CPU backend has no multiprocess collectives")
     here = os.path.dirname(os.path.abspath(__file__))
     worker = os.path.join(here, "dist_collective_worker.py")
     port = _free_port()

@@ -14,6 +14,16 @@ import mxnet_tpu as mx
 from mxnet_tpu.parallel.launch import launch_local
 
 
+def test_only_workers_may_reach_the_chip(monkeypatch):
+    """One process per chip: scheduler and server roles are pinned to the
+    host platform; a worker keeps whatever platform its launcher has."""
+    from mxnet_tpu.parallel.launch import _env_for
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    for role in ("scheduler", "server"):
+        assert _env_for(role, 2, 1, "127.0.0.1", 9091)["JAX_PLATFORMS"] == "cpu"
+    assert _env_for("worker", 2, 1, "127.0.0.1", 9091)["JAX_PLATFORMS"] == "tpu"
+
+
 def test_dist_kvstore_requires_cluster_env(monkeypatch):
     for v in ("MXTPU_ROLE", "DMLC_ROLE"):
         monkeypatch.delenv(v, raising=False)

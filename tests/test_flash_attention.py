@@ -167,7 +167,6 @@ def test_flash_under_manual_region_not_double_wrapped():
     manual axes — the GSPMD wrap must not re-enter shard_map."""
     import functools
     from jax.sharding import PartitionSpec as P
-    from mxnet_tpu._compat import shard_map
     from mxnet_tpu.parallel import make_mesh
     from mxnet_tpu.parallel.mesh import default_mesh
 
@@ -183,14 +182,9 @@ def test_flash_under_manual_region_not_double_wrapped():
                                block_k=64, interpret=True)
 
     with default_mesh(mesh):
-        try:
-            fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
                            out_specs=spec)
-            out = jax.jit(fn)(q, k, v)
-        except NotImplementedError:  # old jax: no pallas replication rule
-            fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                           out_specs=spec, check_rep=False)
-            out = jax.jit(fn)(q, k, v)
+        out = jax.jit(fn)(q, k, v)
     ref = local_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)

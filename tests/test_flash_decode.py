@@ -18,6 +18,7 @@ tests pin the kernel's numerics — not a Python re-implementation:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from mxnet_tpu import telemetry
@@ -116,6 +117,27 @@ def test_flash_rejects_mixed_pools():
     with pytest.raises(MXNetError):
         flash_decode_attention(q, _quantize(kp), vp, tables, lengths,
                                interpret=True)
+
+
+@pytest.mark.parametrize("pool", ["f32", "bf16", "fp8"])
+def test_kernel_lowers_for_tpu(pool):
+    """Mosaic must accept the kernel at serving shapes — cross-lowered
+    here, no chip needed.  The head-batched dots this kernel once used
+    passed every interpret-mode test and could not lower at all, which
+    ``attn_impl="auto"`` (-> flash on TPU) turns into an engine that
+    cannot warm up."""
+    sds = jax.ShapeDtypeStruct
+    b, h, hd, bs, nb, nblk = 16, 8, 64, 16, 256, 128
+    if pool == "fp8":
+        kv = kvcache.QuantPool(sds((nb, bs, h, hd), jnp.float8_e4m3fn),
+                       sds((nb, bs), jnp.float32))
+    else:
+        kv = sds((nb, bs, h, hd), jnp.dtype(
+            {"f32": "float32", "bf16": "bfloat16"}[pool]))
+    text = jax.jit(flash_decode_attention).trace(
+        sds((b, h, hd), jnp.float32), kv, kv, sds((b, nblk), jnp.int32),
+        sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
 
 
 def test_default_split_k():

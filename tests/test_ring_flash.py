@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mxnet_tpu._compat import shard_map
 
 from mxnet_tpu.parallel.ring_attention import (_ring_flash,
                                                local_attention,
@@ -31,7 +30,7 @@ def _mk(b=2, h=2, l=256, d=32, seed=0):
 
 def _ring_fn(mesh, sp, causal):
     spec = P(None, None, "seq", None)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(ring_attention, axis_name="seq", causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
 

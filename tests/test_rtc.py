@@ -4,12 +4,12 @@ Parity model: reference ``tests/python/gpu/test_rtc.py`` (compile a tiny
 kernel from Python, launch on device data, check the result).
 """
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu.context import _accel_platform
 
 
 def test_pallas_kernel_push():
@@ -46,13 +46,37 @@ def test_softmax_rows_platform_branch():
                                np.asarray(jax.nn.softmax(x, -1)), atol=1e-6)
 
 
+def test_softmax_rows_lowers_for_tpu_under_a_mesh():
+    """Under a multi-device mesh the kernel branch runs per shard inside
+    a ``shard_map`` — GSPMD cannot partition a Mosaic kernel, and jax
+    refuses to lower one that is not wrapped (cross-lowered, no chip)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops.nn_ops import _softmax_rows
+    from mxnet_tpu.parallel import make_mesh
+    from mxnet_tpu.parallel.mesh import default_mesh
+    mesh = make_mesh({"data": 4, "model": 2})
+    x = jax.ShapeDtypeStruct((256, 1000), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data", None)))
+    with default_mesh(mesh):
+        traced = jax.jit(_softmax_rows).trace(x)
+        assert "tpu_custom_call" in traced.lower(
+            lowering_platforms=("tpu",)).as_text()
+
+        # already inside a manual region: no second shard_map
+        def body(v):
+            return _softmax_rows(v)
+        inner = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=P("data", None),
+            out_specs=P("data", None), check_vma=False)).trace(x)
+    assert str(traced.jaxpr).count("shard_map") == 1
+    assert str(inner.jaxpr).count("shard_map") == 1
+
+
+@pytest.mark.tpu
 def test_pallas_softmax_on_accelerator():
-    """The bespoke kernel runs natively on the chip when one is present."""
-    import pytest
-    if _accel_platform() is None:
-        pytest.skip("no accelerator attached")
+    """The bespoke kernel runs natively on the chip."""
     from mxnet_tpu.ops.nn_ops import _pallas_softmax_rows
-    dev = jax.devices(_accel_platform())[0]
+    dev = jax.devices("tpu")[0]
     x = jax.device_put(
         np.random.RandomState(1).randn(640, 100).astype(np.float32), dev)
     y = jax.jit(_pallas_softmax_rows)(x)
