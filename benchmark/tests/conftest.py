@@ -1,0 +1,10 @@
+"""Tests of the benchmark itself: ``python -m pytest benchmark/tests -q``
+from the root of the repo.  They run on the CPU; nothing here measures."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
