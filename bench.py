@@ -14,10 +14,6 @@ Default run prints THREE JSON lines and the driver parses the LAST:
    same epoch-time-equivalent ratio (the reference has no ResNet-50
    ImageNet table).
 
-``--profile-step`` additionally emits a per-phase step-overhead
-attribution (host pre-step / dispatch / device compute / fetch) for each
-benched network — see docs/perf.md "step overhead attribution".
-
 The CIFAR-10 inception-bn-28-small headline (842 img/s on 1x GTX 980,
 BASELINE.md row 1) runs via --network inception-bn-28-small.
 
@@ -193,14 +189,8 @@ def _emit_row(rec):
 def report(metric, value, unit, vs_baseline, per_step, dispatch, compile_s,
            flops, precision):
     import jax
-    from mxnet_tpu import telemetry
     peak = _peak_flops()
     tflops = (flops / per_step / 1e12) if flops else None
-    if flops:
-        # feed the derived-gauge denominators (derived.mfu /
-        # derived.flops_per_s) for any steps run after this report
-        telemetry.set_program_costs(flops_per_step=flops,
-                                    peak_flops_per_s=peak or None)
     rec = {
         "metric": metric,
         "value": round(value, 1),
@@ -217,19 +207,6 @@ def report(metric, value, unit, vs_baseline, per_step, dispatch, compile_s,
     print(json.dumps(rec))
     _tee(rec)
     return rec
-
-
-def _emit_step_profile(trainer, host_feeds, steps, title):
-    """--profile-step: per-phase attribution table (human) + one JSON line
-    (machine; tools/parse_log.py --diff-profile consumes these)."""
-    from mxnet_tpu import profiler
-    prof = profiler.profile_step(trainer, host_feeds, steps=steps)
-    print(profiler.format_step_profile(prof, title))
-    row = {"step_profile": {k: round(v, 4) for k, v in prof.items()},
-           "metric": title}
-    _emit_row(row)
-    _tee(row)
-    return prof
 
 
 def _make_trainer(sym, precision, compute_dtype, optimizer="sgd",
@@ -347,9 +324,6 @@ def bench_image(args, network=None, image_shape=None, batch=None,
         for _ in range(2)]
     feeds = [trainer.place_batch(f) for f in host_feeds]
     per_step, dispatch, compile_s, flops = measure(trainer, feeds, args.steps)
-    if getattr(args, "profile_step", False):
-        _emit_step_profile(trainer, host_feeds, args.steps,
-                           f"{network} batch {batch}")
     img_s = batch / per_step
     if network == "inception-bn-28-small":
         vs = round(img_s / BASELINE_IMG_S, 3)
@@ -417,9 +391,6 @@ def bench_lm(args, batch=None, seq_len=None, head_loss=None):
     per_step, dispatch, compile_s, _ = measure(trainer, feeds, args.steps,
                                                with_flops=False)
     flops = _step_flops(trainer, feeds[0], flops_symbol=dense_sym)
-    if getattr(args, "profile_step", False):
-        _emit_step_profile(trainer, host_feeds, args.steps,
-                           f"transformer-lm seq{l} batch {b}")
     tok_s = b * l / per_step
     prec = args.compute_dtype or args.precision
     return report(
@@ -676,13 +647,6 @@ def bench_audit(args):
             elapsed = time.perf_counter() - t0
             hbm = report.metrics.get("trainer.train", {}).get("hbm_passes", {})
             buckets = hbm.get("buckets", [])
-            if buckets and hbm.get("max_reads") is not None:
-                # grad-bucket HBM traffic per step from the auditor's own
-                # byte counts -> derived.hbm_gbps denominator
-                from mxnet_tpu import telemetry
-                telemetry.set_program_costs(
-                    hbm_bytes_per_step=sum(b["bytes"] for b in buckets)
-                    * (hbm["max_reads"] + (hbm.get("max_writes") or 0)))
             label = "fused" if fused else "unfused"
             passed = bool(report.clean) and (
                 not fused or (hbm.get("max_reads") == 1
@@ -1999,10 +1963,6 @@ def main():
                     choices=("none", "int8", "bf16", "fp8"),
                     help="quantized gradient all-reduce wire format "
                     "(dp meshes; see docs/perf.md gradient communication)")
-    ap.add_argument("--profile-step", action="store_true",
-                    help="per-phase step-overhead attribution (host "
-                    "pre-step / dispatch / device compute / fetch) for "
-                    "each benched network; see docs/perf.md")
     ap.add_argument("--checkpoint", action="store_true",
                     help="bench checkpoint step-loop stall: no-save "
                     "baseline vs sync vs async save_state (see "

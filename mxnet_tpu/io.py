@@ -21,6 +21,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from . import telemetry
 from .base import MXNetError
 from .context import Context
 from .ndarray import NDArray, array as nd_array
@@ -509,7 +510,6 @@ class DevicePrefetchIter(DataIter):
                         raise
 
         def worker():
-            from . import telemetry
             telemetry.name_thread("prefetch")
             n = 0
             try:
@@ -572,7 +572,10 @@ class DevicePrefetchIter(DataIter):
     def next(self):
         if self._thread is None:
             self._start()
-        kind, staged, source = self._queue.get()
+        # the consumer's side of the queue: what the training loop
+        # waited (``prefetch.batch`` is the producer's, on its thread)
+        with telemetry.span("prefetch.wait"):
+            kind, staged, source = self._queue.get()
         if kind == "end":
             # keep the sentinel so repeated next() keeps raising
             self._queue.put(DevicePrefetchIter._END)

@@ -178,32 +178,41 @@ def lm_config_from_params(params):
     return int(embed.shape[0]), n, int(embed.shape[1])
 
 
+def _embed(params, tokens):
+    with jax.named_scope("embed"):
+        return jnp.take(_param(params, "embed_weight"),
+                        tokens.astype(jnp.int32), axis=0)
+
+
 def _block_step(params, i, h, attend):
     """One transformer block on hidden states ``h`` ([..., d]) where
     ``attend(q, k, v)`` maps per-head states [..., H, hd] -> attention
-    output of the same shape (the caller owns the KV story)."""
-    d = h.shape[-1]
+    output of the same shape (the caller owns the KV story).  The parts
+    are ``jax.named_scope``s, so a device trace names them."""
 
     def p(suffix):
         return _param(params, f"layer{i}_{suffix}")
 
-    hn = _lnm(h, p("ln1_gamma"), p("ln1_beta"))
-    q, k, v = (_fcm(hn, p(f"{nm}_weight"), p(f"{nm}_bias"))
-               for nm in ("q", "k", "v"))
+    with jax.named_scope("qkv"):
+        hn = _lnm(h, p("ln1_gamma"), p("ln1_beta"))
+        q, k, v = (_fcm(hn, p(f"{nm}_weight"), p(f"{nm}_bias"))
+                   for nm in ("q", "k", "v"))
     att = attend(q, k, v)
-    att = _fcm(att, p("proj_weight"), p("proj_bias"))
-    h = h + att
-    hn = _lnm(h, p("ln2_gamma"), p("ln2_beta"))
-    f = _fcm(hn, p("ffn1_weight"), p("ffn1_bias"))
-    f = jnp.maximum(f, 0)
-    return h + _fcm(f, p("ffn2_weight"), p("ffn2_bias"))
+    with jax.named_scope("proj"):
+        h = h + _fcm(att, p("proj_weight"), p("proj_bias"))
+    with jax.named_scope("ffn"):
+        hn = _lnm(h, p("ln2_gamma"), p("ln2_beta"))
+        f = _fcm(hn, p("ffn1_weight"), p("ffn1_bias"))
+        f = jnp.maximum(f, 0)
+        return h + _fcm(f, p("ffn2_weight"), p("ffn2_bias"))
 
 
 def _lm_head(params, h):
-    h = _lnm(h, _param(params, "final_ln_gamma"),
-             _param(params, "final_ln_beta"))
-    return _fcm(h, _param(params, "lm_head_weight"),
-                _param(params, "lm_head_bias"))
+    with jax.named_scope("lm_head"):
+        h = _lnm(h, _param(params, "final_ln_gamma"),
+                 _param(params, "final_ln_beta"))
+        return _fcm(h, _param(params, "lm_head_weight"),
+                    _param(params, "lm_head_bias"))
 
 
 def transformer_lm_prefill(params, tokens, *, heads):
@@ -222,17 +231,17 @@ def transformer_lm_prefill(params, tokens, *, heads):
         raise MXNetError(f"d_model {d} not divisible by heads {heads}")
     hd = d // heads
     b, l = tokens.shape
-    h = jnp.take(_param(params, "embed_weight"),
-                 tokens.astype(jnp.int32), axis=0)
+    h = _embed(params, tokens)
     ks, vs = [], []
 
     def attend(q, k, v):
         q, k, v = (t.reshape(b, l, heads, hd) for t in (q, k, v))
         ks.append(k)
         vs.append(v)
-        qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        out = local_attention(qt, kt, vt, causal=True, block_size=None)
-        return out.transpose(0, 2, 1, 3).reshape(b, l, d)
+        with jax.named_scope("attn"):
+            qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            out = local_attention(qt, kt, vt, causal=True, block_size=None)
+            return out.transpose(0, 2, 1, 3).reshape(b, l, d)
 
     for i in range(num_layers):
         h = _block_step(params, i, h, attend)
@@ -260,8 +269,7 @@ def transformer_lm_prefill_chunk(params, tokens, *, heads, attend):
         raise MXNetError(f"d_model {d} not divisible by heads {heads}")
     hd = d // heads
     b, c = tokens.shape
-    h = jnp.take(_param(params, "embed_weight"),
-                 tokens.astype(jnp.int32), axis=0)
+    h = _embed(params, tokens)
 
     def make_attend(i):
         def _attend(q, k, v):
@@ -297,8 +305,7 @@ def transformer_lm_verify(params, tokens, *, heads, attend):
         raise MXNetError(f"d_model {d} not divisible by heads {heads}")
     hd = d // heads
     b, c = tokens.shape
-    h = jnp.take(_param(params, "embed_weight"),
-                 tokens.astype(jnp.int32), axis=0)
+    h = _embed(params, tokens)
 
     def make_attend(i):
         def _attend(q, k, v):
@@ -327,8 +334,7 @@ def transformer_lm_decode(params, tokens, *, heads, attend):
     vocab, num_layers, d = lm_config_from_params(params)
     hd = d // heads
     b = tokens.shape[0]
-    h = jnp.take(_param(params, "embed_weight"),
-                 tokens.astype(jnp.int32), axis=0)
+    h = _embed(params, tokens)
 
     def make_attend(i):
         def _attend(q, k, v):

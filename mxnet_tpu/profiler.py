@@ -9,41 +9,19 @@ device compute, HLO ops, and host activity.
     mx.profiler.start("/tmp/profile")
     ... training steps ...
     mx.profiler.stop()
-The second half of this module is a lightweight **step-phase profiler**
-(:func:`profile_step`) that attributes one training step's wall time to
-the phases the framework controls:
-
-* ``place_ms``  — host time to build + dispatch the sharded ``device_put``
-  for a batch (hidden by :class:`~mxnet_tpu.io.DevicePrefetchIter`),
-* ``dispatch_ms`` — host time for ``trainer.step`` to *return* on a
-  pre-placed batch (trace/lower excluded; this is the Python+jax dispatch
-  overhead per step),
-* ``device_ms`` — pure device compute per step, measured with the
-  two-point slope method from ``docs/perf.md`` (run N then 3N steps, each
-  closed by one forced fetch; the slope cancels the constant cost of the
-  closing fetch and the pipelined dispatch ramp),
-* ``fetch_ms`` — one device→host scalar fetch on an idle device (the
-  per-readback round trip a per-batch metric would pay).
-
-``host_gap_ms = max(0, place_ms + dispatch_ms - device_ms)`` is the part
-of host work that CANNOT hide under device compute — the framework
-overhead a step actually pays.  Exposed via ``bench.py --profile-step``.
 """
 from __future__ import annotations
 
 import contextlib
 import threading
-import time
-from typing import Callable, Dict, List, Optional
-
-import numpy as np
+from typing import Dict, List, Optional
 
 import jax
 
 from .base import MXNetError
 
-__all__ = ["start", "stop", "trace", "annotate", "profile_step",
-           "format_step_profile", "record_compile", "compile_events",
+__all__ = ["start", "stop", "trace", "annotate",
+           "record_compile", "compile_events",
            "reset_compile_events", "format_compile_report",
            "bump", "counter", "counters", "reset_counters"]
 
@@ -221,106 +199,3 @@ def counters(prefix: str = "") -> Dict[str, int]:
 def reset_counters(prefix: str = "") -> None:
     from . import telemetry
     telemetry.registry().reset(prefix, kinds=("counter",))
-
-
-# ---------------------------------------------------------------------------
-# Step-phase profiler
-# ---------------------------------------------------------------------------
-
-def _fetch(heads) -> None:
-    """Force one tiny device→host transfer (closes the async pipeline)."""
-    h = heads[0] if isinstance(heads, (list, tuple)) else heads
-    np.asarray(h[(0,) * h.ndim])
-
-
-def _device_slope_ms(run_steps: Callable[[int], None], base_steps: int,
-                     repeats: int = 3) -> float:
-    """Two-point-slope device time per step (docs/perf.md): time N and 3N
-    steps, each closed by one forced fetch; ``(t2-t1)/2N`` cancels the
-    constant cost of the closing fetch and the pipelined dispatch ramp.
-    Lower median of ``repeats`` slopes."""
-    slopes = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        run_steps(base_steps)
-        t1 = time.perf_counter()
-        run_steps(3 * base_steps)
-        t2 = time.perf_counter()
-        slopes.append(((t2 - t1) - (t1 - t0)) / (2 * base_steps))
-    slopes.sort()
-    return slopes[(len(slopes) - 1) // 2] * 1e3
-
-
-def profile_step(trainer, host_feeds: List[dict], steps: int = 10,
-                 repeats: int = 3) -> Dict[str, float]:
-    """Attribute one training step's wall time to framework phases.
-
-    ``host_feeds``: a few *host* batch dicts ({input name: numpy array},
-    static shapes) — kept on host so the place phase measures the real
-    ``device_put`` dispatch cost.  Returns a dict with per-phase
-    milliseconds plus the derived ``host_gap_ms`` (host work that cannot
-    hide under device compute) and ``step_ms`` (slope-measured total).
-    """
-    feeds = [dict(f) for f in host_feeds]
-    placed = [dict(trainer.place_batch(f)) for f in feeds]
-
-    # warm up: compile + one full step closed by a fetch
-    _fetch(trainer.step(placed[0]))
-
-    # host pre-step: build + dispatch the sharded device_put for a batch
-    t0 = time.perf_counter()
-    for i in range(steps):
-        trainer.place_batch(dict(feeds[i % len(feeds)]))
-    place_ms = (time.perf_counter() - t0) / steps * 1e3
-
-    # dispatch: step() return time on pre-placed feeds (async — this is
-    # the host-side per-step framework cost, not device compute)
-    t0 = time.perf_counter()
-    for i in range(steps):
-        heads = trainer.step(placed[i % len(placed)])
-    dispatch_ms = (time.perf_counter() - t0) / steps * 1e3
-    _fetch(heads)  # drain before the slope phase
-
-    def run_steps(n: int) -> None:
-        h = None
-        for i in range(n):
-            h = trainer.step(placed[i % len(placed)])
-        _fetch(h)
-
-    device_ms = _device_slope_ms(run_steps, steps, repeats)
-
-    # fetch: device idle (run_steps ended with a fetch) — time the pure
-    # device→host scalar round trip
-    heads = trainer.step(placed[0])
-    _fetch(heads)
-    t0 = time.perf_counter()
-    for _ in range(max(3, repeats)):
-        _fetch(heads)
-    fetch_ms = (time.perf_counter() - t0) / max(3, repeats) * 1e3
-
-    return {
-        "place_ms": place_ms,
-        "dispatch_ms": dispatch_ms,
-        "device_ms": device_ms,
-        "fetch_ms": fetch_ms,
-        "host_gap_ms": max(0.0, place_ms + dispatch_ms - device_ms),
-        "step_ms": device_ms + max(0.0, place_ms + dispatch_ms - device_ms),
-    }
-
-
-def format_step_profile(prof: Dict[str, float], title: str = "step") -> str:
-    """Render a profile dict as the per-phase attribution table."""
-    rows = [
-        ("host pre-step (place_batch)", prof["place_ms"]),
-        ("dispatch (step() return)", prof["dispatch_ms"]),
-        ("device compute (slope)", prof["device_ms"]),
-        ("fetch (device->host RTT)", prof["fetch_ms"]),
-        ("host gap (unhidden host work)", prof["host_gap_ms"]),
-        ("effective step", prof["step_ms"]),
-    ]
-    width = max(len(r[0]) for r in rows)
-    lines = [f"step-phase profile [{title}]",
-             f"{'phase'.ljust(width)}   ms/step"]
-    for name, ms in rows:
-        lines.append(f"{name.ljust(width)}   {ms:8.3f}")
-    return "\n".join(lines)

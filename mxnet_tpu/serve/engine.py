@@ -556,8 +556,8 @@ class Engine:
     def _make_prefill_fn(self, lb: int):
         heads, nl = self.heads, self.num_layers
 
-        def fn(kpool, vpool, params, tokens, length, table_row, key,
-               temp, topk):
+        def fn_prefill(kpool, vpool, params, tokens, length, table_row,
+                       key, temp, topk):
             self.trace_counts[f"prefill@{lb}"] += 1
             logits, ks, vs = transformer_lm_prefill(params, tokens,
                                                     heads=heads)
@@ -566,12 +566,13 @@ class Engine:
                                               table_row, length)
                 vpool = kvcache.write_prefill(vpool, i, vs[i][0],
                                               table_row, length)
-            last = jnp.take(logits[0], length - 1, axis=0)
-            tok = _sample_row(last, key, temp, topk, length)
-            ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
+            with jax.named_scope("sample"):
+                last = jnp.take(logits[0], length - 1, axis=0)
+                tok = _sample_row(last, key, temp, topk, length)
+                ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
             return kpool, vpool, tok, ok
 
-        return fn
+        return fn_prefill
 
     def _make_chunk_prefill_fn(self, cb: int):
         """Chunked prefill: ingest one [1, cb] slice of a prompt at
@@ -582,8 +583,8 @@ class Engine:
         heads, nl = self.heads, self.num_layers
         from ..models.transformer import transformer_lm_prefill_chunk
 
-        def fn(kpool, vpool, params, tokens, start, length, table_row,
-               key, temp, topk):
+        def fn_prefill_chunk(kpool, vpool, params, tokens, start, length,
+                             table_row, key, temp, topk):
             self.trace_counts[f"prefill_chunk@{cb}"] += 1
             pools = [kpool, vpool]
 
@@ -606,19 +607,21 @@ class Engine:
             logits = transformer_lm_prefill_chunk(params, tokens,
                                                   heads=heads,
                                                   attend=attend)
-            last = jnp.take(logits[0],
-                            jnp.clip(length - 1 - start, 0, cb - 1), axis=0)
-            tok = _sample_row(last, key, temp, topk, length)
-            ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
+            with jax.named_scope("sample"):
+                last = jnp.take(logits[0],
+                                jnp.clip(length - 1 - start, 0, cb - 1),
+                                axis=0)
+                tok = _sample_row(last, key, temp, topk, length)
+                ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
             return pools[0], pools[1], tok, ok
 
-        return fn
+        return fn_prefill_chunk
 
     def _make_decode_fn(self, bb: int):
         heads, impl = self.heads, self.attn_impl
 
-        def fn(kpool, vpool, params, tokens, tables, lengths, slots,
-               offsets, active, keys, temps, topks):
+        def fn_decode(kpool, vpool, params, tokens, tables, lengths, slots,
+                      offsets, active, keys, temps, topks):
             self.trace_counts[f"decode@{bb}"] += 1
             pools = [kpool, vpool]
 
@@ -634,11 +637,14 @@ class Engine:
 
             logits = transformer_lm_decode(params, tokens, heads=heads,
                                            attend=attend)
-            toks = _sample_batch(logits, keys, temps, topks, lengths + 1)
-            oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)), axis=-1)
+            with jax.named_scope("sample"):
+                toks = _sample_batch(logits, keys, temps, topks,
+                                     lengths + 1)
+                oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
+                              axis=-1)
             return pools[0], pools[1], toks, oks
 
-        return fn
+        return fn_decode
 
     def _make_verify_fn(self, bb: int):
         """The speculative step program: write the window's K/V, score
@@ -652,8 +658,8 @@ class Engine:
         bsz = self.config.block_size
         mb = self.max_blocks
 
-        def fn(kpool, vpool, params, tokens, tables, lengths, live,
-               active, keys, temps, topks):
+        def fn_verify(kpool, vpool, params, tokens, tables, lengths, live,
+                      active, keys, temps, topks):
             self.trace_counts[f"verify@{bb}"] += 1
             pools = [kpool, vpool]
             win = jnp.arange(c)[None, :]
@@ -673,8 +679,9 @@ class Engine:
 
             logits = transformer_lm_verify(params, tokens, heads=heads,
                                            attend=attend)
-            out, nem = _spec_accept(logits, tokens, live, keys,
-                                    temps, topks, lengths)
+            with jax.named_scope("sample"):
+                out, nem = _spec_accept(logits, tokens, live, keys,
+                                        temps, topks, lengths)
             # cursor rollback: the block cursor truncates to the last
             # accepted draft, and the rejected tail's K/V is scrubbed
             # in-graph (kept positions redirect to the trash block)
@@ -685,11 +692,12 @@ class Engine:
             # finite guard over the window positions acceptance read
             # (dead positions attend over unwritten garbage by design)
             livemask = win <= live[:, None]
-            oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32))
-                          | ~livemask[:, :, None], axis=(1, 2))
+            with jax.named_scope("sample"):
+                oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32))
+                              | ~livemask[:, :, None], axis=(1, 2))
             return pools[0], pools[1], out, nem, oks
 
-        return fn
+        return fn_verify
 
     def _make_draft_fn(self, bb: int):
         """The model drafter's program: K-step greedy unroll of the
@@ -699,7 +707,7 @@ class Engine:
         k = self.spec_k
         heads, w = self.spec.heads, self.spec.window
 
-        def fn(dparams, window, ctx_len):
+        def fn_draft(dparams, window, ctx_len):
             self.trace_counts[f"draft@{bb}"] += 1
             toks, ln = window, ctx_len
             outs = []
@@ -713,7 +721,7 @@ class Engine:
                 ln = jnp.minimum(ln + 1, w)
             return jnp.stack(outs, axis=1)
 
-        return fn
+        return fn_draft
 
     def _run_draft_program(self, win: np.ndarray, lens: np.ndarray):
         """Runner bound into the ModelDrafter: pad to the decode
@@ -971,35 +979,39 @@ class Engine:
             self._chaos_fire()
             if self._hung:
                 return
-        now = time.monotonic()
-        for req in list(self.sched.running):
-            if req.cancel_requested:
-                self._finish(req, "cancelled", CANCELLED)
-        for req in list(self.sched.running) + list(self.sched.queue):
-            if (req.deadline_ms is not None
-                    and (now - req.submit_t) * 1e3 > req.deadline_ms):
-                telemetry.counter("serve.timeouts").inc()
-                self._finish(req, "timeout", FAILED)
-        with telemetry.span("serve.admit", step=self.step_idx,
-                            queued=self.sched.queue_depth):
-            admitted = self.sched.admit(
-                self._admission_gate(), now,
-                prefill_backlog_ms=self._prefill_backlog_ms(),
-                decode_backlog_ms=self._decode_backlog_ms())
-        if self.prefill_chunk:
-            for req in admitted:
-                self._prefill_begin(req)
-            self._prefill_pump()
-        else:
-            for req in admitted:
-                self._prefill(req)
-        if self.sched.running:
-            self._decode_step()
-        self.publish_load_gauges()
-        telemetry.flight_recorder().record({
-            "kind": "serve", "step": self.step_idx,
-            "active": self.sched.active, "queued": self.sched.queue_depth,
-            "blocks_used": self.alloc.num_used})
+        with telemetry.span("serve.step", step=self.step_idx,
+                            queued=self.sched.queue_depth) as step_span:
+            now = time.monotonic()
+            for req in list(self.sched.running):
+                if req.cancel_requested:
+                    self._finish(req, "cancelled", CANCELLED)
+            for req in list(self.sched.running) + list(self.sched.queue):
+                if (req.deadline_ms is not None
+                        and (now - req.submit_t) * 1e3 > req.deadline_ms):
+                    telemetry.counter("serve.timeouts").inc()
+                    self._finish(req, "timeout", FAILED)
+            with telemetry.span("serve.admit", step=self.step_idx,
+                                queued=self.sched.queue_depth):
+                admitted = self.sched.admit(
+                    self._admission_gate(), now,
+                    prefill_backlog_ms=self._prefill_backlog_ms(),
+                    decode_backlog_ms=self._decode_backlog_ms())
+            if self.prefill_chunk:
+                for req in admitted:
+                    self._prefill_begin(req)
+                chunk = self._prefill_pump()
+            else:
+                for req in admitted:
+                    self._prefill(req)
+                chunk = bool(admitted)
+            rows = self._decode_step() if self.sched.running else 0
+            self.publish_load_gauges()
+            telemetry.flight_recorder().record({
+                "kind": "serve", "step": self.step_idx,
+                "active": self.sched.active,
+                "queued": self.sched.queue_depth,
+                "blocks_used": self.alloc.num_used})
+            step_span.annotate(rows=rows, chunk=int(chunk))
         self.beat += 1
 
     def publish_load_gauges(self) -> None:
@@ -1191,24 +1203,29 @@ class Engine:
         req.blocks = self.alloc.alloc(nblocks, req.id)
         lb = cc.bucket_for(plen, self.prompt_buckets)
         self._ensure_program("prefill", lb)
-        padded = np.zeros((1, lb), np.int32)
-        padded[0, :plen] = toks
-        table_row = np.zeros((self.max_blocks,), np.int32)
-        table_row[:len(req.blocks)] = req.blocks
         t0 = time.monotonic()
         with telemetry.span("serve.prefill", req=req.id, bucket=lb,
                             prompt=plen):
-            self.kpool, self.vpool, tok, ok = (
-                self._programs[("prefill", lb)](
-                    self.kpool, self.vpool, self._step_params(), padded,
-                    np.int32(plen), table_row, req.key,
-                    np.float32(req.temperature), np.int32(req.top_k)))
+            with telemetry.span("serve.build"):
+                padded = np.zeros((1, lb), np.int32)
+                padded[0, :plen] = toks
+                table_row = np.zeros((self.max_blocks,), np.int32)
+                table_row[:len(req.blocks)] = req.blocks
+            with telemetry.span("serve.dispatch", kind="prefill",
+                                bucket=lb):
+                self.kpool, self.vpool, tok, ok = (
+                    self._programs[("prefill", lb)](
+                        self.kpool, self.vpool, self._step_params(),
+                        padded, np.int32(plen), table_row, req.key,
+                        np.float32(req.temperature), np.int32(req.top_k)))
+            with telemetry.span("serve.fetch"):
+                tok, ok = jax.device_get((tok, ok))
         req.cached = plen
         req.prefilled = req.prefill_target = plen
         telemetry.counter("serve.prefills").inc()
         telemetry.histogram("serve.prefill_ms").observe(
             (time.monotonic() - t0) * 1e3)
-        if not bool(ok):
+        if not ok:
             self._fail_nan(req)
             return
         self._append_token(req, int(tok))
@@ -1247,17 +1264,19 @@ class Engine:
         the p99 ITL contract protects — measured in docs/perf.md r12.)
         When nothing can decode yet (engine start, or every slot
         mid-prefill) the pump keeps going until one request completes,
-        since there is no decode to stall.
+        since there is no decode to stall.  Returns whether a chunk ran.
         """
+        ran = False
         while True:
             pending = [r for r in self.sched.running
                        if r.prefilled < r.prefill_target]
             if not pending:
-                return
+                return ran
             self._prefill_chunk_step(pending[0])
+            ran = True
             if any(r.prefilled >= r.prefill_target
                    for r in self.sched.running):
-                return
+                return ran
 
     def _prefill_chunk_step(self, req: Request) -> None:
         cb = self.prefill_chunk
@@ -1267,19 +1286,27 @@ class Engine:
         plen = req.prefill_target
         toks = req.seed_tokens[start:start + cb]
         self._ensure_program("prefill_chunk", cb)
-        padded = np.zeros((1, cb), np.int32)
-        padded[0, :len(toks)] = toks
-        table_row = np.zeros((self.max_blocks,), np.int32)
-        table_row[:len(req.blocks)] = req.blocks
         t0 = time.monotonic()
         with telemetry.span("serve.prefill", req=req.id, bucket=cb,
                             prompt=plen, chunk_start=start,
                             chunk_budget=cb):
-            self.kpool, self.vpool, tok, ok = (
-                self._programs[("prefill_chunk", cb)](
-                    self.kpool, self.vpool, self._step_params(), padded,
-                    np.int32(start), np.int32(plen), table_row, req.key,
-                    np.float32(req.temperature), np.int32(req.top_k)))
+            with telemetry.span("serve.build"):
+                padded = np.zeros((1, cb), np.int32)
+                padded[0, :len(toks)] = toks
+                table_row = np.zeros((self.max_blocks,), np.int32)
+                table_row[:len(req.blocks)] = req.blocks
+            with telemetry.span("serve.dispatch", kind="prefill_chunk",
+                                bucket=cb):
+                self.kpool, self.vpool, tok, ok = (
+                    self._programs[("prefill_chunk", cb)](
+                        self.kpool, self.vpool, self._step_params(),
+                        padded, np.int32(start), np.int32(plen), table_row,
+                        req.key, np.float32(req.temperature),
+                        np.int32(req.top_k)))
+            with telemetry.span("serve.fetch"):
+                # one read of both, and the clock below stops after it:
+                # the chunk's real time, not its dispatch
+                tok, ok = jax.device_get((tok, ok))
         ms = (time.monotonic() - t0) * 1e3
         self._chunk_ms = (ms if self._chunk_ms == 0.0
                           else 0.8 * self._chunk_ms + 0.2 * ms)
@@ -1287,7 +1314,7 @@ class Engine:
         req.cached = req.prefilled
         telemetry.counter("serve.prefill_chunks").inc()
         telemetry.histogram("serve.prefill_ms").observe(ms)
-        if not bool(ok):
+        if not ok:
             # mid-chunk NaN already contaminated this request's cached
             # K/V — fail now rather than stream garbage at the end
             self._fail_nan(req)
@@ -1346,10 +1373,11 @@ class Engine:
         victim.published = 0
         self.sched.requeue(victim)
 
-    def _decode_step(self) -> None:
+    def _decode_step(self) -> int:
+        """One batched decode step; returns the decode-ready rows it
+        carried (0: every running request is still mid-prefill)."""
         if self.spec is not None:
-            self._verify_step()
-            return
+            return self._verify_step()
         # growth pass first: a preemption inside _grow_blocks mutates
         # sched.running, so the batch roster is only read afterwards
         # (a preempted victim must not decode on freed blocks).
@@ -1363,50 +1391,55 @@ class Engine:
         active = [r for r in self.sched.running
                   if r.prefilled >= r.prefill_target]
         if not active:
-            return
+            return 0
         bb = cc.bucket_for(len(active), self.decode_buckets)
         self._ensure_program("decode", bb)
-        bsz = self.alloc.block_size
-        tokens = np.zeros((bb,), np.int32)
-        tables = np.zeros((bb, self.max_blocks), np.int32)
-        lengths = np.zeros((bb,), np.int32)
-        slots = np.zeros((bb,), np.int32)
-        offsets = np.zeros((bb,), np.int32)
-        active_m = np.zeros((bb,), np.bool_)
-        keys = np.zeros((bb, 2), np.uint32)
-        temps = np.zeros((bb,), np.float32)
-        topks = np.zeros((bb,), np.int32)
-        for i, req in enumerate(active):
-            tokens[i] = req.tokens[-1]
-            tables[i, :len(req.blocks)] = req.blocks
-            lengths[i] = req.cached
-            slots[i] = req.blocks[req.cached // bsz]
-            offsets[i] = req.cached % bsz
-            active_m[i] = True
-            keys[i] = req.key
-            temps[i] = req.temperature
-            topks[i] = req.top_k
-        t0 = time.monotonic()
         with telemetry.span("serve.decode", step=self.step_idx, bucket=bb,
                             active=len(active)):
-            self.kpool, self.vpool, toks, oks = (
-                self._programs[("decode", bb)](
-                    self.kpool, self.vpool, self._step_params(), tokens,
-                    tables, lengths, slots, offsets, active_m, keys,
-                    temps, topks))
-        toks = np.asarray(toks)
-        oks = np.asarray(oks)
-        step_ms = (time.monotonic() - t0) * 1e3
-        hist = telemetry.histogram("serve.token_ms")
-        for i, req in enumerate(active):
-            req.cached += 1
-            if not bool(oks[i]):
-                self._fail_nan(req)
-                continue
-            hist.observe(step_ms)
-            self._append_token(req, int(toks[i]))
+            with telemetry.span("serve.build"):
+                bsz = self.alloc.block_size
+                tokens = np.zeros((bb,), np.int32)
+                tables = np.zeros((bb, self.max_blocks), np.int32)
+                lengths = np.zeros((bb,), np.int32)
+                slots = np.zeros((bb,), np.int32)
+                offsets = np.zeros((bb,), np.int32)
+                active_m = np.zeros((bb,), np.bool_)
+                keys = np.zeros((bb, 2), np.uint32)
+                temps = np.zeros((bb,), np.float32)
+                topks = np.zeros((bb,), np.int32)
+                for i, req in enumerate(active):
+                    tokens[i] = req.tokens[-1]
+                    tables[i, :len(req.blocks)] = req.blocks
+                    lengths[i] = req.cached
+                    slots[i] = req.blocks[req.cached // bsz]
+                    offsets[i] = req.cached % bsz
+                    active_m[i] = True
+                    keys[i] = req.key
+                    temps[i] = req.temperature
+                    topks[i] = req.top_k
+            t0 = time.monotonic()
+            with telemetry.span("serve.dispatch", kind="decode", bucket=bb):
+                self.kpool, self.vpool, toks, oks = (
+                    self._programs[("decode", bb)](
+                        self.kpool, self.vpool, self._step_params(), tokens,
+                        tables, lengths, slots, offsets, active_m, keys,
+                        temps, topks))
+            with telemetry.span("serve.fetch"):
+                toks = np.asarray(toks)
+                oks = np.asarray(oks)
+            step_ms = (time.monotonic() - t0) * 1e3
+            hist = telemetry.histogram("serve.token_ms")
+            with telemetry.span("serve.emit"):
+                for i, req in enumerate(active):
+                    req.cached += 1
+                    if not bool(oks[i]):
+                        self._fail_nan(req)
+                        continue
+                    hist.observe(step_ms)
+                    self._append_token(req, int(toks[i]))
+        return len(active)
 
-    def _verify_step(self) -> None:
+    def _verify_step(self) -> int:
         """The speculative replacement for :meth:`_decode_step`: draft
         K tokens per row, verify all of them (plus the bonus position)
         in ONE fixed-shape program, emit ``1..K+1`` tokens per row.
@@ -1434,68 +1467,74 @@ class Engine:
         active = [r for r in self.sched.running
                   if r.prefilled >= r.prefill_target]
         if not active:
-            return
+            return 0
         bb = cc.bucket_for(len(active), self.decode_buckets)
         self._ensure_program("verify", bb)
+        # drafting (a device program of its own with the model drafter)
+        # is the step's, not the verify program's: outside serve.decode
         drafts = np.asarray(
             self.spec.propose([r.seed_tokens for r in active], k),
             np.int32)
-        # drafter hygiene: a wrong draft is wasted width, an
-        # out-of-range id would be an invalid embedding lookup
-        drafts = np.clip(drafts, 0, self.vocab - 1)
-        tokens = np.zeros((bb, c), np.int32)
-        tables = np.zeros((bb, self.max_blocks), np.int32)
-        lengths = np.zeros((bb,), np.int32)
-        live_v = np.zeros((bb,), np.int32)
-        active_m = np.zeros((bb,), np.bool_)
-        keys = np.zeros((bb, 2), np.uint32)
-        temps = np.zeros((bb,), np.float32)
-        topks = np.zeros((bb,), np.int32)
-        for i, req in enumerate(active):
-            tokens[i, 0] = req.tokens[-1]
-            tokens[i, 1:] = drafts[i]
-            tables[i, :len(req.blocks)] = req.blocks
-            lengths[i] = req.cached
-            live_v[i] = req.spec_live
-            active_m[i] = True
-            keys[i] = req.key
-            temps[i] = req.temperature
-            topks[i] = req.top_k
-        t0 = time.monotonic()
         with telemetry.span("serve.decode", step=self.step_idx, bucket=bb,
                             active=len(active), spec_k=k):
-            self.kpool, self.vpool, out, nem, oks = (
-                self._programs[("verify", bb)](
-                    self.kpool, self.vpool, self._step_params(), tokens,
-                    tables, lengths, live_v, active_m, keys, temps,
-                    topks))
-        out = np.asarray(out)
-        nem = np.asarray(nem)
-        oks = np.asarray(oks)
-        step_ms = (time.monotonic() - t0) * 1e3
-        self._decode_ms = (step_ms if self._decode_ms == 0.0
-                           else 0.8 * self._decode_ms + 0.2 * step_ms)
-        hist = telemetry.histogram("serve.token_ms")
-        drafted = int(np.sum(live_v[:len(active)]))
-        accepted = 0
-        emitted = 0
-        for i, req in enumerate(active):
-            n = int(nem[i])
-            req.cached += n          # cursor: +accepted drafts +1
-            if not bool(oks[i]):
-                self._fail_nan(req)
-                continue
-            accepted += n - 1
-            for j in range(n):
-                # multi-token burst: the step's latency lands on the
-                # first token; later burst tokens arrive back-to-back
-                # (that IS their inter-token latency — satellite of
-                # BENCH_r15, keeps p99 ITL honest)
-                hist.observe(step_ms if j == 0 else 0.0)
-                emitted += 1
-                self._append_token(req, int(out[i, j]))
-                if req.done():
-                    break
+            with telemetry.span("serve.build"):
+                # drafter hygiene: a wrong draft is wasted width, an
+                # out-of-range id would be an invalid embedding lookup
+                drafts = np.clip(drafts, 0, self.vocab - 1)
+                tokens = np.zeros((bb, c), np.int32)
+                tables = np.zeros((bb, self.max_blocks), np.int32)
+                lengths = np.zeros((bb,), np.int32)
+                live_v = np.zeros((bb,), np.int32)
+                active_m = np.zeros((bb,), np.bool_)
+                keys = np.zeros((bb, 2), np.uint32)
+                temps = np.zeros((bb,), np.float32)
+                topks = np.zeros((bb,), np.int32)
+                for i, req in enumerate(active):
+                    tokens[i, 0] = req.tokens[-1]
+                    tokens[i, 1:] = drafts[i]
+                    tables[i, :len(req.blocks)] = req.blocks
+                    lengths[i] = req.cached
+                    live_v[i] = req.spec_live
+                    active_m[i] = True
+                    keys[i] = req.key
+                    temps[i] = req.temperature
+                    topks[i] = req.top_k
+            t0 = time.monotonic()
+            with telemetry.span("serve.dispatch", kind="verify", bucket=bb):
+                self.kpool, self.vpool, out, nem, oks = (
+                    self._programs[("verify", bb)](
+                        self.kpool, self.vpool, self._step_params(), tokens,
+                        tables, lengths, live_v, active_m, keys, temps,
+                        topks))
+            with telemetry.span("serve.fetch"):
+                out = np.asarray(out)
+                nem = np.asarray(nem)
+                oks = np.asarray(oks)
+            step_ms = (time.monotonic() - t0) * 1e3
+            self._decode_ms = (step_ms if self._decode_ms == 0.0
+                               else 0.8 * self._decode_ms + 0.2 * step_ms)
+            hist = telemetry.histogram("serve.token_ms")
+            drafted = int(np.sum(live_v[:len(active)]))
+            accepted = 0
+            emitted = 0
+            with telemetry.span("serve.emit"):
+                for i, req in enumerate(active):
+                    n = int(nem[i])
+                    req.cached += n          # cursor: +accepted drafts +1
+                    if not bool(oks[i]):
+                        self._fail_nan(req)
+                        continue
+                    accepted += n - 1
+                    for j in range(n):
+                        # multi-token burst: the step's latency lands on
+                        # the first token; later burst tokens arrive
+                        # back-to-back (that IS their inter-token latency
+                        # — satellite of BENCH_r15, keeps p99 ITL honest)
+                        hist.observe(step_ms if j == 0 else 0.0)
+                        emitted += 1
+                        self._append_token(req, int(out[i, j]))
+                        if req.done():
+                            break
         self._tps = 0.8 * self._tps + 0.2 * (emitted / max(len(active), 1))
         self._spec_drafted += drafted
         self._spec_accepted += accepted
@@ -1507,6 +1546,7 @@ class Engine:
         if self._spec_drafted:
             telemetry.gauge("serve.spec.accept_rate").set(
                 self._spec_accepted / self._spec_drafted)
+        return len(active)
 
     def _decode_backlog_ms(self) -> float:
         """Expected wait until a decode slot frees, credited to queued

@@ -44,6 +44,7 @@ identical shapes.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from collections import OrderedDict
 from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
@@ -98,10 +99,25 @@ class QuantPool(NamedTuple):
 Pool = Union[jax.Array, QuantPool]
 
 
+def _scoped(name: str):
+    """Run the decorated helper under ``jax.named_scope(name)``, so the
+    device trace's op metadata says which part of a serving program an
+    instruction belongs to.  A fresh scope object per call: the one
+    ``jax.named_scope`` returns keeps state and is not re-entrant."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return deco
+
+
 def is_quantized(pool) -> bool:
     return isinstance(pool, QuantPool)
 
 
+@_scoped("pool_read")
 def layer_view(pool: Pool, layer: int) -> Pool:
     """One layer's slice of a pool, preserving quantization structure:
     ``[num_blocks, BS, H, hd]`` (array) or the matching ``QuantPool``
@@ -536,6 +552,7 @@ def _attend_blocks(q, read_block, nblk: int, block_size: int, lengths,
     return (acc / l[..., None]).astype(q.dtype)
 
 
+@_scoped("attn")
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
                     scale: Optional[float] = None, impl: str = "scan"):
     """One-token-per-request attention over a paged cache.
@@ -600,6 +617,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     return _attend_blocks(q, read_block, nblk, bs, lengths, scale_)
 
 
+@_scoped("attn")
 def paged_prefill_attention(q, k_pool, v_pool, table_row, start, length, *,
                             scale: Optional[float] = None):
     """Causal attention for one **prefill chunk** over a paged cache.
@@ -636,6 +654,7 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start, length, *,
     return (out / l[..., None]).astype(q.dtype)
 
 
+@_scoped("attn")
 def paged_verify_attention(q, k_pool, v_pool, tables, lengths, *,
                            scale: Optional[float] = None):
     """Causal attention for one **speculative verify** step: C query
@@ -700,6 +719,7 @@ def dense_attention(q, k_buf, v_buf, lengths, *, block_size: int,
     return _attend_blocks(q, read_block, nblk, block_size, lengths, scale_)
 
 
+@_scoped("kv_write")
 def write_prefill(pool, layer: int, states, table_row, length, start=0):
     """Scatter a prompt's (or prompt chunk's) K or V states into its
     table's slots.
@@ -731,6 +751,7 @@ def write_prefill(pool, layer: int, states, table_row, length, start=0):
     return pool.at[layer, slot, off].set(states)
 
 
+@_scoped("kv_write")
 def write_decode(pool, layer: int, states, slots, offsets, active):
     """Scatter one decode step's K or V states, one position per row.
 
@@ -746,6 +767,7 @@ def write_decode(pool, layer: int, states, slots, offsets, active):
     return pool.at[layer, slot, offsets].set(states)
 
 
+@_scoped("kv_write")
 def write_spec(pool, layer: int, states, slots, offsets):
     """Scatter one speculative-verify window's K or V states: C
     positions per row.

@@ -42,13 +42,12 @@ __all__ = ["Registry", "Metric", "JsonlEmitter", "delta",
            "histogram", "scrape", "snapshot_flat", "span", "annotate",
            "name_thread", "trace_enabled", "export_trace",
            "validate_trace", "emit", "flush_metrics", "record_step",
-           "dump_flight", "flight_recorder", "set_program_costs",
+           "dump_flight", "flight_recorder",
            "configure", "reset_for_tests", "tracing"]
 
 _registry = Registry()
 _flight = _flight_mod.FlightRecorder()
 _emitter: Optional[JsonlEmitter] = None
-_costs: Dict[str, float] = {}   # program flops / hbm bytes / peak flops
 _ready = False
 _init_lock = threading.Lock()
 _atexit_armed = False
@@ -144,7 +143,6 @@ def reset_for_tests() -> None:
         _flight._ring.clear()
         _flight.dump_count = 0
     _flight.dump_dir = None
-    _costs.clear()
     tracing.configure(None)
     tracing.clear()
     _emitter = None
@@ -233,50 +231,18 @@ def flight_recorder() -> _flight_mod.FlightRecorder:
     return _flight
 
 
-def set_program_costs(flops_per_step: Optional[float] = None,
-                      hbm_bytes_per_step: Optional[float] = None,
-                      peak_flops_per_s: Optional[float] = None) -> None:
-    """Install the static per-step program costs the derived gauges
-    divide by step time: auditor HBM byte counts -> ``derived.hbm_gbps``,
-    ``cost_analysis`` flops (+ device peak) -> ``derived.mfu``.
-    ``bench.py`` calls this from its audit/measure paths; anything that
-    knows its program's costs may too."""
-    g = _registry.gauge
-    if flops_per_step is not None:
-        _costs["flops"] = float(flops_per_step)
-        g("program.flops_per_step").set(flops_per_step)
-    if hbm_bytes_per_step is not None:
-        _costs["hbm_bytes"] = float(hbm_bytes_per_step)
-        g("program.hbm_bytes_per_step").set(hbm_bytes_per_step)
-    if peak_flops_per_s is not None:
-        _costs["peak"] = float(peak_flops_per_s)
-        g("program.peak_flops_per_s").set(peak_flops_per_s)
-
-
 def record_step(rec: Dict[str, Any]) -> None:
     """Per-step hook (called by ``ShardedTrainer.fit`` every batch).
 
     Appends ``rec`` to the flight ring, folds its timing into the
-    registry (``step.count``, ``step.host_ms`` histogram), refreshes
-    the derived bandwidth/MFU gauges when program costs are known, and
-    gives the JSONL emitter its rate-limited snapshot chance.  Cost
-    with every channel off: one deque append + two registry writes."""
+    registry (``step.count``, ``step.host_ms`` histogram) and gives the
+    JSONL emitter its rate-limited snapshot chance.  Cost with every
+    channel off: one deque append + two registry writes."""
     _flight.record(rec)
     _registry.counter("step.count").inc()
     ms = rec.get("host_ms")
     if ms is not None and ms > 0:
         _registry.histogram("step.host_ms").observe(ms)
-        if _costs:
-            sec = ms * 1e-3
-            hbm = _costs.get("hbm_bytes")
-            if hbm:
-                _registry.gauge("derived.hbm_gbps").set(hbm / sec / 1e9)
-            fl = _costs.get("flops")
-            if fl:
-                _registry.gauge("derived.flops_per_s").set(fl / sec)
-                peak = _costs.get("peak")
-                if peak:
-                    _registry.gauge("derived.mfu").set(fl / sec / peak)
     if _emitter is not None:
         if _emitter.maybe_snapshot(_registry):
             _emitter.emit("step", rec)

@@ -11,8 +11,11 @@ even outside a viewer.
 
 Disabled (the default) a ``span(...)`` call returns a shared null
 context — one function call, one attribute test, no allocation.
-Enabled, closing a span appends one dict to a bounded ring; the export
-cost is paid only at :func:`export` time.
+Enabled, a span is also a ``jax.profiler.TraceAnnotation`` of the same
+name for its lifetime, so while ``jax.profiler`` is tracing every span
+is a region of its ``/host:CPU`` plane, on the device trace's clock;
+closing a span appends one dict to a bounded ring, and the export cost
+is paid only at :func:`export` time.
 
 Output is the Chrome trace-event JSON-object format (Perfetto and
 ``chrome://tracing`` both load it): ``{"traceEvents": [...]}`` with
@@ -30,6 +33,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+import jax
 
 __all__ = ["span", "annotate", "enabled", "configure", "export",
            "name_thread", "validate", "clear", "tail"]
@@ -89,7 +94,7 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "id", "parent", "_t0")
+    __slots__ = ("name", "cat", "args", "id", "parent", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any]):
         self.name = name
@@ -111,11 +116,15 @@ class _Span:
         if stack:
             self.parent = stack[-1].id
         stack.append(self)
+        # looked up per span: free while no profiler session is active
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()

@@ -1,5 +1,5 @@
 """Step-overhead guarantees: zero steady-state retraces, donation-safe
-reads, sync-free metrics, and the step-phase profiler.
+reads and sync-free metrics.
 
 The PR-2 contract (docs/perf.md "step overhead attribution"):
 
@@ -13,8 +13,7 @@ The PR-2 contract (docs/perf.md "step overhead attribution"):
   a descriptive RuntimeError naming the donating step, not an opaque
   jax "deleted buffer" error;
 * AsyncMetric snapshots device values at update() time, so a later
-  donation/deletion of the source buffer cannot corrupt the metric;
-* profile_step attributes a step to place/dispatch/device/fetch phases.
+  donation/deletion of the source buffer cannot corrupt the metric.
 """
 import logging
 
@@ -24,7 +23,7 @@ import pytest
 import jax
 
 import mxnet_tpu as mx
-from mxnet_tpu import models, profiler
+from mxnet_tpu import models
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.metric import AsyncMetric
 from mxnet_tpu.parallel import ShardedTrainer, make_mesh
@@ -239,25 +238,3 @@ def test_async_metric_matches_eager_inner():
     assert deferred.get() == eager.get()
     deferred.reset()
     assert deferred.num_inst == 0
-
-
-# ---------------------------------------------------------------------------
-# step-phase profiler
-# ---------------------------------------------------------------------------
-
-def test_profile_step_smoke():
-    tr = _fc_trainer()
-    rng = np.random.RandomState(7)
-    feeds = [_fc_batch(rng) for _ in range(2)]
-    prof = profiler.profile_step(tr, feeds, steps=4, repeats=2)
-    for key in ("place_ms", "dispatch_ms", "device_ms", "fetch_ms",
-                "host_gap_ms", "step_ms"):
-        assert key in prof and np.isfinite(prof[key]), (key, prof)
-        assert prof[key] >= 0.0, (key, prof)
-    assert abs(prof["host_gap_ms"] -
-               max(0.0, prof["place_ms"] + prof["dispatch_ms"]
-                   - prof["device_ms"])) < 1e-9
-    table = profiler.format_step_profile(prof, "smoke")
-    assert "device compute" in table and "host gap" in table
-    # profiling itself must not have retraced the step program
-    tr.assert_steady_state()

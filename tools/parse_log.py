@@ -5,12 +5,7 @@ Parses the logging output of ``FeedForward.fit`` / ``Module.fit`` /
 ``ShardedTrainer.fit`` — epoch times, train/validation metrics,
 Speedometer throughput — and prints a per-epoch markdown table.
 
-``--diff-profile A B`` instead diffs two ``bench.py --profile-step``
-outputs: for every network present in both, a per-phase table of
-ms/step deltas (B - A) and percentages — the regression-triage view for
-step-overhead changes.
-
-``--diff-resilience A B`` diffs the training-guardrail epoch counters
+``--diff-resilience A B`` instead diffs the training-guardrail epoch counters
 (``Epoch[N] Resilience: skipped=... overflows=... rollbacks=...
 loss-scale=... lr-scale=...``) of two runs — the triage view for
 stability changes (docs/resilience.md).
@@ -92,49 +87,6 @@ def parse(lines):
     for epoch, sp in speeds.items():
         rows[epoch]["speed"] = sum(sp) / len(sp)
     return rows
-
-
-def read_profiles(path):
-    """Collect {metric: {phase: ms}} from a bench.py --profile-step log
-    (one JSON object per line with a "step_profile" key; the last record
-    per metric wins)."""
-    profiles = {}
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                rec = json.loads(line)
-            except ValueError:
-                continue
-            if isinstance(rec, dict) and "step_profile" in rec:
-                profiles[rec.get("metric", "?")] = rec["step_profile"]
-    return profiles
-
-
-def diff_profiles(path_a, path_b):
-    a, b = read_profiles(path_a), read_profiles(path_b)
-    common = [m for m in a if m in b]
-    if not common:
-        print("no common step_profile records between the two logs",
-              file=sys.stderr)
-        return 1
-    for metric in common:
-        pa, pb = a[metric], b[metric]
-        phases = [p for p in pa if p in pb]
-        print(f"\n{metric}")
-        print("| phase | A ms | B ms | delta ms | delta % |")
-        print("|---|---|---|---|---|")
-        for ph in phases:
-            va, vb = float(pa[ph]), float(pb[ph])
-            delta = vb - va
-            pct = f"{delta / va * 100:+.1f}%" if va else "n/a"
-            print(f"| {ph} | {va:.3f} | {vb:.3f} | {delta:+.3f} | {pct} |")
-    only = [m for m in (set(a) | set(b)) if m not in common]
-    if only:
-        print(f"\n(unmatched records: {sorted(only)})", file=sys.stderr)
-    return 0
 
 
 def read_resilience(path):
@@ -736,9 +688,6 @@ def diff_staticcheck(path_a, path_b):
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("logfile", nargs="?", help="default: stdin")
-    ap.add_argument("--diff-profile", nargs=2, metavar=("A", "B"),
-                    help="diff two bench.py --profile-step outputs "
-                    "(per-phase ms + %% deltas, B relative to A)")
     ap.add_argument("--diff-resilience", nargs=2, metavar=("A", "B"),
                     help="diff the guardrail counters (skipped/overflows/"
                     "rollbacks/loss-scale/lr-scale) of two runs' epoch "
@@ -775,8 +724,6 @@ def main():
         return diff_serve(*args.diff_serve)
     if args.diff_elastic:
         return diff_elastic(*args.diff_elastic)
-    if args.diff_profile:
-        return diff_profiles(*args.diff_profile)
     if args.diff_resilience:
         return diff_resilience(*args.diff_resilience)
     if args.diff_audit:

@@ -58,8 +58,8 @@ os.environ.setdefault(
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-CORE_SPANS = {"serve.warmup", "serve.admit", "serve.prefill",
-              "serve.decode"}
+CORE_SPANS = {"serve.warmup", "serve.step", "serve.admit", "serve.prefill",
+              "serve.decode", "serve.fetch"}
 
 
 def fail(msg: str) -> None:
