@@ -15,6 +15,7 @@ sharded over the ring axis.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -56,7 +57,10 @@ def local_attention(q, k, v, *, causal=False, scale=None,
                                    scale=scale, q_offset=q_offset,
                                    kv_offset=kv_offset, neg_inf=neg_inf)
     d = q.shape[-1]
-    scale = (1.0 / jnp.sqrt(d).astype(q.dtype)) if scale is None else scale
+    if scale is None:
+        # sqrt on the host: ``jnp.sqrt(d)`` of a Python int is a float64
+        # equation under the package's x64 (same value, rounded the same)
+        scale = 1.0 / jnp.asarray(math.sqrt(d), q.dtype)
     # softmax in f32 regardless of activation dtype (AMP policy), probs
     # cast back so the PV matmul stays on the bf16 MXU path
     scores = (jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale).astype(jnp.float32)

@@ -242,8 +242,10 @@ def _spec_accept_row(logits, toks, live, key, temp, topk, length):
     pos = length + 1 + jnp.arange(c)
 
     def accept_u(p):
+        # float32 by name: the default is float64 under the package's
+        # x64, and `us < p_draft` would then compare in float64
         return jax.random.uniform(jax.random.fold_in(
-            jax.random.fold_in(key, p), _SALT_ACCEPT))
+            jax.random.fold_in(key, p), _SALT_ACCEPT), dtype=jnp.float32)
 
     us = jax.vmap(accept_u)(pos[:k])
     p_draft = jnp.take_along_axis(probs[:k], draft[:, None], axis=1)[:, 0]

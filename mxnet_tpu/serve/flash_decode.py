@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..parallel.flash_attention import NEG_INF
-from .kvcache import QuantPool, is_quantized
+from .kvcache import QuantPool, is_quantized, softmax_scale
 
 __all__ = ["flash_decode_attention", "default_split_k"]
 
@@ -142,7 +142,6 @@ def flash_decode_attention(q, k_pool, v_pool, tables, lengths, *,
     b, h, hd = q.shape
     _, bs, _, _ = kp.shape
     nblk = tables.shape[1]
-    scale_ = (1.0 / np.sqrt(hd)) if scale is None else scale
 
     splits = default_split_k(nblk) if split_k is None else int(split_k)
     if splits < 1:
@@ -156,7 +155,7 @@ def flash_decode_attention(q, k_pool, v_pool, tables, lengths, *,
         tables = jnp.pad(tables, ((0, 0), (0, padded - nblk)))
 
     kernel = partial(_decode_kernel, bps=bps, block_size=bs,
-                     quantized=quantized, scale=np.float32(scale_))
+                     quantized=quantized, scale=softmax_scale(hd, scale))
 
     def kv_spec():
         return pl.BlockSpec(

@@ -60,7 +60,8 @@ from .. import quant as quantmod
 
 __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
            "PrefixIndex", "make_pools", "is_quantized", "layer_view",
-           "pool_nbytes", "kv_bytes_per_token", "paged_attention",
+           "pool_nbytes", "kv_bytes_per_token", "softmax_scale",
+           "paged_attention",
            "paged_prefill_attention", "paged_verify_attention",
            "dense_attention", "write_prefill", "write_decode", "write_spec",
            "scrub_positions", "compact_pool"]
@@ -503,6 +504,18 @@ def make_pools(num_layers: int, num_blocks: int, block_size: int,
     return one(), one()
 
 
+def softmax_scale(head_dim: int, scale: Optional[float] = None) -> np.float32:
+    """The scores' scale (default ``1/sqrt(head_dim)``) as a **float32**
+    scalar.  The package runs with ``jax_enable_x64`` on, and an
+    ``np.float64`` scalar is not weakly typed: ``1.0 / np.sqrt(d)`` met
+    the f32 scores and promoted the whole masked softmax and both
+    contractions to float64, which a TPU emulates in ``while`` loops
+    (ISSUE 25: 448 of the prefill chunk's 500 ms).  Every attention of
+    the serving tier takes its scale from here;
+    tests/test_no_float64.py walks the programs."""
+    return np.float32(1.0 / np.sqrt(head_dim) if scale is None else scale)
+
+
 def _block_size_of(pool: Pool) -> int:
     return (pool.payload if is_quantized(pool) else pool).shape[-3]
 
@@ -585,7 +598,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     b, h, d = q.shape
     nblk = tables.shape[1]
     bs = _block_size_of(k_pool)
-    scale_ = (1.0 / np.sqrt(d)) if scale is None else scale
+    scale_ = softmax_scale(d, scale)
 
     if impl in ("flash", "flash_interpret"):
         from .flash_decode import flash_decode_attention
@@ -638,7 +651,7 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start, length, *,
     c, h, d = q.shape
     nblk = table_row.shape[0]
     bs = _block_size_of(k_pool)
-    scale_ = (1.0 / np.sqrt(d)) if scale is None else scale
+    scale_ = softmax_scale(d, scale)
     f32 = jnp.float32
     k = _gather_blocks(k_pool, table_row).reshape(nblk * bs, h, d)
     v = _gather_blocks(v_pool, table_row).reshape(nblk * bs, h, d)
@@ -681,7 +694,7 @@ def paged_verify_attention(q, k_pool, v_pool, tables, lengths, *,
     b, c, h, d = q.shape
     nblk = tables.shape[1]
     bs = _block_size_of(k_pool)
-    scale_ = (1.0 / np.sqrt(d)) if scale is None else scale
+    scale_ = softmax_scale(d, scale)
     f32 = jnp.float32
     k = _gather_blocks(k_pool, tables).reshape(b, nblk * bs, h, d)
     v = _gather_blocks(v_pool, tables).reshape(b, nblk * bs, h, d)
@@ -709,7 +722,7 @@ def dense_attention(q, k_buf, v_buf, lengths, *, block_size: int,
         raise MXNetError(f"dense cache length {lpad} not a multiple of "
                          f"block {block_size}")
     nblk = lpad // block_size
-    scale_ = (1.0 / np.sqrt(d)) if scale is None else scale
+    scale_ = softmax_scale(d, scale)
     kb = k_buf.reshape(b, nblk, block_size, h, d)
     vb = v_buf.reshape(b, nblk, block_size, h, d)
 

@@ -40,6 +40,7 @@ from ..base import MXNetError
 from ..models.transformer import (_block_step, _lm_head, _param,
                                   lm_config_from_params)
 from ..parallel.flash_attention import NEG_INF
+from .kvcache import softmax_scale
 
 __all__ = ["Drafter", "NGramDrafter", "ModelDrafter", "make_drafter",
            "DRAFT_KINDS", "draft_window_logits"]
@@ -140,7 +141,7 @@ def draft_window_logits(params, tokens, ctx_len, *, heads):
     hd = d // heads
     b, w = tokens.shape
     f32 = jnp.float32
-    scale = 1.0 / np.sqrt(hd)
+    scale = softmax_scale(hd)
     idx = jnp.arange(w)
     # key j of row b is valid iff it is inside the context window and
     # causally visible: j >= W - ctx_len[b] and j <= query position
