@@ -133,86 +133,41 @@ def transformer_lm(vocab_size=256, num_layers=2, d_model=64, heads=4,
 # also work standalone with the dense cache helpers below.
 # ---------------------------------------------------------------------------
 
-_LN_EPS = 1e-5   # LayerNorm op default (ops/nn_ops.py)
+from .decoder import (SOFTMAX, ModelSpec, _param, block,  # noqa: F401
+                      decoder_forward, lm_config_from_params)
+from .decoder import embed as _embed
+from .decoder import lm_head as _head
 
-
-def _fcm(x, weight, bias):
-    """Mirror of the FullyConnected op on [..., d_in] activations."""
-    lead = x.shape[:-1]
-    h = x.reshape((-1, x.shape[-1]))
-    if h.dtype != weight.dtype:
-        h = h.astype(weight.dtype)
-    h = jnp.dot(h, weight.T) + bias.astype(weight.dtype)
-    return h.reshape(lead + (weight.shape[0],))
-
-
-def _lnm(x, gamma, beta):
-    """Mirror of the LayerNorm op (f32 stats under AMP)."""
-    x32 = x.astype(jnp.float32) if x.dtype in (jnp.bfloat16,
-                                               jnp.float16) else x
-    mean = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.var(x32, axis=-1, keepdims=True)
-    xhat = (x32 - mean) * jax.lax.rsqrt(var + _LN_EPS)
-    out = xhat * gamma.astype(x32.dtype) + beta.astype(x32.dtype)
-    return out.astype(x.dtype)
-
-
-def _param(params, name):
-    try:
-        return params[name]
-    except KeyError:
-        raise MXNetError(f"transformer_lm params missing {name!r} — not a "
-                         "transformer_lm parameter dict?")
-
-
-def lm_config_from_params(params):
-    """Infer ``(vocab_size, num_layers, d_model)`` from a transformer_lm
-    parameter dict (heads is not recoverable from shapes — it must come
-    from the caller's config/manifest)."""
-    embed = _param(params, "embed_weight")
-    n = 0
-    while f"layer{n}_q_weight" in params:
-        n += 1
-    if n == 0:
-        raise MXNetError("no layer0_q_weight: not transformer_lm params")
-    return int(embed.shape[0]), n, int(embed.shape[1])
-
-
-def _embed(params, tokens):
-    with jax.named_scope("embed"):
-        return jnp.take(_param(params, "embed_weight"),
-                        tokens.astype(jnp.int32), axis=0)
+# the in-tree description with ``heads=1``: the two helpers below hand
+# ``attend`` the flat [..., d] states, and their callers split the heads
+_FLAT = ModelSpec(heads=1)
 
 
 def _block_step(params, i, h, attend):
-    """One transformer block on hidden states ``h`` ([..., d]) where
-    ``attend(q, k, v)`` maps per-head states [..., H, hd] -> attention
-    output of the same shape (the caller owns the KV story).  The parts
-    are ``jax.named_scope``s, so a device trace names them."""
+    """One in-tree transformer block on hidden states ``h`` ([..., d])
+    where ``attend(q, k, v)`` maps the flat projected states [..., d]
+    to the attention output of the same shape (the caller owns the KV
+    story).  :func:`~mxnet_tpu.models.decoder.block` under the in-tree
+    description."""
+    lead, d = h.shape[:-1], h.shape[-1]
 
-    def p(suffix):
-        return _param(params, f"layer{i}_{suffix}")
+    def flat(q, k, v, _gate):
+        out = attend(*(t.reshape(lead + (d,)) for t in (q, k, v)))
+        return out.reshape(lead + (1, d))
 
-    with jax.named_scope("qkv"):
-        hn = _lnm(h, p("ln1_gamma"), p("ln1_beta"))
-        q, k, v = (_fcm(hn, p(f"{nm}_weight"), p(f"{nm}_bias"))
-                   for nm in ("q", "k", "v"))
-    att = attend(q, k, v)
-    with jax.named_scope("proj"):
-        h = h + _fcm(att, p("proj_weight"), p("proj_bias"))
-    with jax.named_scope("ffn"):
-        hn = _lnm(h, p("ln2_gamma"), p("ln2_beta"))
-        f = _fcm(hn, p("ffn1_weight"), p("ffn1_bias"))
-        f = jnp.maximum(f, 0)
-        return h + _fcm(f, p("ffn2_weight"), p("ffn2_bias"))
+    return block(_FLAT, params, i, SOFTMAX, h, None, flat)
 
 
 def _lm_head(params, h):
-    with jax.named_scope("lm_head"):
-        h = _lnm(h, _param(params, "final_ln_gamma"),
-                 _param(params, "final_ln_beta"))
-        return _fcm(h, _param(params, "lm_head_weight"),
-                    _param(params, "lm_head_bias"))
+    return _head(_FLAT, params, h)
+
+
+def _windowed(params, tokens, heads, attend):
+    """The in-tree LM over a caller-owned cache: ``attend(layer, q, k,
+    v)`` on per-head states, as the three entry points below document."""
+    return decoder_forward(
+        ModelSpec(heads=heads), params, tokens, None,
+        lambda i, _kind, q, k, v, _gate: attend(i, q, k, v))
 
 
 def transformer_lm_prefill(params, tokens, *, heads):
@@ -264,22 +219,7 @@ def transformer_lm_prefill_chunk(params, tokens, *, heads, attend):
     offset is entirely the attend closure's business (the serve tier
     passes it to ``serve.kvcache.paged_prefill_attention``).
     """
-    vocab, num_layers, d = lm_config_from_params(params)
-    if d % heads:
-        raise MXNetError(f"d_model {d} not divisible by heads {heads}")
-    hd = d // heads
-    b, c = tokens.shape
-    h = _embed(params, tokens)
-
-    def make_attend(i):
-        def _attend(q, k, v):
-            q, k, v = (t.reshape(b, c, heads, hd) for t in (q, k, v))
-            return attend(i, q, k, v).reshape(b, c, d)
-        return _attend
-
-    for i in range(num_layers):
-        h = _block_step(params, i, h, make_attend(i))
-    return _lm_head(params, h)
+    return _windowed(params, tokens, heads, attend)
 
 
 def transformer_lm_verify(params, tokens, *, heads, attend):
@@ -300,22 +240,7 @@ def transformer_lm_verify(params, tokens, *, heads, attend):
     attend closure's business (the serve tier passes them to
     ``serve.kvcache.paged_verify_attention``).
     """
-    vocab, num_layers, d = lm_config_from_params(params)
-    if d % heads:
-        raise MXNetError(f"d_model {d} not divisible by heads {heads}")
-    hd = d // heads
-    b, c = tokens.shape
-    h = _embed(params, tokens)
-
-    def make_attend(i):
-        def _attend(q, k, v):
-            q, k, v = (t.reshape(b, c, heads, hd) for t in (q, k, v))
-            return attend(i, q, k, v).reshape(b, c, d)
-        return _attend
-
-    for i in range(num_layers):
-        h = _block_step(params, i, h, make_attend(i))
-    return _lm_head(params, h)
+    return _windowed(params, tokens, heads, attend)
 
 
 def transformer_lm_decode(params, tokens, *, heads, attend):
@@ -331,20 +256,7 @@ def transformer_lm_decode(params, tokens, *, heads, attend):
     (``serve.kvcache.paged_attention``); :func:`transformer_lm_decode_dense`
     below is the self-contained dense-cache form.
     """
-    vocab, num_layers, d = lm_config_from_params(params)
-    hd = d // heads
-    b = tokens.shape[0]
-    h = _embed(params, tokens)
-
-    def make_attend(i):
-        def _attend(q, k, v):
-            q, k, v = (t.reshape(b, heads, hd) for t in (q, k, v))
-            return attend(i, q, k, v).reshape(b, d)
-        return _attend
-
-    for i in range(num_layers):
-        h = _block_step(params, i, h, make_attend(i))
-    return _lm_head(params, h)
+    return _windowed(params, tokens, heads, attend)
 
 
 def transformer_lm_decode_dense(params, tokens, lengths, k_cache, v_cache,

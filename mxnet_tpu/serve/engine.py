@@ -50,11 +50,14 @@ from .. import chaos as chaos_mod
 from .. import compile_cache as cc
 from .. import telemetry
 from ..base import MXNetError
+from ..models.decoder import ModelSpec, decoder_forward
+from ..models.retention import chunk_form
 from ..models.transformer import (lm_config_from_params,
                                   transformer_lm_decode,
                                   transformer_lm_prefill,
                                   transformer_lm_verify)
 from . import kvcache
+from . import retention_decode as retention_mod
 from . import speculate as speculate_mod
 from .scheduler import (CANCELLED, FAILED, FINISHED, Request, Scheduler,
                         ServeError)
@@ -82,9 +85,23 @@ class EngineConfig:
 
     ``heads`` must come from the caller (or checkpoint meta): it is the
     one transformer_lm hyperparameter not recoverable from parameter
-    shapes.
+    shapes.  ``model`` describes any other architecture
+    (:class:`~mxnet_tpu.models.decoder.ModelSpec`, or a dict of its
+    fields as a configuration file holds it); None is the in-tree
+    ``transformer-lm`` with ``heads`` heads.
+
+    For a model whose layers all keep a recurrent state (no paged K/V:
+    ``kvcache.CacheSpec``), ``num_blocks`` counts the state slots (slot
+    0 the trash slot, so ``num_blocks - 1`` requests can be live: give
+    it ``max_batch + 1``), ``block_size`` is not read (a slot holds any
+    number of tokens; ``Engine.max_blocks`` is 1) and ``dtype`` is the
+    state's type: float32, the one type the state is kept in (any other
+    is refused).  ``Engine.num_layers`` /
+    ``heads`` / ``head_dim`` are the model's; ``Request.cached`` counts
+    the tokens a request's state has absorbed.
     """
     heads: int = 4
+    model: Any = None             # ModelSpec | dict of its fields | None
     block_size: int = 16          # kv entries per pool block
     num_blocks: int = 128         # physical pool blocks (slot 0 = trash)
     max_batch: int = 8            # decode slots
@@ -164,6 +181,8 @@ class EngineConfig:
         bandwidth, dominates the decode step."""
         impl = self.attn_impl
         if impl == "auto":
+            # "flash" is the Pallas kernel of the model's cache kind:
+            # flash-decode over paged K/V, retention-decode over states
             return "flash" if jax.default_backend() == "tpu" else "dense"
         if impl not in ("scan", "dense", "flash", "flash_interpret"):
             raise MXNetError(
@@ -340,12 +359,44 @@ class Engine:
             for k, v in params.items()}
         self.vocab, self.num_layers, self.d_model = (
             lm_config_from_params(self._params))
-        self.heads = int(config.heads)
-        if self.d_model % self.heads:
-            raise MXNetError(f"d_model {self.d_model} not divisible by "
-                             f"heads {self.heads}")
-        self.head_dim = self.d_model // self.heads
-        bs = config.block_size
+        # the architecture is TOLD (``config.model``): shapes give the
+        # vocabulary, the depth and the width alone
+        self.model = ModelSpec.resolve(config.model, config.heads)
+        self.heads, self.kv_heads, self.head_dim = self.model.dims(
+            self.d_model)
+        self.cache = kvcache.CacheSpec.for_attention(
+            self.model.layer_kinds(self.num_layers))
+        self.recurrent = self.cache.recurrent
+        if self.recurrent:
+            for name, on in (("prefix_cache", config.prefix_cache),
+                             ("speculate", config.speculate),
+                             ("kv_quant", config.kv_quant)):
+                if on:
+                    raise ServeError(
+                        "unsupported", -1,
+                        f"EngineConfig.{name} needs paged K/V; this "
+                        "model's layers keep a recurrent state "
+                        f"({self.model.attention}): a state has no "
+                        "per-token rows to share, roll back or quantize")
+            if not config.prefill_chunk:
+                raise MXNetError(
+                    "a recurrent-state model ingests prompts through the "
+                    "chunk program: set prefill_chunk > 0")
+            if jnp.dtype(config.dtype) != jnp.float32:
+                raise ServeError(
+                    "unsupported", -1,
+                    f"EngineConfig.dtype {jnp.dtype(config.dtype).name}: "
+                    "the recurrent state is kept in float32 (every decode "
+                    "step rounds it again, so a narrower state is another "
+                    "result, not a faster one)")
+        elif self.model != ModelSpec(heads=self.heads):
+            raise MXNetError(
+                "the paged-K/V programs serve the in-tree transformer-lm "
+                f"description only; {self.model} has softmax layers they "
+                "cannot run yet (position offsets and grouped heads "
+                "through the paged pools: ROADMAP R1)")
+        # a state slot holds any number of tokens: one "block" a request
+        bs = config.max_seq_len if self.recurrent else config.block_size
         self.max_blocks = -(-config.max_seq_len // bs)
         self.attn_impl = config.resolved_attn_impl()
         self.kv_quant = config.kv_quant
@@ -391,9 +442,15 @@ class Engine:
                 telemetry.counter("serve.prefix.evictions").inc()
 
             self.alloc.on_evict = _on_evict
-        self.kpool, self.vpool = kvcache.make_pools(
-            self.num_layers, config.num_blocks, bs, self.heads,
-            self.head_dim, dtype=config.dtype, quant=config.kv_quant)
+        # the donated cache arrays, in the order the programs take them
+        if self.recurrent:
+            self._caches = (kvcache.make_state_pool(
+                self.num_layers, config.num_blocks, self.kv_heads,
+                self.head_dim),)
+        else:
+            self._caches = kvcache.make_pools(
+                self.num_layers, config.num_blocks, bs, self.heads,
+                self.head_dim, dtype=config.dtype, quant=config.kv_quant)
         self.sched = Scheduler(config.max_batch, config.max_queue,
                                config.slo_ms, config.slo_admit_frac)
         if config.max_prompt_len > config.max_seq_len:
@@ -458,11 +515,38 @@ class Engine:
             f"{self.heads}:bs{bs}:nb{config.num_blocks}:"
             f"mb{self.max_blocks}:{np.dtype(config.dtype).name}:"
             f"pc{self.prefill_chunk}:kv{config.kv_quant or 'f32'}:"
-            f"{self.attn_impl}{spec_tag}")
-        telemetry.gauge("kv_bytes_per_token").set(
-            kvcache.kv_bytes_per_token(self.num_layers, self.heads,
-                                       self.head_dim, config.kv_quant,
-                                       dtype=config.dtype))
+            f"{self.attn_impl}{spec_tag}{self.model.signature()}")
+        if self.recurrent:
+            telemetry.gauge("serve.state.bytes_per_request").set(
+                kvcache.pool_nbytes(self.state) // config.num_blocks)
+        else:
+            telemetry.gauge("kv_bytes_per_token").set(
+                kvcache.kv_bytes_per_token(self.num_layers, self.heads,
+                                           self.head_dim, config.kv_quant,
+                                           dtype=config.dtype))
+
+    # the cache arrays by name
+    @property
+    def kpool(self):
+        return self._caches[0]
+
+    @property
+    def vpool(self):
+        return self._caches[1]
+
+    @property
+    def state(self):
+        """The recurrent-state pool (``kvcache.make_state_pool``)."""
+        return self._caches[0]
+
+    def _run(self, kind: str, bucket: int, *args):
+        """Run a warmed program over the donated caches and this step's
+        weights; keeps the caches it returns and hands back the rest."""
+        n = len(self._caches)
+        out = self._programs[(kind, bucket)](
+            *self._caches, self._step_params(), *args)
+        self._caches = tuple(out[:n])
+        return out[n:]
 
     # -- weight loading ---------------------------------------------------
 
@@ -582,6 +666,8 @@ class Engine:
         the first token (read only when this is the final chunk — the
         sampled value is position-keyed at ``length``, identical to the
         whole-prompt program's)."""
+        if self.recurrent:
+            return self._make_state_chunk_fn(cb)
         heads, nl = self.heads, self.num_layers
         from ..models.transformer import transformer_lm_prefill_chunk
 
@@ -619,7 +705,86 @@ class Engine:
 
         return fn_prefill_chunk
 
+    def _make_state_chunk_fn(self, cb: int):
+        """The chunk program of a recurrent-state model: the request's
+        state slot and its position offset go from chunk to chunk in
+        place of a table row.  The state is read with a dynamic slice
+        and written back with a dynamic update of the donated pool (in
+        place); a request's FIRST chunk (``start == 0``) reads zeros
+        whatever the slot held, which is how a slot is scrubbed before
+        reuse."""
+        spec, eps = self.model, self.model.retention_eps
+
+        def fn_prefill_chunk(state, params, tokens, start, length, slot,
+                             key, temp, topk):
+            self.trace_counts[f"prefill_chunk@{cb}"] += 1
+            pool = [state]
+            positions = start + jnp.arange(cb, dtype=jnp.int32)[None, :]
+            n_valid = jnp.clip(length - start, 0, cb)
+
+            def attend(i, _kind, q, k, v, gate):
+                with jax.named_scope("retention"):
+                    at = (np.int32(i), slot) + (np.int32(0),) * (
+                        state.ndim - 2)
+                    prev = jax.lax.dynamic_slice(
+                        pool[0], at, (1, 1) + state.shape[2:])[0, 0]
+                    prev = jnp.where(start == 0, np.float32(0.0), prev)
+                    y, new = chunk_form(prev, q[0], k[0], v[0], gate[0],
+                                        n_valid, eps)
+                    pool[0] = jax.lax.dynamic_update_slice(
+                        pool[0], new[None, None], at)
+                return y[None].astype(q.dtype)
+
+            logits = decoder_forward(spec, params, tokens, positions,
+                                     attend)
+            with jax.named_scope("sample"):
+                last = jnp.take(logits[0],
+                                jnp.clip(length - 1 - start, 0, cb - 1),
+                                axis=0)
+                tok = _sample_row(last, key, temp, topk, length)
+                ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
+            return pool[0], tok, ok
+
+        return fn_prefill_chunk
+
+    def _make_state_decode_fn(self, bb: int):
+        """The decode program of a recurrent-state model: every row's
+        state is read and rewritten once a layer, in place (the Pallas
+        kernel aliases the pool; rows that are not active carry the
+        trash slot)."""
+        spec, eps, impl = self.model, self.model.retention_eps, self.attn_impl
+
+        def update(pool, i, slots, q, k, v, gate):
+            if impl in ("flash", "flash_interpret"):
+                return retention_mod.retention_decode(
+                    pool, i, slots, q, k, v, gate, eps,
+                    interpret=impl == "flash_interpret")
+            return retention_mod.retention_decode_xla(
+                pool, i, slots, q, k, v, gate, eps)
+
+        def fn_decode(state, params, tokens, lengths, slots, keys, temps,
+                      topks):
+            self.trace_counts[f"decode@{bb}"] += 1
+            pool = [state]
+
+            def attend(i, _kind, q, k, v, gate):
+                with jax.named_scope("retention"):
+                    y, pool[0] = update(pool[0], i, slots, q, k, v, gate)
+                return y.astype(q.dtype)
+
+            logits = decoder_forward(spec, params, tokens, lengths, attend)
+            with jax.named_scope("sample"):
+                toks = _sample_batch(logits, keys, temps, topks,
+                                     lengths + 1)
+                oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
+                              axis=-1)
+            return pool[0], toks, oks
+
+        return fn_decode
+
     def _make_decode_fn(self, bb: int):
+        if self.recurrent:
+            return self._make_state_decode_fn(bb)
         heads, impl = self.heads, self.attn_impl
 
         def fn_decode(kpool, vpool, params, tokens, tables, lengths, slots,
@@ -741,13 +906,25 @@ class Engine:
     def _pool_aval(self):
         sds = jax.ShapeDtypeStruct
         return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
-                                      self.kpool)
+                                      self._caches[0])
 
     def _avals(self, kind: str, bucket: int):
         sds = jax.ShapeDtypeStruct
         pool = self._pool_aval()
         params = {k: sds(v.shape, v.dtype) for k, v in self._params.items()}
         key = sds((2,), jnp.uint32)
+        if self.recurrent:
+            i32 = lambda *s: sds(s, jnp.int32)
+            if kind == "prefill_chunk":
+                return (pool, params, i32(1, bucket), i32(), i32(), i32(),
+                        key, sds((), jnp.float32), i32())
+            if kind == "decode":
+                b = bucket
+                return (pool, params, i32(b), i32(b), i32(b),
+                        sds((b, 2), jnp.uint32), sds((b,), jnp.float32),
+                        i32(b))
+            raise MXNetError(f"a recurrent-state model has no {kind!r} "
+                             "program")
         if kind == "prefill":
             return (pool, pool, params, sds((1, bucket), jnp.int32),
                     sds((), jnp.int32), sds((self.max_blocks,), jnp.int32),
@@ -782,7 +959,7 @@ class Engine:
                 "verify": self._make_verify_fn,
                 "draft": self._make_draft_fn}[kind]
         # the draft program owns no pools — nothing to donate
-        donate = () if kind == "draft" else (0, 1)
+        donate = () if kind == "draft" else tuple(range(len(self._caches)))
         jit_fn = jax.jit(make(bucket), donate_argnums=donate)
         avals = self._avals(kind, bucket)
         ckey = cc.program_key(self._fingerprint, avals, donate=donate,
@@ -1025,6 +1202,9 @@ class Engine:
         telemetry.gauge("serve.queue_depth").set(self.sched.queue_depth)
         telemetry.gauge("serve.active_slots").set(self.sched.active)
         telemetry.gauge("serve.kv_blocks_used").set(self.alloc.num_used)
+        if self.recurrent:
+            telemetry.gauge("serve.state.slots_used").set(
+                self.alloc.num_used)
         if self.prefix is not None:
             telemetry.gauge("serve.prefix.cached_frac").set(
                 self.alloc.num_cached / (self.config.num_blocks - 1))
@@ -1076,13 +1256,17 @@ class Engine:
         # its private unpublished blocks), and zeroing it would corrupt
         # the co-owner's stream.  This request merely drops its
         # references via _finish.
-        scrub = [b for b in req.blocks
-                 if self.alloc.refcount(b) <= 1
-                 and (self.prefix is None
-                      or not self.prefix.contains_block(b))]
-        scrub += [kvcache.TRASH_BLOCK]
-        self.kpool = kvcache.scrub_blocks(self.kpool, scrub)
-        self.vpool = kvcache.scrub_blocks(self.vpool, scrub)
+        # A state slot needs no scrub here: its next request's first
+        # chunk reads zeros in its place, and the trash slot is read by
+        # no live row.
+        if not self.recurrent:
+            scrub = [b for b in req.blocks
+                     if self.alloc.refcount(b) <= 1
+                     and (self.prefix is None
+                          or not self.prefix.contains_block(b))]
+            scrub += [kvcache.TRASH_BLOCK]
+            self._caches = tuple(kvcache.scrub_blocks(pool, scrub)
+                                 for pool in self._caches)
         self._finish(req, "error", FAILED)
 
     # -- prefix cache (round 18) ------------------------------------------
@@ -1215,11 +1399,10 @@ class Engine:
                 table_row[:len(req.blocks)] = req.blocks
             with telemetry.span("serve.dispatch", kind="prefill",
                                 bucket=lb):
-                self.kpool, self.vpool, tok, ok = (
-                    self._programs[("prefill", lb)](
-                        self.kpool, self.vpool, self._step_params(),
-                        padded, np.int32(plen), table_row, req.key,
-                        np.float32(req.temperature), np.int32(req.top_k)))
+                tok, ok = self._run(
+                    "prefill", lb, padded, np.int32(plen), table_row,
+                    req.key, np.float32(req.temperature),
+                    np.int32(req.top_k))
             with telemetry.span("serve.fetch"):
                 tok, ok = jax.device_get((tok, ok))
         req.cached = plen
@@ -1243,6 +1426,10 @@ class Engine:
         prefilled, so the pump starts at the first uncached chunk."""
         toks = req.seed_tokens
         req.prefill_target = len(toks)
+        if self.recurrent and req.tokens:
+            # a preempted (or adopted) stream: its state is rebuilt by
+            # re-chunking prompt + tokens, as paged K/V is
+            telemetry.counter("serve.state.rebuilds").inc()
         hits = req.prefix_blocks
         req.prefix_blocks = []
         fresh = self.alloc.alloc(
@@ -1295,16 +1482,21 @@ class Engine:
             with telemetry.span("serve.build"):
                 padded = np.zeros((1, cb), np.int32)
                 padded[0, :len(toks)] = toks
-                table_row = np.zeros((self.max_blocks,), np.int32)
-                table_row[:len(req.blocks)] = req.blocks
+                if self.recurrent:
+                    # the request's state slot; the program zeroes it
+                    # when start == 0
+                    where = np.int32(req.blocks[0])
+                    if start == 0:
+                        telemetry.counter("serve.state.resets").inc()
+                else:
+                    where = np.zeros((self.max_blocks,), np.int32)
+                    where[:len(req.blocks)] = req.blocks
             with telemetry.span("serve.dispatch", kind="prefill_chunk",
                                 bucket=cb):
-                self.kpool, self.vpool, tok, ok = (
-                    self._programs[("prefill_chunk", cb)](
-                        self.kpool, self.vpool, self._step_params(),
-                        padded, np.int32(start), np.int32(plen), table_row,
-                        req.key, np.float32(req.temperature),
-                        np.int32(req.top_k)))
+                tok, ok = self._run(
+                    "prefill_chunk", cb, padded, np.int32(start),
+                    np.int32(plen), where, req.key,
+                    np.float32(req.temperature), np.int32(req.top_k))
             with telemetry.span("serve.fetch"):
                 # one read of both, and the clock below stops after it:
                 # the chunk's real time, not its dispatch
@@ -1419,13 +1611,17 @@ class Engine:
                     keys[i] = req.key
                     temps[i] = req.temperature
                     topks[i] = req.top_k
+                if self.recurrent:
+                    # ``slots`` are the rows' state slots (a request's
+                    # one "block"); rows past ``active`` keep the trash
+                    # slot 0 and are not otherwise told apart
+                    where = (lengths, slots)
+                else:
+                    where = (tables, lengths, slots, offsets, active_m)
             t0 = time.monotonic()
             with telemetry.span("serve.dispatch", kind="decode", bucket=bb):
-                self.kpool, self.vpool, toks, oks = (
-                    self._programs[("decode", bb)](
-                        self.kpool, self.vpool, self._step_params(), tokens,
-                        tables, lengths, slots, offsets, active_m, keys,
-                        temps, topks))
+                toks, oks = self._run("decode", bb, tokens, *where, keys,
+                                      temps, topks)
             with telemetry.span("serve.fetch"):
                 toks = np.asarray(toks)
                 oks = np.asarray(oks)
@@ -1503,11 +1699,9 @@ class Engine:
                     topks[i] = req.top_k
             t0 = time.monotonic()
             with telemetry.span("serve.dispatch", kind="verify", bucket=bb):
-                self.kpool, self.vpool, out, nem, oks = (
-                    self._programs[("verify", bb)](
-                        self.kpool, self.vpool, self._step_params(), tokens,
-                        tables, lengths, live_v, active_m, keys, temps,
-                        topks))
+                out, nem, oks = self._run(
+                    "verify", bb, tokens, tables, lengths, live_v,
+                    active_m, keys, temps, topks)
             with telemetry.span("serve.fetch"):
                 out = np.asarray(out)
                 nem = np.asarray(nem)
@@ -1604,8 +1798,9 @@ class Engine:
         number of relocated blocks; outputs are bitwise unaffected."""
         mapping = self.alloc.defrag()
         if mapping:
-            self.kpool = kvcache.compact_pool(self.kpool, mapping)
-            self.vpool = kvcache.compact_pool(self.vpool, mapping)
+            # blocks and state slots alike sit on the pools' axis 1
+            self._caches = tuple(kvcache.compact_pool(pool, mapping)
+                                 for pool in self._caches)
             for req in self.sched.running:
                 req.blocks = [mapping.get(b, b) for b in req.blocks]
             if self.prefix is not None:

@@ -59,6 +59,7 @@ from ..parallel.flash_attention import NEG_INF
 from .. import quant as quantmod
 
 __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
+           "PAGED_KV", "RECURRENT_STATE", "CacheSpec", "make_state_pool",
            "PrefixIndex", "make_pools", "is_quantized", "layer_view",
            "pool_nbytes", "kv_bytes_per_token", "softmax_scale",
            "paged_attention",
@@ -70,6 +71,10 @@ __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
 #: inactive decode rows scatter their garbage there, keeping every
 #: device-side write unconditional (no retrace-prone masking branches).
 TRASH_BLOCK = 0
+
+#: the two kinds of per-layer cache (:class:`CacheSpec`)
+PAGED_KV = "paged_kv"                 # K and V rows that grow with the sequence
+RECURRENT_STATE = "recurrent_state"   # one fixed-size state a request
 
 #: supported quantized-pool storage formats ("fp8" = e4m3 payload + one
 #: f32 scale per cached position; see :class:`QuantPool`).
@@ -151,6 +156,64 @@ def kv_bytes_per_token(num_layers: int, heads: int, head_dim: int,
         raise MXNetError(f"unknown kv quant format {quant!r}, expected one "
                          f"of {KV_QUANT_FORMATS} or None")
     return 2 * num_layers * (per_pos * 1 + 4)
+
+
+# ---------------------------------------------------------------------------
+# What a model caches, layer by layer
+# ---------------------------------------------------------------------------
+
+class CacheSpec(NamedTuple):
+    """The cache kind of every layer of a model.
+
+    ``paged_kv`` (softmax attention): the layer's K and V rows live in
+    the block pools of :func:`make_pools`; a request owns
+    ``ceil(tokens / block_size)`` blocks and grows by one as it decodes.
+
+    ``recurrent_state`` (power retention): the layer keeps one
+    fixed-size float32 state a request, in the pool of
+    :func:`make_state_pool`; a request owns ONE state slot from
+    admission to finish, cancel, failure or preemption, whatever its
+    length.
+
+    Both are handed out by the one :class:`BlockAllocator`: a state
+    slot is a physical slot that holds any number of tokens
+    (``block_size`` = the engine's ``max_seq_len``), so ``num_used``,
+    ``check`` and the engine's drain check see slots as they see
+    blocks, and slot 0 is the trash slot of both.  A slot's contents
+    are discarded before reuse: the first prefill chunk of a request
+    (``start == 0``) reads zeros in place of whatever the slot held.
+    """
+    kinds: Tuple[str, ...]
+
+    @classmethod
+    def for_attention(cls, attention_kinds: Sequence[str]) -> "CacheSpec":
+        from ..models.decoder import POWER_RETENTION, SOFTMAX
+        table = {SOFTMAX: PAGED_KV, POWER_RETENTION: RECURRENT_STATE}
+        return cls(tuple(table[k] for k in attention_kinds))
+
+    @property
+    def recurrent(self) -> bool:
+        """Every layer keeps a recurrent state (False: every layer is
+        paged; a model that mixes the two needs blocks AND a slot a
+        request, which no model here asks for yet)."""
+        kinds = set(self.kinds)
+        if len(kinds) != 1:
+            raise MXNetError(
+                f"a model mixing cache kinds {sorted(kinds)} is not served "
+                "yet: every layer must be paged_kv or every layer "
+                "recurrent_state")
+        return kinds == {RECURRENT_STATE}
+
+
+def make_state_pool(num_layers: int, num_slots: int, kv_heads: int,
+                    head_dim: int) -> jax.Array:
+    """The recurrent-state pool, zeroed: ``[num_layers, num_slots,
+    kv_heads, chunks, rows, head_dim]`` float32, where ``(chunks, rows,
+    head_dim)`` is one head's ``S`` and ``z`` side by side in the layout
+    of ``models/retention.py``.  Slot 0 is the trash slot."""
+    from ..models.retention import state_shape
+    return jnp.zeros((num_layers, num_slots, kv_heads)
+                     + state_shape(head_dim), jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -64,7 +64,12 @@ def configure(path: Optional[str], enable: Optional[bool] = None) -> None:
 
 
 def clear() -> None:
+    """Empty the ring.  The calling thread's stack of open spans goes
+    with it: a span left open (a generator that never finished, tracing
+    switched off inside it) would otherwise be the parent of every later
+    root span, under an id the emptied ring no longer holds."""
     _events.clear()
+    _tls.stack = None
     with _lock:
         _thread_names.clear()
 

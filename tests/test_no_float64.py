@@ -73,6 +73,47 @@ _CASES = ([("decode", pool, impl) for pool in _POOLS
           + [("draft", "plain", "dense")])
 
 
+# a recurrent-state model (ISSUE 26): the block with RMSNorm, q/k norms,
+# RoPE, a gated SiLU FFN and power retention; its two programs
+_RETENTION = dict(kv_heads=2, head_dim=8, norm="rmsnorm", norm_eps=1e-6,
+                  qk_norm=True, bias=False, ffn="silu_gated",
+                  position="rope", rope_theta=1e6,
+                  attention="power_retention")
+
+
+def _retention_params(seed=0):
+    rng = np.random.RandomState(seed)
+    kv, hd, f = 2, 8, 48
+    shapes = {"embed_weight": (V, D), "final_ln_gamma": (D,),
+              "lm_head_weight": (V, D)}
+    for i in range(NL):
+        p = f"layer{i}_"
+        shapes.update({
+            p + "q_weight": (H * hd, D), p + "k_weight": (kv * hd, D),
+            p + "v_weight": (kv * hd, D), p + "proj_weight": (D, H * hd),
+            p + "gate_weight": (kv, D), p + "gate_bias": (kv,),
+            p + "q_norm_gamma": (hd,), p + "k_norm_gamma": (hd,),
+            p + "ffn_gate_weight": (f, D), p + "ffn_up_weight": (f, D),
+            p + "ffn_down_weight": (D, f), p + "ln1_gamma": (D,),
+            p + "ln2_gamma": (D,)})
+    return {n: (rng.randn(*s) * 0.05).astype(np.float32)
+            for n, s in shapes.items()}
+
+
+@pytest.mark.parametrize("kind,impl", [("prefill_chunk", "dense"),
+                                       ("decode", "dense"),
+                                       ("decode", "flash_interpret")])
+def test_recurrent_state_program_makes_no_float64(kind, impl):
+    eng = Engine(_retention_params(), EngineConfig(
+        heads=H, model=_RETENTION, num_blocks=5, max_batch=4,
+        max_prompt_len=16, max_seq_len=48, prefill_chunk=8, attn_impl=impl))
+    bucket = 8 if kind == "prefill_chunk" else 4
+    fn = getattr(eng, _MAKERS[kind])(bucket)
+    closed = jax.make_jaxpr(fn)(*eng._avals(kind, bucket))
+    assert len(closed.jaxpr.eqns) > 20, "nothing was traced"
+    _assert_no_float64(closed, f"{kind}@{bucket} (recurrent state, {impl})")
+
+
 @pytest.mark.parametrize("kind,pool,impl", _CASES,
                          ids=["-".join(c) for c in _CASES])
 def test_serving_program_makes_no_float64(kind, pool, impl):
