@@ -116,7 +116,7 @@ def make_requests(traffic: Dict[str, Any], vocab: int, seed: int, n: int,
     for i, j in enumerate(rng.permutation(n)):
         toks = rng.integers(1, vocab, int(plens[i]))
         reqs.append(RequestSpec(
-            prompt=tuple(int(t) for t in toks), max_new_tokens=int(outs[i]),
+            prompt=tuple(toks.tolist()), max_new_tokens=int(outs[i]),
             temperature=float(plan[j]["temperature"]),
             top_k=int(plan[j]["top_k"]),
             seed=request_seed(int(seed), stream, i)))

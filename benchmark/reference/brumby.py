@@ -88,6 +88,25 @@ def state_bytes_per_request(cfg: Dict[str, Any]) -> int:
     return n * kv * (hd * (hd + 1) // 2) * (hd + 1) * 4
 
 
+def forward_flops(cfg: Dict[str, Any], positions: int, attended: int) -> float:
+    """FLOPs the forward pass needs for ``positions`` new positions: 2
+    per parameter of the matmuls and position (q, k, v, gate, proj, the
+    three FFN matrices, the head; the embedding is a lookup) and, per
+    layer and key/value head, the recurrent form's work on a state of
+    ``hd (hd + 1) / 2`` features by ``hd + 1``: decay and rank-one
+    update, 3 an element, and the read, 2 an element and query head of
+    the group (``benchmark/kernels/mxtpu_retention_decode.py`` counts
+    the same).  The state is as large at any length, so ``attended``
+    (the cached positions a softmax layer would read) changes nothing.
+    Served tokens' share of the chip's peak (``serve_mfu``) reads it."""
+    v, n, d, h, kv, hd, f = dims(cfg)
+    matmul_params = n * (2 * h * hd * d + 2 * kv * hd * d + kv * d
+                         + 3 * d * f) + v * d
+    state = (hd * (hd + 1) // 2) * (hd + 1)
+    retention = n * kv * state * (3 + 2 * (h // kv))
+    return (2.0 * matmul_params + retention) * positions
+
+
 def init_params(seed: int, cfg: Dict[str, Any], dtype=jnp.float32,
                 std: float = 0.02) -> Dict[str, jax.Array]:
     """Seeded random weights made ON the device, in the type they are

@@ -172,3 +172,16 @@ def kv_bytes_per_token(cfg: Dict[str, Any], itemsize: int) -> int:
     """K and V of one position across every layer."""
     _, n, d, _, _ = dims(cfg)
     return 2 * n * d * itemsize
+
+
+def forward_flops(cfg: Dict[str, Any], positions: int, attended: int) -> float:
+    """FLOPs the forward pass needs for ``positions`` new positions that
+    attend over ``attended`` cached positions between them (each one's
+    own included): 2 per parameter of the matmuls and position (q, k, v,
+    proj, the two FFN matrices, the head; the embedding is a lookup),
+    and q.k and p.v, 2 each per attended position, layer and channel
+    (``benchmark/kernels/mxtpu_flash_decode.py`` counts the same 4).
+    Served tokens' share of the chip's peak (``serve_mfu``) reads it."""
+    v, n, d, _, f = dims(cfg)
+    matmul_params = n * (4 * d * d + 2 * d * f) + v * d
+    return 2.0 * matmul_params * positions + 4.0 * n * d * attended

@@ -30,3 +30,9 @@ def test_rehearsal_runs_and_reports_no_metric(cell, trace):
     assert line["device"]["platform"] == "cpu"
     assert line["attempted"] > 0 and line["failed"] == 0
     assert "in-window compiles 0" in out.stdout
+    mix = spec.load_traffic(spec.find_cell(BENCH, cell)["traffic"])
+    if mix["kind"].startswith("serve_engine_closed"):
+        # the tiny engine is the fast one: its clients go through dozens
+        # of rounds where the chip's stay in round 0
+        assert "requests_per_client" not in mix["rehearsal"]
+        assert "[traffic] round 2 drawn at +" in out.stdout
