@@ -297,20 +297,26 @@ def phase_kernels(cfg, interpret: bool) -> None:
         for _ in range(p["b"])]), jnp.int32)
     lengths = jnp.asarray(rng.randint(1, p["nblk"] * bs + 1, p["b"]),
                           jnp.int32).at[0].set(p["nblk"] * bs)
-    pools = {"f32": [s.reshape(nb, bs, h, hd) for s in states]}
+    # the pools' stored form, two layers: layer 1 holds the states,
+    # layer 0 other values in the same slots (a reader that ignored the
+    # layer would read those)
+    def stored(x, tail=(h * hd,)):
+        x = x.reshape((nb, bs) + tail)
+        return jnp.stack([jnp.flip(x, 0), x])
+
+    pools = {"f32": [stored(s) for s in states]}
     fp8 = []
     for s in states:
         payload, scale = quant.rowwise_quantize(s, kvcache.KV_FP8_FORMAT)
-        fp8.append(kvcache.QuantPool(payload.reshape(nb, bs, h, hd),
-                                     scale.reshape(nb, bs)))
+        fp8.append(kvcache.QuantPool(stored(payload), stored(scale, ())))
     pools["fp8"] = fp8
     flash_impl = "flash_interpret" if interpret else "flash"
     for name, (kp, vp) in pools.items():
-        out = jax.jit(lambda *t: kvcache.paged_attention(
-            *t, impl=flash_impl))(qd, kp, vp, tables, lengths)
+        out = jax.jit(lambda q, kp, vp, *t: kvcache.paged_attention(
+            q, kp, vp, 1, *t, impl=flash_impl))(qd, kp, vp, tables, lengths)
         with hi:
-            ref = jax.jit(lambda *t: kvcache.paged_attention(
-                *t, impl="dense"))(qd, kp, vp, tables, lengths)
+            ref = jax.jit(lambda q, kp, vp, *t: kvcache.paged_attention(
+                q, kp, vp, 1, *t, impl="dense"))(qd, kp, vp, tables, lengths)
         e = nerr(out, ref)
         say(f"[kernels] paged_attention impl=flash vs dense, {name} pool "
             f"{nb} blocks x {bs}: err {e:.2e} (tol {TOL_DECODE})")

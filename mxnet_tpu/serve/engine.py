@@ -687,9 +687,7 @@ class Engine:
                                                  table_row, length,
                                                  start=start)
                 out = kvcache.paged_prefill_attention(
-                    q[0], kvcache.layer_view(pools[0], i),
-                    kvcache.layer_view(pools[1], i), table_row, start,
-                    length)
+                    q[0], pools[0], pools[1], i, table_row, start, length)
                 return out[None]
 
             logits = transformer_lm_prefill_chunk(params, tokens,
@@ -798,8 +796,7 @@ class Engine:
                 pools[1] = kvcache.write_decode(pools[1], i, v, slots,
                                                 offsets, active)
                 return kvcache.paged_attention(
-                    q, kvcache.layer_view(pools[0], i),
-                    kvcache.layer_view(pools[1], i), tables, lengths + 1,
+                    q, pools[0], pools[1], i, tables, lengths + 1,
                     impl=impl)
 
             logits = transformer_lm_decode(params, tokens, heads=heads,
@@ -841,8 +838,7 @@ class Engine:
                 pools[0] = kvcache.write_spec(pools[0], i, k, slots, offs)
                 pools[1] = kvcache.write_spec(pools[1], i, v, slots, offs)
                 return kvcache.paged_verify_attention(
-                    q, kvcache.layer_view(pools[0], i),
-                    kvcache.layer_view(pools[1], i), tables, lengths)
+                    q, pools[0], pools[1], i, tables, lengths)
 
             logits = transformer_lm_verify(params, tokens, heads=heads,
                                            attend=attend)

@@ -2,11 +2,25 @@
 
 vLLM-style paging on top of the repo's blockwise-attention machinery:
 key/value states live in **preallocated device pools** of fixed-size
-blocks (``[num_layers, num_blocks, block_size, heads, head_dim]``), and
-each in-flight request owns a host-side **block table** — logical block
-``j`` of the request maps to physical pool slot ``table[j]``.  Slots are
-recycled the moment a request finishes, so HBM for the cache is bounded
-by the pool, not by max-batch × max-seq-len.
+blocks, and each in-flight request owns a host-side **block table** —
+logical block ``j`` of the request maps to physical pool slot
+``table[j]``.  Slots are recycled the moment a request finishes, so HBM
+for the cache is bounded by the pool, not by max-batch × max-seq-len.
+
+**The stored form** (:func:`make_pools`, the one place that decides it):
+``[num_layers, num_blocks, block_size, heads * head_dim]`` — a position's
+heads side by side on the minor dimension.  That dimension is what a TPU
+lays along its 128 lanes: at ``heads * head_dim`` a multiple of 128 and a
+block one sublane tile deep, XLA's default layout of the buffer and the
+layout the Pallas kernel's block pipeline wants are the same bytes, so a
+donated pool crosses every program's and every kernel's boundary
+untouched (a ``(..., heads, head_dim)`` minor pair with ``head_dim`` 64
+fills half of every lane row, and was re-laid-out whole on entry to and
+exit from each program).  No helper here ever takes one layer's slice of
+a pool: readers get the whole pool and the layer, and put the layer among
+the indices of their gather (or of the kernel's block map); writers
+scatter at ``[layer, slot, offset]``.  The head geometry is never read
+off a pool: it comes with the query states (``[..., H, hd]``).
 
 The device side is three pure functions, all shape-static so the serve
 engine's decode program never retraces:
@@ -60,7 +74,7 @@ from .. import quant as quantmod
 
 __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
            "PAGED_KV", "RECURRENT_STATE", "CacheSpec", "make_state_pool",
-           "PrefixIndex", "make_pools", "is_quantized", "layer_view",
+           "PrefixIndex", "make_pools", "is_quantized",
            "pool_nbytes", "kv_bytes_per_token", "softmax_scale",
            "paged_attention",
            "paged_prefill_attention", "paged_verify_attention",
@@ -92,8 +106,8 @@ class QuantPool(NamedTuple):
     scale per cached token position per layer — the row absmax lands on
     the fp8 format max, so the cast never overflows).
 
-    ``payload``: ``[num_layers, num_blocks, block_size, heads, head_dim]``
-    fp8; ``scale``: ``[num_layers, num_blocks, block_size]`` f32.  A
+    ``payload``: the stored form of :func:`make_pools` in fp8;
+    ``scale``: ``[num_layers, num_blocks, block_size]`` f32.  A
     NamedTuple so the pair rides through jit/donation as one pytree —
     every pool-taking function here accepts either a plain array pool or
     a ``QuantPool`` and dispatches on the type.
@@ -121,16 +135,6 @@ def _scoped(name: str):
 
 def is_quantized(pool) -> bool:
     return isinstance(pool, QuantPool)
-
-
-@_scoped("pool_read")
-def layer_view(pool: Pool, layer: int) -> Pool:
-    """One layer's slice of a pool, preserving quantization structure:
-    ``[num_blocks, BS, H, hd]`` (array) or the matching ``QuantPool``
-    of ``(payload, scale[num_blocks, BS])``."""
-    if is_quantized(pool):
-        return QuantPool(pool.payload[layer], pool.scale[layer])
-    return pool[layer]
 
 
 def pool_nbytes(*pools: Pool) -> int:
@@ -546,15 +550,17 @@ class PrefixIndex:
 def make_pools(num_layers: int, num_blocks: int, block_size: int,
                heads: int, head_dim: int, dtype=jnp.float32,
                quant: Optional[str] = None) -> Tuple[Pool, Pool]:
-    """Preallocate the K and V pools:
-    ``[num_layers, num_blocks, block_size, heads, head_dim]``.
+    """Preallocate the K and V pools in the stored form:
+    ``[num_layers, num_blocks, block_size, heads * head_dim]`` (the
+    module docstring says why the heads are flattened onto the minor
+    dimension: lanes).
 
     ``quant="fp8"`` returns :class:`QuantPool` pairs instead — e4m3
     payload plus per-position f32 scales — halving cache bytes per token
     (4B -> 1B payload + amortized scale).  Each pool gets its own fresh
     buffers: the engine donates both, and aliased donations are illegal.
     """
-    shape = (num_layers, num_blocks, block_size, heads, head_dim)
+    shape = (num_layers, num_blocks, block_size, heads * head_dim)
     if quant is None:
         return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
     if quant not in KV_QUANT_FORMATS:
@@ -580,18 +586,32 @@ def softmax_scale(head_dim: int, scale: Optional[float] = None) -> np.float32:
 
 
 def _block_size_of(pool: Pool) -> int:
-    return (pool.payload if is_quantized(pool) else pool).shape[-3]
+    return (pool.payload if is_quantized(pool) else pool).shape[2]
 
 
-def _gather_blocks(pool: Pool, idx):
-    """Gather physical blocks by slot index, dequantizing fp8 payloads
-    to f32 against their per-position scales.  ``idx`` may be any int
-    shape; the result is ``idx.shape + [BS, H, hd]``."""
+@_scoped("pool_read")
+def _gather_blocks(pool: Pool, layer: int, idx, shape):
+    """Gather physical blocks of one layer by slot index, dequantizing
+    fp8 payloads to f32 against their per-position scales, as ``shape``
+    (``[..., positions, H, hd]``: what the per-head contractions want).
+    ``idx`` may be any int shape.
+
+    The layer rides among the gather's indices (``pool[layer, idx]`` is
+    ONE gather out of the whole pool), so no ``[num_blocks, BS, ...]``
+    slice of a layer is ever materialised.  Un-flattening the gathered
+    rows' ``H * hd`` lanes into heads re-lays-out the gathered rows (not
+    the pool), which is this scope's larger half on a TPU."""
     if is_quantized(pool):
-        q = jnp.take(pool.payload, idx, axis=0)
-        s = jnp.take(pool.scale, idx, axis=0)
-        return q.astype(jnp.float32) * s[..., None, None]
-    return jnp.take(pool, idx, axis=0)
+        rows = (pool.payload[layer, idx].astype(jnp.float32)
+                * pool.scale[layer, idx][..., None])
+    else:
+        rows = pool[layer, idx]
+    return rows.reshape(shape)
+
+
+def _flat_heads(states):
+    """``[..., H, hd]`` states as the pools store them: ``[..., H * hd]``."""
+    return states.reshape(states.shape[:-2] + (-1,))
 
 
 def _attend_blocks(q, read_block, nblk: int, block_size: int, lengths,
@@ -628,14 +648,13 @@ def _attend_blocks(q, read_block, nblk: int, block_size: int, lengths,
     return (acc / l[..., None]).astype(q.dtype)
 
 
-@_scoped("attn")
-def paged_attention(q, k_pool, v_pool, tables, lengths, *,
+def paged_attention(q, k_pool, v_pool, layer: int, tables, lengths, *,
                     scale: Optional[float] = None, impl: str = "scan"):
     """One-token-per-request attention over a paged cache.
 
-    ``q``: [B, H, hd] query states; ``k_pool``/``v_pool``:
-    [num_blocks, BS, H, hd] (one layer's pool, plain or
-    :class:`QuantPool`); ``tables``: [B, max_blocks] int32 physical slot
+    ``q``: [B, H, hd] query states; ``k_pool``/``v_pool``: the WHOLE
+    pools (plain or :class:`QuantPool`) and ``layer`` the layer to
+    read; ``tables``: [B, max_blocks] int32 physical slot
     per logical block (unused entries may hold any valid slot — the
     length mask kills them); ``lengths``: [B] int32 valid cache entries
     (including the current token, which must already be written).
@@ -665,22 +684,24 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
 
     if impl in ("flash", "flash_interpret"):
         from .flash_decode import flash_decode_attention
-        return flash_decode_attention(
-            q, k_pool, v_pool, tables, lengths, scale=scale_,
-            interpret=(impl == "flash_interpret"))
+        with jax.named_scope("attn"):
+            return flash_decode_attention(
+                q, k_pool, v_pool, layer, tables, lengths, scale=scale_,
+                interpret=(impl == "flash_interpret"))
 
     if impl == "dense":
         f32 = jnp.float32
-        k = _gather_blocks(k_pool, tables).reshape(b, nblk * bs, h, d)
-        v = _gather_blocks(v_pool, tables).reshape(b, nblk * bs, h, d)
-        s = jnp.einsum("bhd,blhd->bhl", q, k).astype(f32) * scale_
-        valid = jnp.arange(nblk * bs)[None, :] < lengths[:, None]
-        s = jnp.where(valid[:, None, :], s, NEG_INF)
-        m = jnp.max(s, axis=-1)
-        p = jnp.where(valid[:, None, :], jnp.exp(s - m[..., None]), 0.0)
-        l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
-        out = jnp.einsum("bhl,blhd->bhd", p, v.astype(f32))
-        return (out / l[..., None]).astype(q.dtype)
+        k = _gather_blocks(k_pool, layer, tables, (b, nblk * bs, h, d))
+        v = _gather_blocks(v_pool, layer, tables, (b, nblk * bs, h, d))
+        with jax.named_scope("attn"):
+            s = jnp.einsum("bhd,blhd->bhl", q, k).astype(f32) * scale_
+            valid = jnp.arange(nblk * bs)[None, :] < lengths[:, None]
+            s = jnp.where(valid[:, None, :], s, NEG_INF)
+            m = jnp.max(s, axis=-1)
+            p = jnp.where(valid[:, None, :], jnp.exp(s - m[..., None]), 0.0)
+            l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
+            out = jnp.einsum("bhl,blhd->bhd", p, v.astype(f32))
+            return (out / l[..., None]).astype(q.dtype)
 
     if impl != "scan":
         raise MXNetError(f"paged_attention: unknown impl {impl!r}, expected "
@@ -688,18 +709,20 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
 
     def read_block(j):
         slot = tables[:, j]
-        return _gather_blocks(k_pool, slot), _gather_blocks(v_pool, slot)
+        return (_gather_blocks(k_pool, layer, slot, (b, bs, h, d)),
+                _gather_blocks(v_pool, layer, slot, (b, bs, h, d)))
 
-    return _attend_blocks(q, read_block, nblk, bs, lengths, scale_)
+    with jax.named_scope("attn"):
+        return _attend_blocks(q, read_block, nblk, bs, lengths, scale_)
 
 
-@_scoped("attn")
-def paged_prefill_attention(q, k_pool, v_pool, table_row, start, length, *,
-                            scale: Optional[float] = None):
+def paged_prefill_attention(q, k_pool, v_pool, layer: int, table_row, start,
+                            length, *, scale: Optional[float] = None):
     """Causal attention for one **prefill chunk** over a paged cache.
 
     ``q``: [C, H, hd] — the chunk's query states at absolute positions
-    ``start .. start+C-1``; ``table_row``: [max_blocks] int32 — one
+    ``start .. start+C-1``; ``k_pool``/``v_pool``/``layer``: the whole
+    pools and the layer to read; ``table_row``: [max_blocks] int32 — one
     request's block table; ``length``: scalar — total valid cache
     entries (the chunk's own K/V must already be written, so position
     ``p`` of the chunk may attend to every cached position ``<= start+p``).
@@ -716,22 +739,22 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start, length, *,
     bs = _block_size_of(k_pool)
     scale_ = softmax_scale(d, scale)
     f32 = jnp.float32
-    k = _gather_blocks(k_pool, table_row).reshape(nblk * bs, h, d)
-    v = _gather_blocks(v_pool, table_row).reshape(nblk * bs, h, d)
-    s = jnp.einsum("chd,lhd->chl", q, k).astype(f32) * scale_
-    pos = jnp.arange(nblk * bs)
-    qpos = start + jnp.arange(c)
-    valid = (pos[None, :] <= qpos[:, None]) & (pos[None, :] < length)
-    s = jnp.where(valid[:, None, :], s, NEG_INF)
-    m = jnp.max(s, axis=-1)
-    p = jnp.where(valid[:, None, :], jnp.exp(s - m[..., None]), 0.0)
-    l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
-    out = jnp.einsum("chl,lhd->chd", p, v.astype(f32))
-    return (out / l[..., None]).astype(q.dtype)
+    k = _gather_blocks(k_pool, layer, table_row, (nblk * bs, h, d))
+    v = _gather_blocks(v_pool, layer, table_row, (nblk * bs, h, d))
+    with jax.named_scope("attn"):
+        s = jnp.einsum("chd,lhd->chl", q, k).astype(f32) * scale_
+        pos = jnp.arange(nblk * bs)
+        qpos = start + jnp.arange(c)
+        valid = (pos[None, :] <= qpos[:, None]) & (pos[None, :] < length)
+        s = jnp.where(valid[:, None, :], s, NEG_INF)
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(valid[:, None, :], jnp.exp(s - m[..., None]), 0.0)
+        l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
+        out = jnp.einsum("chl,lhd->chd", p, v.astype(f32))
+        return (out / l[..., None]).astype(q.dtype)
 
 
-@_scoped("attn")
-def paged_verify_attention(q, k_pool, v_pool, tables, lengths, *,
+def paged_verify_attention(q, k_pool, v_pool, layer: int, tables, lengths, *,
                            scale: Optional[float] = None):
     """Causal attention for one **speculative verify** step: C query
     positions per request over a paged cache.
@@ -739,7 +762,8 @@ def paged_verify_attention(q, k_pool, v_pool, tables, lengths, *,
     ``q``: [B, C, H, hd] — query states at absolute positions
     ``lengths[b] .. lengths[b]+C-1`` (position 0 of the window is the
     request's current last token, 1..C-1 the drafted continuation);
-    ``tables``: [B, max_blocks]; ``lengths``: [B] cache entries valid
+    ``k_pool``/``v_pool``/``layer``: the whole pools and the layer to
+    read; ``tables``: [B, max_blocks]; ``lengths``: [B] cache entries valid
     *before* this step.  The window's own K/V must already be written
     (the verify program writes them first, exactly like the decode and
     chunk-prefill twins), so window position ``c`` may attend to every
@@ -759,18 +783,19 @@ def paged_verify_attention(q, k_pool, v_pool, tables, lengths, *,
     bs = _block_size_of(k_pool)
     scale_ = softmax_scale(d, scale)
     f32 = jnp.float32
-    k = _gather_blocks(k_pool, tables).reshape(b, nblk * bs, h, d)
-    v = _gather_blocks(v_pool, tables).reshape(b, nblk * bs, h, d)
-    s = jnp.einsum("bchd,blhd->bchl", q, k).astype(f32) * scale_
-    pos = jnp.arange(nblk * bs)
-    qpos = lengths[:, None] + jnp.arange(c)[None, :]          # [B, C]
-    valid = pos[None, None, :] <= qpos[:, :, None]            # [B, C, L]
-    s = jnp.where(valid[:, :, None, :], s, NEG_INF)
-    m = jnp.max(s, axis=-1)
-    p = jnp.where(valid[:, :, None, :], jnp.exp(s - m[..., None]), 0.0)
-    l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
-    out = jnp.einsum("bchl,blhd->bchd", p, v.astype(f32))
-    return (out / l[..., None]).astype(q.dtype)
+    k = _gather_blocks(k_pool, layer, tables, (b, nblk * bs, h, d))
+    v = _gather_blocks(v_pool, layer, tables, (b, nblk * bs, h, d))
+    with jax.named_scope("attn"):
+        s = jnp.einsum("bchd,blhd->bchl", q, k).astype(f32) * scale_
+        pos = jnp.arange(nblk * bs)
+        qpos = lengths[:, None] + jnp.arange(c)[None, :]          # [B, C]
+        valid = pos[None, None, :] <= qpos[:, :, None]            # [B, C, L]
+        s = jnp.where(valid[:, :, None, :], s, NEG_INF)
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(valid[:, :, None, :], jnp.exp(s - m[..., None]), 0.0)
+        l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
+        out = jnp.einsum("bchl,blhd->bchd", p, v.astype(f32))
+        return (out / l[..., None]).astype(q.dtype)
 
 
 def dense_attention(q, k_buf, v_buf, lengths, *, block_size: int,
@@ -800,8 +825,9 @@ def write_prefill(pool, layer: int, states, table_row, length, start=0):
     """Scatter a prompt's (or prompt chunk's) K or V states into its
     table's slots.
 
-    ``pool``: [layers, nblocks, BS, H, hd] (plain or :class:`QuantPool`);
-    ``states``: [L_pad, H, hd] (bucket- or chunk-padded); ``table_row``:
+    ``pool``: the whole pool (plain or :class:`QuantPool`); ``states``:
+    [L_pad, H, hd] (bucket- or chunk-padded; stored flattened to
+    ``H * hd``, and nothing else about them changes); ``table_row``:
     [max_blocks] int32; ``length``: scalar total valid positions;
     ``start``: absolute position of ``states[0]`` (chunked prefill
     writes chunk *i* with ``start = i * chunk``).  Positions
@@ -820,6 +846,7 @@ def write_prefill(pool, layer: int, states, table_row, length, start=0):
     slot = jnp.where(pos < length, jnp.take(table_row, logical),
                      TRASH_BLOCK)
     off = pos % bs
+    states = _flat_heads(states)
     if is_quantized(pool):
         q, s = quantmod.rowwise_quantize(states, KV_FP8_FORMAT)
         return QuantPool(pool.payload.at[layer, slot, off].set(q),
@@ -836,6 +863,7 @@ def write_decode(pool, layer: int, states, slots, offsets, active):
     inactive rows write to the trash block.  Returns the updated pool.
     """
     slot = jnp.where(active, slots, TRASH_BLOCK)
+    states = _flat_heads(states)
     if is_quantized(pool):
         q, s = quantmod.rowwise_quantize(states, KV_FP8_FORMAT)
         return QuantPool(pool.payload.at[layer, slot, offsets].set(q),
@@ -854,10 +882,11 @@ def write_spec(pool, layer: int, states, slots, offsets):
     count) by pointing their slot at the trash block — the scatter
     itself is unconditional, like :func:`write_decode`.  Quantized
     pools quantize each position row independently (flattened to
-    ``[B*C, H, hd]`` so a position's fp8 payload+scale is a pure
+    ``[B*C, H*hd]`` so a position's fp8 payload+scale is a pure
     function of its states, independent of the window shape — the
     byte-identity contract of speculative decode depends on it).
     """
+    states = _flat_heads(states)
     if is_quantized(pool):
         b, c = states.shape[:2]
         q, s = quantmod.rowwise_quantize(

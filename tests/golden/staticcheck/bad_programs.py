@@ -139,19 +139,19 @@ def hbm_bytes_quantized():
 
 def _decode_read(quant):
     """Trace one layer's paged decode-attention read (the dense impl's
-    table gather) over a pool that is f32 or fp8-quantized."""
+    table gather) over pools that are f32 or fp8-quantized."""
     from mxnet_tpu.serve import kvcache
-    nb, bs, h, hd, b, mb = 16, 8, 2, 16, 2, 4
+    nl, nb, bs, h, hd, b, mb = 2, 16, 8, 2, 16, 2, 4
 
     if quant:
         pool = kvcache.QuantPool(
-            _SDS((nb, bs, h, hd), jnp.float8_e4m3fn),
-            _SDS((nb, bs), jnp.float32))
+            _SDS((nl, nb, bs, h * hd), jnp.float8_e4m3fn),
+            _SDS((nl, nb, bs), jnp.float32))
     else:
-        pool = _SDS((nb, bs, h, hd), jnp.float32)
+        pool = _SDS((nl, nb, bs, h * hd), jnp.float32)
 
     def step(q, kp, vp, tables, lengths):
-        return kvcache.paged_attention(q, kp, vp, tables, lengths,
+        return kvcache.paged_attention(q, kp, vp, 1, tables, lengths,
                                        impl="dense")
 
     return jax.jit(step).trace(

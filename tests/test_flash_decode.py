@@ -7,9 +7,14 @@ tests pin the kernel's numerics — not a Python re-implementation:
 
 * parity with the dense one-shot reference across block counts (single
   block through long ragged contexts) and every split-K partitioning,
-  including splits that do not divide the block count;
+  including splits that do not divide the block count; the pools come
+  from ``make_pools`` (the stored form, ``[L, blocks, BS, H*hd]``) and
+  the kernel is handed the whole pool and a layer other than 0, with
+  other values in the same slots of the other layers;
+* the stand-in's widths (32 heads x 64, blocks of 16, bf16), where the
+  kernel walks a block in 128-lane chunks and multiplies in bf16;
 * fp8 QuantPool in-kernel dequantization matches the dense fp8 read
-  exactly (both dequantize the same payload/scale pairs);
+  (both read the same payload/scale pairs);
 * the ``default_split_k`` heuristic: serial up to 8 blocks, then
   partitions of <= 8 blocks each, capped at 8 streams;
 * end-to-end: an engine configured with ``attn_impl="flash_interpret"``
@@ -23,10 +28,12 @@ import jax.numpy as jnp
 
 from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
-from mxnet_tpu.quant import rowwise_quantize
 from mxnet_tpu.serve import kvcache
-from mxnet_tpu.serve.flash_decode import (default_split_k,
+from mxnet_tpu.serve.flash_decode import (_lane_chunks, _split_bf16,
+                                          default_split_k,
                                           flash_decode_attention)
+
+LAYER = 2       # of 3: the layer read; layers 0 and 1 hold decoys
 
 
 @pytest.fixture(autouse=True)
@@ -36,14 +43,14 @@ def _fresh_telemetry():
     telemetry.reset_for_tests()
 
 
-def _setup(seed, B, H, HD, BS, nblk_per_req, npool=64):
-    """Paged pools with per-request ragged lengths; returns the dense
-    reference output alongside the paged operands."""
+def _setup(seed, B, H, HD, BS, nblk_per_req, npool=64, dtype=jnp.float32,
+           quant=None):
+    """Paged pools (``make_pools``, filled through ``write_prefill``)
+    with per-request ragged lengths; returns the dense reader's output
+    at ``LAYER`` alongside the paged operands."""
     rng = np.random.RandomState(seed)
     max_blocks = max(nblk_per_req)
-    q = rng.randn(B, H, HD).astype(np.float32)
-    kp = rng.randn(npool, BS, H, HD).astype(np.float32)
-    vp = rng.randn(npool, BS, H, HD).astype(np.float32)
+    q = jnp.asarray(rng.randn(B, H, HD), dtype)
     tables = np.zeros((B, max_blocks), np.int32)
     lengths = np.zeros(B, np.int32)
     free = iter(rng.permutation(np.arange(1, npool)))
@@ -51,18 +58,18 @@ def _setup(seed, B, H, HD, BS, nblk_per_req, npool=64):
         tables[b, :nb] = [next(free) for _ in range(nb)]
         # ragged: last block partially filled (at least one slot)
         lengths[b] = (nb - 1) * BS + int(rng.randint(1, BS + 1))
-    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(tables), jnp.asarray(lengths))
-    ref = np.asarray(kvcache.paged_attention(*args, impl="dense"))
+    kp, vp = kvcache.make_pools(3, npool, BS, H, HD, dtype=dtype, quant=quant)
+    for layer in range(3):
+        for b, nb in enumerate(nblk_per_req):
+            ks, vs = (jnp.asarray(rng.randn(nb * BS, H, HD), dtype)
+                      for _ in range(2))
+            row, full = jnp.asarray(tables[b]), jnp.int32(nb * BS)
+            kp = kvcache.write_prefill(kp, layer, ks, row, full)
+            vp = kvcache.write_prefill(vp, layer, vs, row, full)
+    args = (q, kp, vp, LAYER, jnp.asarray(tables), jnp.asarray(lengths))
+    ref = np.asarray(kvcache.paged_attention(*args, impl="dense")
+                     .astype(jnp.float32))
     return args, ref
-
-
-def _quantize(pool):
-    npool, bs = pool.shape[:2]
-    pay, sc = rowwise_quantize(
-        jnp.asarray(np.asarray(pool).reshape(npool * bs, -1)), "e4m3")
-    return kvcache.QuantPool(pay.reshape(pool.shape),
-                             sc.reshape(npool, bs))
 
 
 @pytest.mark.parametrize("nblk_per_req", [
@@ -74,23 +81,94 @@ def _quantize(pool):
 ])
 @pytest.mark.parametrize("split_k", [None, 1, 2, 4])
 def test_flash_matches_dense(nblk_per_req, split_k):
-    (q, kp, vp, tables, lengths), ref = _setup(
+    args, ref = _setup(
         seed=11 + len(nblk_per_req), B=len(nblk_per_req), H=2, HD=16,
         BS=4, nblk_per_req=nblk_per_req)
     out = np.asarray(flash_decode_attention(
-        q, kp, vp, tables, lengths, split_k=split_k, interpret=True))
+        *args, split_k=split_k, interpret=True))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_flash_reads_the_layer_it_is_given(layer):
+    """The kernel's block map carries the layer: each layer's read
+    matches the dense reader's of that layer, and no two agree."""
+    (q, kp, vp, _, tables, lengths), _ = _setup(
+        seed=2, B=3, H=2, HD=16, BS=4, nblk_per_req=[5, 2, 3])
+    outs = [np.asarray(flash_decode_attention(
+        q, kp, vp, i, tables, lengths, interpret=True)) for i in range(3)]
+    dense = np.asarray(kvcache.paged_attention(
+        q, kp, vp, layer, tables, lengths, impl="dense"))
+    np.testing.assert_allclose(outs[layer], dense, rtol=1e-5, atol=1e-6)
+    for other in set(range(3)) - {layer}:
+        assert np.abs(outs[other] - outs[layer]).max() > 0.1
+
+
+@pytest.mark.parametrize("heads,head_dim,want", [
+    (32, 64, (128, 8)),      # the stand-in: two heads a 128-lane chunk
+    (8, 64, (128, 8)),
+    (8, 128, (128, 8)),      # one head a chunk
+    (4, 128, (128, 4)),      # fewer heads than a sublane tile
+    (16, 32, (128, 8)),
+    (2, 16, (32, 2)),        # the CPU tests' widths: one chunk, one group
+    (2, 256, (512, 2)),      # a head wider than a lane row: one chunk
+    (12, 64, (128, 12)),     # 12 heads do not tile by 8: one group
+])
+def test_lane_chunks(heads, head_dim, want):
+    assert _lane_chunks(heads, head_dim) == want
+    w, r = want
+    assert heads * head_dim % w == 0 and heads % r == 0
+    assert w % head_dim == 0 and r % (w // head_dim) == 0
+
+
+def test_split_bf16_sums_to_the_float32():
+    """Three bf16 pieces carry all 24 mantissa bits of a float32."""
+    x = jnp.asarray(np.random.RandomState(0).rand(8, 16), jnp.float32)
+    pieces = _split_bf16(x)
+    assert pieces.dtype == jnp.bfloat16 and pieces.shape == (24, 16)
+    back = sum(np.asarray(pieces[i * 8:(i + 1) * 8].astype(jnp.float32))
+               for i in range(3))
+    np.testing.assert_array_equal(back, np.asarray(x))
+
+
+@pytest.mark.parametrize("dtype,quant", [("bfloat16", None),
+                                         ("float32", None),
+                                         ("bfloat16", "fp8")])
+def test_flash_at_the_stand_ins_widths(dtype, quant):
+    """32 heads x 64 in blocks of 16: the kernel walks each block in
+    sixteen 128-lane chunks of two heads, in four groups of eight head
+    rows, and (bf16, fp8) multiplies in bf16 with the probabilities in
+    three pieces: the code the chip runs.  Against a float32 softmax of
+    the values the pool holds."""
+    H, HD, BS = 32, 64, 16
+    args, ref = _setup(seed=4, B=3, H=H, HD=HD, BS=BS,
+                       nblk_per_req=[9, 2, 4], npool=24,
+                       dtype=jnp.dtype(dtype), quant=quant)
+    q, kp, vp, layer, tables, lengths = args
+    out = np.asarray(flash_decode_attention(*args, interpret=True)
+                     .astype(jnp.float32))
+    f32 = jnp.float32
+    if quant:
+        wide = [(p.payload.astype(f32) * p.scale[..., None]) for p in (kp, vp)]
+    else:
+        wide = [p.astype(f32) for p in (kp, vp)]
+    exact = np.asarray(kvcache.paged_attention(
+        q.astype(f32), *wide, layer, tables, lengths, impl="scan"))
+    # the output is rounded to the queries' dtype, and to nothing else
+    ulp = 2.0 ** -8 if dtype == "bfloat16" else 2.0 ** -20
+    np.testing.assert_allclose(out, exact, rtol=ulp, atol=ulp)
+    np.testing.assert_allclose(out, ref, rtol=0.02, atol=0.02)
 
 
 def test_flash_long_context_split_k():
     """Long ragged contexts where split-K actually engages, including a
     split that does not divide the block count (trash-padded tail)."""
     nblk = [17, 9, 23]
-    (q, kp, vp, tables, lengths), ref = _setup(
+    args, ref = _setup(
         seed=3, B=3, H=4, HD=8, BS=4, nblk_per_req=nblk, npool=128)
     for sk in (None, 1, 3, 8):
         out = np.asarray(flash_decode_attention(
-            q, kp, vp, tables, lengths, split_k=sk, interpret=True))
+            *args, split_k=sk, interpret=True))
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6,
                                    err_msg=f"split_k={sk}")
 
@@ -100,42 +178,55 @@ def test_flash_fp8_matches_dense_fp8(split_k):
     """In-kernel dequant reads the same payload/scale pairs the dense
     path reads — fp8 flash vs fp8 dense is a tight comparison, and both
     stay near the f32 reference."""
-    (q, kp, vp, tables, lengths), f32_ref = _setup(
-        seed=5, B=3, H=2, HD=16, BS=4, nblk_per_req=[4, 1, 3])
-    qkp, qvp = _quantize(kp), _quantize(vp)
-    dense = np.asarray(kvcache.paged_attention(
-        q, qkp, qvp, tables, lengths, impl="dense"))
+    shape = dict(seed=5, B=3, H=2, HD=16, BS=4, nblk_per_req=[4, 1, 3])
+    _, f32_ref = _setup(**shape)
+    args, dense = _setup(**shape, quant="fp8")      # the same values
+    assert kvcache.is_quantized(args[1])
     flash = np.asarray(flash_decode_attention(
-        q, qkp, qvp, tables, lengths, split_k=split_k, interpret=True))
+        *args, split_k=split_k, interpret=True))
     np.testing.assert_allclose(flash, dense, rtol=1e-5, atol=1e-6)
     assert np.max(np.abs(flash - f32_ref)) < 0.1
 
 
 def test_flash_rejects_mixed_pools():
-    (q, kp, vp, tables, lengths), _ = _setup(
-        seed=9, B=2, H=2, HD=8, BS=4, nblk_per_req=[2, 1])
+    shape = dict(seed=9, B=2, H=2, HD=8, BS=4, nblk_per_req=[2, 1])
+    (q, kp, vp, layer, tables, lengths), _ = _setup(**shape)
+    (_, qkp, _, _, _, _), _ = _setup(**shape, quant="fp8")
     with pytest.raises(MXNetError):
-        flash_decode_attention(q, _quantize(kp), vp, tables, lengths,
+        flash_decode_attention(q, qkp, vp, layer, tables, lengths,
                                interpret=True)
 
 
+def test_flash_rejects_a_pool_of_another_width():
+    """The head geometry comes with the queries; a pool that stores
+    another number of lanes a position is refused by name."""
+    (q, kp, vp, layer, tables, lengths), _ = _setup(
+        seed=9, B=2, H=2, HD=8, BS=4, nblk_per_req=[2, 1])
+    with pytest.raises(MXNetError, match="lanes a position"):
+        flash_decode_attention(q[:, :1], kp, vp, layer, tables, lengths,
+                               interpret=True)
+
+
+@pytest.mark.parametrize("h,hd", [(8, 64), (32, 64), (2, 32)])
 @pytest.mark.parametrize("pool", ["f32", "bf16", "fp8"])
-def test_kernel_lowers_for_tpu(pool):
+def test_kernel_lowers_for_tpu(pool, h, hd):
     """Mosaic must accept the kernel at serving shapes — cross-lowered
     here, no chip needed.  The head-batched dots this kernel once used
     passed every interpret-mode test and could not lower at all, which
     ``attn_impl="auto"`` (-> flash on TPU) turns into an engine that
-    cannot warm up."""
+    cannot warm up.  (2, 32) is a width under one 128-lane row: the
+    one-chunk walk lowers too, so no width is refused."""
     sds = jax.ShapeDtypeStruct
-    b, h, hd, bs, nb, nblk = 16, 8, 64, 16, 256, 128
+    b, bs, nl, nb, nblk = 16, 16, 3, 256, 128
+    dtype = jnp.dtype({"f32": "float32"}.get(pool, "bfloat16"))
     if pool == "fp8":
-        kv = kvcache.QuantPool(sds((nb, bs, h, hd), jnp.float8_e4m3fn),
-                       sds((nb, bs), jnp.float32))
+        kv = kvcache.QuantPool(sds((nl, nb, bs, h * hd), jnp.float8_e4m3fn),
+                               sds((nl, nb, bs), jnp.float32))
     else:
-        kv = sds((nb, bs, h, hd), jnp.dtype(
-            {"f32": "float32", "bf16": "bfloat16"}[pool]))
-    text = jax.jit(flash_decode_attention).trace(
-        sds((b, h, hd), jnp.float32), kv, kv, sds((b, nblk), jnp.int32),
+        kv = sds((nl, nb, bs, h * hd), dtype)
+    text = jax.jit(lambda q, k, v, t, n: flash_decode_attention(
+        q, k, v, 1, t, n)).trace(
+        sds((b, h, hd), dtype), kv, kv, sds((b, nblk), jnp.int32),
         sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
 

@@ -1,0 +1,149 @@
+"""The paged K/V pools cross a serving program untouched.
+
+``decode``@32 and ``prefill_chunk``@256 are compiled at the stand-in's
+widths (``benchmark/configs/nope-lm-2048x24.json``: 24 layers x 2048,
+32 heads x 64, FFN 8192, vocabulary 50272, 768 blocks of 16, bf16) for a
+DESCRIBED TPU v5e: shapes only, no chip, nothing runs, no time is
+implied.  What the compiled program says is the counter of ISSUE 29's
+mechanism, which is always on: the donated pools are updated in place,
+the program keeps next to no temporaries, no instruction copies, slices
+or re-lays-out a pool or a layer of one, and the kernel reads the pool
+itself once a layer.  Until that PR the pools were stored
+``[..., heads, head_dim]`` and read through ``pool[layer]``: the decode
+program re-laid both pools out on entry, copied them back, and
+materialised 48 slices (7.38 GB of temporaries).
+"""
+import os
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+D_MODEL, HEADS, FFN, VOCAB = 2048, 32, 8192, 50272
+#: plain bf16 pools at the configuration's real depth, so the program's
+#: sizes are the cells' own; fp8 pools at 4 layers (no cell runs them:
+#: the check is of the program's structure, and a compile is a minute)
+DEPTH = {None: 24, "fp8": 4}
+CHIP_BYTES = 16e9
+ENGINE = dict(block_size=16, num_blocks=768, max_batch=32, max_queue=4096,
+              max_prompt_len=1024, max_seq_len=2048, prefill_chunk=256,
+              prefix_cache=False, speculate=False)
+TEMPORARIES_LIMIT = 0.5e9
+
+
+def _param_shapes(layers):
+    d, f, v = D_MODEL, FFN, VOCAB
+    shapes = {"embed_weight": (v, d), "final_ln_gamma": (d,),
+              "final_ln_beta": (d,), "lm_head_weight": (v, d),
+              "lm_head_bias": (v,)}
+    for i in range(layers):
+        p = f"layer{i}_"
+        for nm in ("q", "k", "v", "proj"):
+            shapes[p + nm + "_weight"] = (d, d)
+            shapes[p + nm + "_bias"] = (d,)
+        shapes.update({p + "ffn1_weight": (f, d), p + "ffn1_bias": (f,),
+                       p + "ffn2_weight": (d, f), p + "ffn2_bias": (d,)})
+        for ln in ("ln1", "ln2"):
+            shapes[p + ln + "_gamma"] = (d,)
+            shapes[p + ln + "_beta"] = (d,)
+    return shapes
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler here, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _shape_engine(kv_quant):
+    """The engine built from SHAPES (no 2.8 GB of weights, no 2.4 GB of
+    pools are made): only its program builders and avals are used.  The
+    pools' shapes are ``kvcache.make_pools``'s own."""
+    import mxnet_tpu.serve.engine as eng_mod
+    from mxnet_tpu.serve import Engine, EngineConfig
+
+    sds = jax.ShapeDtypeStruct
+    real_asarray, real_pools = jnp.asarray, eng_mod.kvcache.make_pools
+    try:
+        eng_mod.jnp.asarray = lambda v, *a, **k: (
+            v if isinstance(v, sds) else real_asarray(v, *a, **k))
+        eng_mod.kvcache.make_pools = lambda *a, **k: jax.eval_shape(
+            lambda: real_pools(*a, **k))
+        return Engine(
+            {k: sds(s, jnp.bfloat16)
+             for k, s in _param_shapes(DEPTH[kv_quant]).items()},
+            EngineConfig(heads=HEADS, dtype=jnp.bfloat16, attn_impl="flash",
+                         kv_quant=kv_quant, **ENGINE))
+    finally:
+        eng_mod.jnp.asarray = real_asarray
+        eng_mod.kvcache.make_pools = real_pools
+
+
+def _counts(shape):
+    n = 1
+    for s in shape:
+        n *= s
+    return n
+
+
+@pytest.mark.parametrize("kv_quant", [None, "fp8"])
+@pytest.mark.parametrize("kind,bucket", [("decode", 32),
+                                         ("prefill_chunk", 256)])
+def test_program_neither_copies_nor_slices_a_pool(topo, kind, bucket,
+                                                  kv_quant):
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.serve import kvcache
+
+    eng = _shape_engine(kv_quant)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    make = {"decode": eng._make_decode_fn,
+            "prefill_chunk": eng._make_chunk_prefill_fn}[kind]
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._avals(kind, bucket))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        comp = jax.jit(make(bucket), donate_argnums=(0, 1)).trace(
+            *avals).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+    pool = eng._pool_aval()
+    m = comp.memory_analysis()
+    print(kind, bucket, kv_quant, "GB: arguments %.2f aliased %.2f "
+          "temporaries %.3f" % (m.argument_size_in_bytes / 1e9,
+                                m.alias_size_in_bytes / 1e9,
+                                m.temp_size_in_bytes / 1e9))
+    # fits one chip beside nothing else (how ``num_blocks`` is chosen) ...
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes) < CHIP_BYTES
+    # ... both pools updated in place ...
+    assert m.alias_size_in_bytes >= kvcache.pool_nbytes(pool, pool)
+    # ... beside next to nothing (a copy of one plain pool is 1.2 GB)
+    assert m.temp_size_in_bytes < TEMPORARIES_LIMIT, m.temp_size_in_bytes
+
+    # no instruction whose result is as large as a pool or a layer of one
+    # moves data without computing anything (the payload: an fp8 pool's
+    # scales are 1.2 MB, which XLA may stage in faster memory)
+    payload = pool.payload if kvcache.is_quantized(pool) else pool
+    sizes = {_counts(payload.shape), _counts(payload.shape[1:])}
+    moved = []
+    for line in comp.as_text().splitlines():
+        hit = re.search(r"= \(?(\w+)\[([\d,]+)\]\S* (copy|slice|bitcast|"
+                        r"dynamic-slice|transpose)\(", line)
+        if hit and _counts(int(d) for d in hit.group(2).split(",")) in sizes:
+            moved.append(line.strip()[:200])
+    assert not moved, moved[:3]
+
+    if kind == "decode":            # the kernel, on the pool, once a layer
+        calls = [ln for ln in comp.as_text().splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln
+                 and "mxtpu_flash_decode" in ln]
+        assert len(calls) == DEPTH[kv_quant], len(calls)

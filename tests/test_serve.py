@@ -146,27 +146,47 @@ def test_engine_defrag_bitwise_stable():
 # Paged attention: bitwise vs dense, allclose vs reference
 # ---------------------------------------------------------------------------
 
-def _paged_setup(seed=7, B=3, HD=8, BS=4, NBLK=5, NPOOL=32):
+_LAYER = 1      # the layer the readers are asked for; 0 and 2 hold decoys
+
+
+def _paged_setup(seed=7, B=3, HD=8, BS=4, NBLK=5, NPOOL=32, quant=None):
+    """Three-layer pools from ``make_pools``, filled through
+    ``write_prefill``: layer ``_LAYER`` holds ``kd``/``vd`` behind the
+    tables, the other layers hold other values in the same slots, so a
+    reader that ignores the layer it is given fails every comparison."""
     rng = np.random.RandomState(seed)
     q = rng.randn(B, H, HD).astype(np.float32)
     kd = rng.randn(B, NBLK * BS, H, HD).astype(np.float32)
     vd = rng.randn(B, NBLK * BS, H, HD).astype(np.float32)
     lengths = np.array([18, 5, 11], np.int32)
     perm = rng.permutation(np.arange(1, NPOOL))[:B * NBLK].reshape(B, NBLK)
-    kp = np.zeros((NPOOL, BS, H, HD), np.float32)
-    vp = np.zeros_like(kp)
-    for b in range(B):
-        for j in range(NBLK):
-            kp[perm[b, j]] = kd[b, j * BS:(j + 1) * BS]
-            vp[perm[b, j]] = vd[b, j * BS:(j + 1) * BS]
-    return q, kd, vd, kp, vp, perm.astype(np.int32), lengths, BS
+    perm = perm.astype(np.int32)
+    kp, vp = kvcache.make_pools(3, NPOOL, BS, H, HD, quant=quant)
+    full = jnp.int32(NBLK * BS)
+    for layer in range(3):
+        for b in range(B):
+            ks, vs = ((kd[b], vd[b]) if layer == _LAYER else
+                      (rng.randn(*kd[b].shape).astype(np.float32),
+                       rng.randn(*vd[b].shape).astype(np.float32)))
+            kp = kvcache.write_prefill(kp, layer, jnp.asarray(ks),
+                                       jnp.asarray(perm[b]), full)
+            vp = kvcache.write_prefill(vp, layer, jnp.asarray(vs),
+                                       jnp.asarray(perm[b]), full)
+    return q, kd, vd, kp, vp, perm, lengths, BS
+
+
+def _softmax_reference(qrow, k, v):
+    s = np.einsum("hd,lhd->hl", qrow, k) / np.sqrt(qrow.shape[-1])
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("hl,lhd->hd", p, v)
 
 
 def test_paged_vs_dense_bitwise():
     q, kd, vd, kp, vp, tables, lengths, BS = _paged_setup()
     paged = np.asarray(kvcache.paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(lengths)))
+        jnp.asarray(q), kp, vp, _LAYER, jnp.asarray(tables),
+        jnp.asarray(lengths)))
     dense = np.asarray(kvcache.dense_attention(
         jnp.asarray(q), jnp.asarray(kd), jnp.asarray(vd),
         jnp.asarray(lengths), block_size=BS))
@@ -176,27 +196,115 @@ def test_paged_vs_dense_bitwise():
 def test_paged_attention_matches_softmax_reference():
     q, kd, vd, kp, vp, tables, lengths, BS = _paged_setup()
     paged = np.asarray(kvcache.paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(lengths)))
+        jnp.asarray(q), kp, vp, _LAYER, jnp.asarray(tables),
+        jnp.asarray(lengths)))
     for b in range(q.shape[0]):
         L = int(lengths[b])
-        s = np.einsum("hd,lhd->hl", q[b], kd[b, :L]) / np.sqrt(q.shape[-1])
-        p = np.exp(s - s.max(-1, keepdims=True))
-        p /= p.sum(-1, keepdims=True)
-        ref = np.einsum("hl,lhd->hd", p, vd[b, :L])
+        ref = _softmax_reference(q[b], kd[b, :L], vd[b, :L])
         np.testing.assert_allclose(paged[b], ref, rtol=1e-5, atol=1e-6)
 
 
-def test_write_prefill_pads_to_trash():
-    pool = jnp.zeros((1, 6, 4, H, 2))            # 1 layer, BS=4
+def _read(reader, q, kp, vp, layer, tables, lengths):
+    """One decode position a row through any reader: ``[B, H, hd]``."""
+    q, tables, lengths = (jnp.asarray(x) for x in (q, tables, lengths))
+    if reader == "verify":          # a window of one, lengths before it
+        return np.asarray(kvcache.paged_verify_attention(
+            q[:, None], kp, vp, layer, tables, lengths - 1))[:, 0]
+    if reader == "prefill":         # a chunk of one, row by row
+        return np.stack([np.asarray(kvcache.paged_prefill_attention(
+            q[b][None], kp, vp, layer, tables[b], lengths[b] - 1,
+            lengths[b]))[0] for b in range(q.shape[0])])
+    return np.asarray(kvcache.paged_attention(
+        q, kp, vp, layer, tables, lengths, impl=reader))
+
+
+_READERS = ("scan", "dense", "flash_interpret", "prefill", "verify")
+
+
+@pytest.mark.parametrize("quant", [None, "fp8"])
+@pytest.mark.parametrize("reader", _READERS)
+def test_every_reader_reads_the_layer_it_is_given(reader, quant):
+    """The pools are handed over whole with the layer beside them: a
+    reader that ignored the layer would return layer 0's decoys."""
+    q, kd, vd, kp, vp, tables, lengths, BS = _paged_setup(quant=quant)
+    got = _read(reader, q, kp, vp, _LAYER, tables, lengths)
+    tol = dict(rtol=1e-5, atol=1e-6) if quant is None else dict(atol=0.05)
+    for b in range(q.shape[0]):
+        L = int(lengths[b])
+        np.testing.assert_allclose(
+            got[b], _softmax_reference(q[b], kd[b, :L], vd[b, :L]), **tol)
+    for other in (0, 2):
+        decoy = _read(reader, q, kp, vp, other, tables, lengths)
+        assert np.abs(decoy - got).max() > 0.1
+
+
+@pytest.mark.parametrize("quant", [None, "fp8"])
+@pytest.mark.parametrize("reader", ("scan", "dense", "flash_interpret"))
+def test_compact_and_scrub_between_two_decode_steps(reader, quant):
+    """Defragmentation and the NaN scrub act on the stored form without
+    knowing it (axis 1 is the slot, whatever lies behind): a decode
+    step, then ``compact_pool`` + ``scrub_blocks`` of the vacated slots,
+    then another decode step reads bit for bit what an undisturbed pool
+    gives."""
+    q, kd, vd, kp, vp, tables, lengths, BS = _paged_setup(quant=quant)
+    rng = np.random.RandomState(23)
+    B = q.shape[0]
+
+    def decode_step(kp, vp, tables, lengths):
+        """Append one position a row to every layer, read ``_LAYER``."""
+        slots = jnp.asarray(tables[np.arange(B), lengths // BS])
+        offs = jnp.asarray(lengths % BS)
+        active = jnp.ones((B,), bool)
+        for layer in range(3):
+            k, v = (jnp.asarray(rng.randn(B, H, q.shape[-1])
+                                .astype(np.float32)) for _ in range(2))
+            kp = kvcache.write_decode(kp, layer, k, slots, offs, active)
+            vp = kvcache.write_decode(vp, layer, v, slots, offs, active)
+        out = _read(reader, q, kp, vp, _LAYER, tables, lengths + 1)
+        return kp, vp, out
+
+    kp, vp, first = decode_step(kp, vp, tables, lengths)
+    state = rng.get_state()
+    _, _, want = decode_step(kp, vp, tables, lengths + 1)
+
+    # move every live slot to the low end, as BlockAllocator.defrag would
+    live = sorted(int(x) for x in np.unique(tables))
+    mapping = {old: new for new, old in enumerate(live, start=1)
+               if old != new}
+    vacated = sorted(set(mapping) - set(mapping.values()))
+    assert mapping and vacated
+    kp, vp = (kvcache.scrub_blocks(kvcache.compact_pool(p, mapping), vacated)
+              for p in (kp, vp))
+    moved = np.vectorize(lambda x: mapping.get(int(x), int(x)))(tables)
+    for pool in (kp, vp):
+        for leaf in jax.tree_util.tree_leaves(pool):
+            assert not np.asarray(leaf[:, np.asarray(vacated)]
+                                  .astype(jnp.float32)).any()
+    np.testing.assert_array_equal(
+        _read(reader, q, kp, vp, _LAYER, moved, lengths + 1), first)
+    rng.set_state(state)
+    _, _, got = decode_step(kp, vp, moved.astype(np.int32), lengths + 1)
+    np.testing.assert_array_equal(got, want)
+
+
+def _rows(states):
+    """States as a pool stores them: the heads side by side."""
+    return np.asarray(states).reshape(states.shape[:-2] + (-1,))
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+def test_write_prefill_pads_to_trash(layer):
+    pool, _ = kvcache.make_pools(2, 6, 4, H, 2)  # BS=4
+    assert pool.shape == (2, 6, 4, H * 2)        # the stored form
     states = jnp.arange(8 * H * 2, dtype=jnp.float32).reshape(8, H, 2) + 1
     table = jnp.asarray([2, 5, 0, 0], jnp.int32)
-    out = np.asarray(kvcache.write_prefill(pool, 0, states, table,
+    out = np.asarray(kvcache.write_prefill(pool, layer, states, table,
                                            jnp.int32(6)))
-    np.testing.assert_array_equal(out[0, 2], np.asarray(states[:4]))
-    np.testing.assert_array_equal(out[0, 5, :2], np.asarray(states[4:6]))
-    assert not out[0, 5, 2:].any()               # padded tail never lands
-    assert not out[0, [1, 3, 4]].any()           # untouched slots stay zero
+    np.testing.assert_array_equal(out[layer, 2], _rows(states[:4]))
+    np.testing.assert_array_equal(out[layer, 5, :2], _rows(states[4:6]))
+    assert not out[layer, 5, 2:].any()           # padded tail never lands
+    assert not out[layer, [1, 3, 4]].any()       # untouched slots stay zero
+    assert not out[1 - layer, 1:].any()          # nor the other layer
 
 
 # ---------------------------------------------------------------------------
@@ -669,18 +777,18 @@ def test_fp8_kv_logit_error_bound():
     from mxnet_tpu.quant import rowwise_quantize
     q, kd, vd, kp, vp, tables, lengths, BS = _paged_setup()
     f32 = np.asarray(kvcache.paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(lengths), impl="dense"))
-
-    def quantize(pool):
-        npool, bs = pool.shape[:2]
-        pay, sc = rowwise_quantize(
-            jnp.asarray(pool.reshape(npool * bs, -1)), "e4m3")
-        return kvcache.QuantPool(pay.reshape(pool.shape),
-                                 sc.reshape(npool, bs))
-
+        jnp.asarray(q), kp, vp, _LAYER, jnp.asarray(tables),
+        jnp.asarray(lengths), impl="dense"))
+    # the same values through the quantizing writer: one e4m3 payload
+    # row and one scale a position
+    _, _, _, kq, vq, _, _, _ = _paged_setup(quant="fp8")
+    assert kq.payload.dtype == jnp.float8_e4m3fn
+    np.testing.assert_array_equal(
+        np.asarray(kq.payload[_LAYER, tables[0, 0]]),
+        np.asarray(rowwise_quantize(
+            jnp.asarray(kd[0, :BS].reshape(BS, -1)), "e4m3")[0]))
     fp8 = np.asarray(kvcache.paged_attention(
-        jnp.asarray(q), quantize(kp), quantize(vp), jnp.asarray(tables),
+        jnp.asarray(q), kq, vq, _LAYER, jnp.asarray(tables),
         jnp.asarray(lengths), impl="dense"))
     assert 0 < np.max(np.abs(fp8 - f32)) < 0.05
 
@@ -703,8 +811,8 @@ def test_attn_impl_parity():
     dense gather and the interpret-mode flash kernel match the
     reference block scan on the same paged pools."""
     q, kd, vd, kp, vp, tables, lengths, BS = _paged_setup()
-    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(tables), jnp.asarray(lengths))
+    args = (jnp.asarray(q), kp, vp, _LAYER, jnp.asarray(tables),
+            jnp.asarray(lengths))
     scan = np.asarray(kvcache.paged_attention(*args, impl="scan"))
     dense = np.asarray(kvcache.paged_attention(*args, impl="dense"))
     flash = np.asarray(kvcache.paged_attention(*args,
@@ -780,7 +888,7 @@ def test_write_spec_and_scrub_positions_roundtrip():
     zeroes exactly the rejected tail and leaves accepted neighbours —
     including entries in the SAME block — untouched."""
     BS, HD = 4, 2
-    pool = jnp.zeros((1, 6, BS, H, HD))
+    pool, _ = kvcache.make_pools(1, 6, BS, H, HD)
     rng = np.random.RandomState(3)
     states = jnp.asarray(rng.randn(2, 3, H, HD).astype(np.float32))
     # row 0 writes block 2 offsets 1..3; row 1 straddles blocks 4 -> 5
@@ -788,11 +896,11 @@ def test_write_spec_and_scrub_positions_roundtrip():
     offs = jnp.asarray([[1, 2, 3], [2, 3, 0]], jnp.int32)
     out = kvcache.write_spec(pool, 0, states, slots, offs)
     np.testing.assert_array_equal(np.asarray(out[0, 2, 1:4]),
-                                  np.asarray(states[0]))
+                                  _rows(states[0]))
     np.testing.assert_array_equal(np.asarray(out[0, 4, 2:4]),
-                                  np.asarray(states[1, :2]))
+                                  _rows(states[1, :2]))
     np.testing.assert_array_equal(np.asarray(out[0, 5, 0]),
-                                  np.asarray(states[1, 2]))
+                                  _rows(states[1, 2]))
     # scrub row 0's last two positions and row 1's last one (kept
     # positions redirect to the trash block, the engine's convention)
     sslots = jnp.asarray([[TRASH_BLOCK, 2, 2],
@@ -801,9 +909,9 @@ def test_write_spec_and_scrub_positions_roundtrip():
     assert not np.asarray(scrubbed[0, 2, 2:4]).any()   # rejected tail gone
     assert not np.asarray(scrubbed[0, 5, 0]).any()
     np.testing.assert_array_equal(                      # survivors intact
-        np.asarray(scrubbed[0, 2, 1]), np.asarray(states[0, 0]))
+        np.asarray(scrubbed[0, 2, 1]), _rows(states[0, 0]))
     np.testing.assert_array_equal(
-        np.asarray(scrubbed[0, 4, 2:4]), np.asarray(states[1, :2]))
+        np.asarray(scrubbed[0, 4, 2:4]), _rows(states[1, :2]))
 
 
 def test_write_spec_fp8_matches_decode_write():
@@ -811,12 +919,8 @@ def test_write_spec_fp8_matches_decode_write():
     rowwise_quantize), so a C-wide speculative write of one position is
     byte-equal to the 1-wide decode write of the same state — the
     quantization invariant greedy byte-identity rides on."""
-    from mxnet_tpu import quant as quantmod
     BS, HD = 4, 2
-    fp8 = quantmod._FP8_DTYPES[kvcache.KV_FP8_FORMAT]
-    pool = kvcache.QuantPool(
-        payload=jnp.zeros((1, 6, BS, H, HD), fp8),
-        scale=jnp.zeros((1, 6, BS), jnp.float32))
+    pool, _ = kvcache.make_pools(1, 6, BS, H, HD, quant="fp8")
     rng = np.random.RandomState(5)
     st = jnp.asarray(rng.randn(1, 3, H, HD).astype(np.float32))
     slots = jnp.asarray([[2, 2, 2]], jnp.int32)
@@ -848,10 +952,10 @@ def test_paged_verify_attention_c1_matches_decode():
     tests/test_speculate.py)."""
     q, kd, vd, kp, vp, tables, lengths, BS = _paged_setup()
     ref = np.asarray(kvcache.paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(lengths), impl="dense"))
+        jnp.asarray(q), kp, vp, _LAYER, jnp.asarray(tables),
+        jnp.asarray(lengths), impl="dense"))
     ver = np.asarray(kvcache.paged_verify_attention(
-        jnp.asarray(q)[:, None], jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q)[:, None], kp, vp, _LAYER,
         jnp.asarray(tables), jnp.asarray(lengths) - 1))
     np.testing.assert_allclose(ver[:, 0], ref, rtol=1e-6, atol=1e-6)
 
@@ -866,15 +970,11 @@ def test_paged_verify_attention_matches_reference():
     qw = rng.randn(q.shape[0], C, H, q.shape[-1]).astype(np.float32)
     base = lengths - C                 # cache holds the window's K/V too
     ver = np.asarray(kvcache.paged_verify_attention(
-        jnp.asarray(qw), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(qw), kp, vp, _LAYER,
         jnp.asarray(tables), jnp.asarray(base)))
     for b in range(q.shape[0]):
         for c in range(C):
             L = int(base[b]) + c + 1
-            s = np.einsum("hd,lhd->hl", qw[b, c], kd[b, :L])
-            s /= np.sqrt(q.shape[-1])
-            p = np.exp(s - s.max(-1, keepdims=True))
-            p /= p.sum(-1, keepdims=True)
-            ref = np.einsum("hl,lhd->hd", p, vd[b, :L])
+            ref = _softmax_reference(qw[b, c], kd[b, :L], vd[b, :L])
             np.testing.assert_allclose(ver[b, c], ref, rtol=1e-5,
                                        atol=1e-6)

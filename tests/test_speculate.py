@@ -319,7 +319,7 @@ def test_spec_rejected_tail_scrubbed_and_tables_clean():
         if req.done():
             break
         assert req.cached == len(req.seed_tokens) - 1
-        kp = np.asarray(eng.kpool)            # [L, blocks, bsz, H, hd]
+        kp = np.asarray(eng.kpool)            # [L, blocks, bsz, H*hd]
         for pos_i, blk in enumerate(req.blocks):
             for off in range(bsz):
                 if pos_i * bsz + off >= req.cached:

@@ -16,7 +16,8 @@ anywhere.  It prints what ``PERF.md`` section 5 is written from:
   trace's clock);
 * for every engine program (``jit_fn_<kind>``) its runs, and for its
   median run the device ms by ``jax.named_scope`` (``kv_write``,
-  ``pool_read``, ``attn``, ``ffn``, ...), from the ``XLA Ops`` events'
+  ``pool_read``: the XLA readers' gather of a table's blocks, ``attn``,
+  ``ffn``, ...), from the ``XLA Ops`` events'
   ``tf_op`` stat (the HLO ``op_name``), with what no scope claims broken
   down by instruction;
 * the span ring's medians beside the device's, so a span that stopped
@@ -164,8 +165,14 @@ def by_scope(space, lo: int, hi: int) -> None:
                 continue
             op = op_name(meta).split(";")[0]    # a merged op: its first
             # inside a scope: by the rest of the op_name; outside: by what
-            # the instruction is and what it is for (``kpool:``: a
-            # parameter's relayout)
+            # the instruction is and what it is for.  A ``copy`` named
+            # ``kpool:`` / ``vpool:`` here is a whole K/V pool re-laid-out
+            # on entry or copied back on exit (7-12 ms each at the
+            # stand-in's 1.2 GB until ISSUE 29): the pools' stored form
+            # (``kvcache.make_pools``) and the layout some instruction
+            # wants no longer agree, or a reader took ``pool[layer]``
+            # again.  tests/test_pool_in_place.py reads the same from the
+            # compiled program without a chip.
             scopes[scope_of(op) or "(no scope)"][
                 op.split("/", 2)[-1].rstrip(":") if scope_of(op)
                 else f"{name} {op}".strip()] += d
