@@ -796,9 +796,11 @@ class Engine:
                 pools[1] = kvcache.write_decode(pools[1], i, v, slots,
                                                 offsets, active)
                 return kvcache.paged_attention(
-                    q, pools[0], pools[1], i, tables, lengths + 1,
-                    impl=impl)
+                    q, pools[0], pools[1], i, tables, attended, impl=impl)
 
+            # a row past ``active`` attends nothing (its output is thrown
+            # away): told so, the flash kernel reads no block for it
+            attended = jnp.where(active, lengths + 1, 0)
             logits = transformer_lm_decode(params, tokens, heads=heads,
                                            attend=attend)
             with jax.named_scope("sample"):
@@ -1585,7 +1587,7 @@ class Engine:
         bb = cc.bucket_for(len(active), self.decode_buckets)
         self._ensure_program("decode", bb)
         with telemetry.span("serve.decode", step=self.step_idx, bucket=bb,
-                            active=len(active)):
+                            active=len(active)) as decode_span:
             with telemetry.span("serve.build"):
                 bsz = self.alloc.block_size
                 tokens = np.zeros((bb,), np.int32)
@@ -1614,6 +1616,13 @@ class Engine:
                     where = (lengths, slots)
                 else:
                     where = (tables, lengths, slots, offsets, active_m)
+                    # what the attention reads (``lengths + 1`` positions
+                    # of each active row) against what its tables could
+                    # hold
+                    decode_span.annotate(
+                        live_blocks=int(np.sum(
+                            (lengths // bsz + 1)[:len(active)])),
+                        table_blocks=bb * self.max_blocks)
             t0 = time.monotonic()
             with telemetry.span("serve.dispatch", kind="decode", bucket=bb):
                 toks, oks = self._run("decode", bb, tokens, *where, keys,

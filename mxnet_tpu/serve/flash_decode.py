@@ -8,30 +8,39 @@ column, which XLA schedules as independent HLOs; this kernel is the
 serving twin of the r8 fused-update kernel: the whole per-request scan
 becomes **one fused Pallas program** that
 
-* prefetches the block *tables* as scalars, so the grid's index map
-  streams each table-addressed KV block from HBM into VMEM exactly once
-  (the gather indirection compiles into the block pipeline itself).  The
-  operand is the WHOLE pool in its stored form, ``[num_layers,
+* prefetches the block *tables*, the rows' *lengths* and the layer as
+  scalars and takes the WHOLE pools as they are stored, ``[num_layers,
   num_blocks, block_size, heads * head_dim]`` (``kvcache.make_pools``),
-  and the block map is ``(layer, tables[b, j], 0, 0)``: no layer's slice
-  is taken outside the kernel, and a ``[block_size, heads * head_dim]``
-  block fills every one of the 128 lanes (at 32 heads x 64 it is one
-  bf16 sublane tile by 16 lane rows), which is the layout XLA gives the
-  buffer anyway, so the pool is never re-laid-out on the kernel's
-  behalf;
-* runs **split-K across block partitions** for long contexts: the grid
-  is ``(batch, splits, blocks_per_split)`` and each split accumulates an
-  independent online-softmax partial ``(acc, m, l)``, so a 32k-token
-  context becomes ``splits`` concurrent streams instead of one long
-  serial scan.  Partials combine outside the kernel in one cheap f32
-  pass (``exp(m_s - m*)`` reweighting — the standard flash-decoding
-  reduction).  A table column wholly past its row's length folds
-  nothing in and is skipped;
-* dequantizes **fp8 pools in-kernel**: a :class:`~.kvcache.QuantPool`
-  ships its e4m3 payload and per-position f32 scales as separate block
-  streams, so the HBM traffic is the 1-byte payload, not a pre-widened
-  f32 copy.  One scale a position: the scores take K's, the
-  probabilities V's.
+  left in HBM (``memory_space=pl.ANY``): no layer's slice is taken
+  outside the kernel and the pool is never re-laid-out on its behalf.
+  A grid step owns one split of one row and **walks that row's live
+  blocks in a loop of its own**: ``cdiv(length, block_size)`` of them,
+  not the table's width (a table is as wide as ``max_seq_len``; at the
+  benchmark's serving shapes 84-99 % of its columns hold nothing).  An
+  iteration takes ``_FOLD`` blocks: blocks ``tables[b, j
+  ..]`` of the layer are copied into one of two VMEM buffers
+  (``pltpu.make_async_copy``) while the ones before them are folded, so
+  a live block crosses HBM -> VMEM once (the row's last up to ``_FOLD``
+  times, to fill the last iteration with something finite) and a dead
+  column, or a pool block no live prefix lists, is never read.  A
+  ``[block_size, heads * head_dim]`` block fills every one of the 128
+  lanes (at 32 heads x 64 it is one bf16 sublane tile by 16 lane rows);
+* runs **split-K across block partitions**: the grid is ``(batch,
+  splits)`` and split ``s`` folds table columns ``s * bps .. (s + 1) *
+  bps`` (those of them that are live) into an independent
+  online-softmax partial ``(acc, m, l)``; a split with no live column
+  writes ``(0, NEG_INF, 0)`` and costs a grid step's overhead.  Partials
+  combine outside the kernel in one cheap f32 pass (``exp(m_s - m*)``
+  reweighting — the standard flash-decoding reduction).  What the
+  splits buy is in :func:`default_split_k`;
+* dequantizes **fp8 pools in-kernel**: a :class:`~.kvcache.QuantPool`'s
+  e4m3 payload is copied like any block, so the HBM traffic is the
+  1-byte payload, not a pre-widened f32 copy.  One scale a position:
+  the scores take K's, the probabilities V's.  The scales of a row's
+  table columns arrive with its query, gathered by XLA (``[batch,
+  columns, block_size]`` floats a call): Mosaic copies whole 128-lane
+  rows out of HBM, and a block's scales are ``block_size`` lanes of
+  ``[num_layers, num_blocks, block_size]``.
 
 Heads lie side by side along the lanes, so a contraction per head is
 block-diagonal: head ``h`` owns lanes ``h*hd .. (h+1)*hd``.  Both run on
@@ -71,12 +80,33 @@ __all__ = ["flash_decode_attention", "default_split_k"]
 
 
 def default_split_k(nblk: int) -> int:
-    """Split-K heuristic: short contexts stay single-stream (no combine
-    overhead); long contexts split so no partition scans more than 8
-    blocks serially."""
+    """Split-K heuristic: tables of up to 8 columns stay one partition
+    (no combine); wider ones split into ``cdiv(columns, 8)`` partitions,
+    at most 8.
+
+    What a split buys depends on the chip.  Every grid dimension is
+    ``arbitrary`` and a v5e has one TensorCore, so there the splits of a
+    row run one after another: nothing runs side by side, each live
+    split pays a grid step's set-up, its block-diagonal query and the
+    un-hidden copy of its first block, and a split with no live column
+    costs a grid step.  They are kept because the partials are what a
+    second core needs to take half of a long row (the split dimension
+    is the one to declare ``parallel`` on a two-core chip: one row of
+    32k tokens is otherwise one core's serial chain), and they bound a
+    partial's chain of ``exp(m - m_new)`` rescales to ``bps`` blocks."""
     if nblk <= 8:
         return 1
     return min(8, -(-nblk // 8))
+
+
+# Blocks a loop iteration fetches and folds: one ``[_FOLD * BS, 128]``
+# operand a contraction and one max / sum / rescale for them all, which
+# is what a block costs (two MXU round trips, whatever its size).  On a
+# v5e, bf16 ``[16, 2048]`` blocks, ms a call at 1 / 2 / 4 / 8: 32 short
+# rows 0.435 / 0.31 / 0.26 / 0.27, two live rows of 32 0.079 / 0.08 /
+# 0.073 / 0.075, six rows that fill their tables 0.417 / 0.282 / 0.221 /
+# 0.220 (PERF.md section 6, PR 32).
+_FOLD = 4
 
 
 def _lane_chunks(heads: int, head_dim: int):
@@ -107,20 +137,27 @@ def _split_bf16(x):
     return jnp.concatenate([x, r1, r2], axis=0).astype(bf16)
 
 
-def _decode_kernel(*refs, bps: int, block_size: int, heads: int,
-                   head_dim: int, quantized: bool, scale: np.float32):
-    """One grid step: fold logical block ``j = s*bps + p`` of request
-    ``b`` into split ``s``'s online-softmax partial.
+def _decode_kernel(*refs, bps: int, nblk: int, fold: int, block_size: int,
+                   heads: int, head_dim: int, quantized: bool,
+                   scale: np.float32):
+    """One grid step: split ``s`` of request ``b``.  Walks the row's LIVE
+    blocks ``s*bps .. min((s+1)*bps, cdiv(length, BS))``, ``fold`` of
+    them an iteration, bringing blocks ``tables[b, j]`` of the layer from
+    the pool in HBM into one of two VMEM buffers while the ones before
+    them are folded into the split's online-softmax partial.
 
     Ref layout (scalar-prefetch args first, then inputs, outputs,
     scratch): ``tables, lengths, layer, q, k, v[, kscale, vscale], out,
-    m, l, qm, acc`` (the layer is the block maps' alone).
+    m, l, qm, acc, kbuf, vbuf, sems`` (``k`` and ``v`` are the whole
+    pools, in HBM; the scales are those of the row's table columns,
+    ``[columns / fold, fold * BS]``).
     """
-    (tables_ref, lengths_ref, _, q_ref, k_ref, v_ref, *scale_refs,
-     out_ref, m_ref, l_ref, qm_ref, acc_ref) = refs
+    (tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm, *scale_refs,
+     out_ref, m_ref, l_ref, qm_ref, acc_ref, kbuf, vbuf, sems) = refs
     kscale_ref, vscale_ref = scale_refs if quantized else (None, None)
 
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
     width = heads * head_dim
@@ -135,11 +172,13 @@ def _decode_kernel(*refs, bps: int, block_size: int, heads: int,
     def lanes(c):
         return slice(c * w, (c + 1) * w)
 
-    def operand(ref, c):
-        """Lane chunk ``c`` of a K or V block in the multiplying dtype
-        (fp8 widens through f32: Mosaic has no fp8 -> bf16 cast)."""
-        x = ref[:, lanes(c)]
-        return (x.astype(f32) if quantized else x).astype(cd)
+    def operand(blocks, c):
+        """Lane chunk ``c`` of an iteration's K or V blocks, ``[fold * BS,
+        W]`` in the multiplying dtype (fp8 widens through f32: Mosaic
+        has no fp8 -> bf16 cast)."""
+        x = blocks[:, :, lanes(c)]
+        x = (x.astype(f32) if quantized else x).astype(cd)
+        return x.reshape(fold * block_size, w)
 
     def group(c):
         """The group of head rows that chunk ``c``'s heads sit in."""
@@ -157,12 +196,101 @@ def _decode_kernel(*refs, bps: int, block_size: int, heads: int,
         return (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
 
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    first = pl.program_id(1) * bps
+    # the split's live blocks: none of a row shorter than the split's
+    # first position, so a table as wide as max_seq_len costs a row only
+    # what it holds
+    length = lengths_ref[b]
+    live = jnp.minimum(pl.cdiv(length, block_size), nblk)
+    limit = jnp.minimum(length, live * block_size)
+    count = jnp.minimum(first + bps, live) - first
+    trips = pl.cdiv(count, fold)
 
-    @pl.when(p == 0)
-    def _init():  # fresh partial per (request, split)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    k_layer, v_layer = k_hbm.at[layer_ref[0]], v_hbm.at[layer_ref[0]]
+
+    def copies(i, slot):
+        """The copies that bring iteration ``i``'s blocks into ``slot``
+        (all on the slot's semaphore; built anew to be waited for).  Past
+        the row's last block it is that block again, masked by position:
+        what multiplies a zero probability has to be finite."""
+        # (a traced index costs a ref's every use some tracing: once each)
+        kdst, vdst, sem = kbuf.at[slot], vbuf.at[slot], sems.at[slot]
+        out = []
+        for g in range(fold):
+            blk = tables_ref[b, jnp.minimum(first + i * fold + g, live - 1)]
+            out += [pltpu.make_async_copy(k_layer.at[blk], kdst.at[g], sem),
+                    pltpu.make_async_copy(v_layer.at[blk], vdst.at[g], sem)]
+        return out
+
+    def fetch(i, slot):
+        for copy in copies(i, slot):
+            copy.start()
+
+    def fold_in(i, carry):
+        """Blocks ``first + i * fold ..``, waited for in their buffer,
+        into (m, l) and the accumulator; the ones after them are already
+        on their way."""
+        m_prev, l_prev = carry                                   # [H, 1]
+        j, slot = first + i * fold, i % 2
+
+        @pl.when(i + 1 < trips)
+        def _next():
+            fetch(i + 1, 1 - slot)
+
+        for copy in copies(i, slot):
+            copy.wait()
+        kblocks, vblocks = kbuf.at[slot], vbuf.at[slot]
+
+        # scores [H, fold * BS], heads on sublanes and positions on
+        # lanes: each chunk contracts its 128 lanes for the heads of its
+        # group (the group's other heads meet zeros of the block-diagonal
+        # query)
+        parts = [None] * ngroup
+        for c in range(nchunk):
+            g = group(c)
+            part = contract(qm_ref[rows(g), lanes(c)], operand(kblocks, c),
+                            (((1,), (1,)), ((), ())))       # [R, fold * BS]
+            parts[g] = part if parts[g] is None else parts[g] + part
+        s = parts[0] if ngroup == 1 else jnp.concatenate(parts, axis=0)
+        if quantized:
+            # one scale a position: the iteration's row of the table's,
+            # positions along the lanes as the scores want them
+            srow = pl.ds(j // fold, 1)
+            s = s * kscale_ref[srow, :]                    # [1, fold * BS]
+        s = s * scale
+
+        pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = pos < limit
+        # f32-typed constants: weak python-float literals re-materialize at
+        # lowering time and can widen to f64 under an ambient x64 context.
+        s = jnp.where(valid, s, np.float32(NEG_INF))
+
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)                          # [H, 1]
+        pmat = jnp.where(valid, jnp.exp(s - m_new), np.float32(0.0))
+        l_new = l_prev * alpha + jnp.sum(pmat, axis=1, keepdims=True)
+        if quantized:
+            # (the scale of a position past the length is anything)
+            pmat = pmat * jnp.where(valid[:1], vscale_ref[srow, :],
+                                    np.float32(0.0))
+
+        # p.v: a chunk of V under its group's probabilities.  The rows of
+        # other heads come out as garbage sums nobody reads (the finish
+        # keeps each head's own lanes).  A bf16 (or fp8) payload is
+        # multiplied exactly: the probabilities go in as three bf16 pieces.
+        lhs = [_split_bf16(pmat[rows(g)]) if exact else pmat[rows(g)]
+               for g in range(ngroup)]
+        for c in range(nchunk):
+            o = contract(lhs[group(c)], operand(vblocks, c),
+                         (((1,), (0,)), ((), ())))                # [R or 3R, W]
+            if exact:
+                o = o[:r] + o[r:2 * r] + o[2 * r:]
+            acc_ref[c] = acc_ref[c] * alpha[rows(group(c))] + o
+        return m_new, l_new
+
+    @pl.when(count > 0)
+    def _live():
+        fetch(0, 0)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # the query as a block-diagonal [H, H*hd]: row h keeps head h's
         # lanes, so q.k for every head is ONE contraction over the lanes
@@ -171,69 +299,21 @@ def _decode_kernel(*refs, bps: int, block_size: int, heads: int,
             qc = jnp.broadcast_to(q_ref[:, lanes(c)].astype(f32), (r, w))
             qm_ref[rows(group(c)), lanes(c)] = jnp.where(
                 owned(c), qc, np.float32(0.0)).astype(cd)
-
-    # logical block index of this grid step -> absolute positions
-    j = pl.program_id(1) * bps + p
-
-    # A column past the row's length folds nothing in (every score
-    # NEG_INF: m and l unmoved, alpha 1, p 0), so it is not computed: a
-    # table is as wide as max_seq_len and most of a row's columns are
-    # dead.  The grid still walks them (ROADMAP S2 bounds the walk).
-    @pl.when(j * block_size < lengths_ref[b])
-    def _fold():
-        if quantized:
-            # one scale a position: the 8-slot tile of scales this step's
-            # block sits in, BS along the lanes as the scores want it
-            srow = pl.ds(tables_ref[b, j] % kscale_ref.shape[0], 1)
-
-        # scores [H, BS], heads on sublanes and positions on lanes: each
-        # chunk contracts its 128 lanes for the heads of its group (the
-        # group's other heads meet zeros of the block-diagonal query)
-        parts = [None] * ngroup
-        for c in range(nchunk):
-            g = group(c)
-            part = contract(qm_ref[rows(g), lanes(c)], operand(k_ref, c),
-                            (((1,), (1,)), ((), ())))             # [R, BS]
-            parts[g] = part if parts[g] is None else parts[g] + part
-        s = parts[0] if ngroup == 1 else jnp.concatenate(parts, axis=0)
-        if quantized:
-            s = s * kscale_ref[srow, :]                           # [1, BS]
-        s = s * scale
-
-        pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = pos < lengths_ref[b]
-        # f32-typed constants: weak python-float literals re-materialize at
-        # lowering time and can widen to f64 under an ambient x64 context.
-        s = jnp.where(valid, s, np.float32(NEG_INF))
-
-        m_prev = m_ref[...]                                      # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)                          # [H, 1]
-        pmat = jnp.where(valid, jnp.exp(s - m_new), np.float32(0.0))
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pmat, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        if quantized:
-            pmat = pmat * vscale_ref[srow, :]
-
-        # p.v: a chunk of V under its group's probabilities.  The rows of
-        # other heads come out as garbage sums nobody reads (_finish keeps
-        # each head's own lanes).  A bf16 (or fp8) payload is multiplied
-        # exactly: the probabilities go in as three bf16 pieces.
-        lhs = [_split_bf16(pmat[rows(g)]) if exact else pmat[rows(g)]
-               for g in range(ngroup)]
-        for c in range(nchunk):
-            o = contract(lhs[group(c)], operand(v_ref, c),
-                         (((1,), (0,)), ((), ())))                # [R or 3R, W]
-            if exact:
-                o = o[:r] + o[r:2 * r] + o[2 * r:]
-            acc_ref[c] = acc_ref[c] * alpha[rows(group(c))] + o
-
-    @pl.when(p == bps - 1)
-    def _finish():  # each head's own lanes of its accumulator row
-        for c in range(nchunk):
+        m, l = jax.lax.fori_loop(
+            0, trips, fold_in, (jnp.full(m_ref.shape, NEG_INF, f32),
+                                jnp.zeros(l_ref.shape, f32)))
+        m_ref[...] = m
+        l_ref[...] = l
+        for c in range(nchunk):     # each head's own lanes of its row
             out_ref[:, lanes(c)] = jnp.sum(
                 jnp.where(owned(c), acc_ref[c], np.float32(0.0)),
                 axis=0, keepdims=True)
+
+    @pl.when(count <= 0)
+    def _empty():   # the partial that the combine weighs with nothing
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
 def flash_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
@@ -245,9 +325,9 @@ def flash_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     the ``layer`` to read, ``tables`` [B, max_blocks], ``lengths`` [B].
     Returns [B, H, hd].
 
-    ``split_k`` partitions the logical blocks into that many concurrent
-    online-softmax streams (default :func:`default_split_k`); partials
-    are combined outside the kernel.  ``interpret=True`` runs the same
+    ``split_k`` partitions the table's columns into that many
+    online-softmax partials (default :func:`default_split_k`), combined
+    outside the kernel.  ``interpret=True`` runs the same
     kernel body on the Pallas interpreter — the CPU test twin.
     """
     if is_quantized(k_pool) != is_quantized(v_pool):
@@ -271,7 +351,7 @@ def flash_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
 def _flash_decode(q, k_pool, v_pool, layer, tables, lengths, *, scale,
                   splits: int, interpret: bool):
     """The kernel's call.  Its own ``jit`` with the layer an operand (it
-    reaches the block maps as a prefetched scalar): a program that reads
+    reaches the kernel as a prefetched scalar): a program that reads
     every layer traces and lowers this ONCE and calls it ``num_layers``
     times, where the kernel's body, traced a layer, was 0.3 s each of a
     24-layer decode program's set-up."""
@@ -282,52 +362,58 @@ def _flash_decode(q, k_pool, v_pool, layer, tables, lengths, *, scale,
     kp = k_pool.payload if quantized else k_pool
     vp = v_pool.payload if quantized else v_pool
     b, h, hd = q.shape
-    _, nb, bs, width = kp.shape
+    _, _, bs, width = kp.shape
+    # Mosaic copies whole 128-lane rows out of HBM.  Every deployed width
+    # is some; a narrower pool (the tiny models of the tests) is padded
+    # to one, which copies it
+    rowed = -(-width // 128) * 128
+    if rowed != width:
+        kp, vp = (jnp.pad(p, ((0, 0),) * 3 + ((0, rowed - width),))
+                  for p in (kp, vp))
     nblk = tables.shape[1]
-    bps = -(-nblk // splits)                # blocks per split partition
-    padded = splits * bps
-    if padded != nblk:
-        # pad with trash-slot entries: their logical positions are
-        # >= nblk*bs >= every length, so the mask kills them.
-        tables = jnp.pad(tables, ((0, 0), (0, padded - nblk)))
+    # table columns per split, whole iterations of the kernel's loop
+    bps = -(-nblk // (splits * _FOLD)) * _FOLD
 
     # both contractions multiply in bf16 when queries and payload hold no
     # more than bf16 does (fp8 widens to it exactly), else in float32
     narrow = quantized or kp.dtype == jnp.bfloat16
     cd = jnp.bfloat16 if narrow and q.dtype == jnp.bfloat16 else jnp.float32
-    kernel = partial(_decode_kernel, bps=bps, block_size=bs, heads=h,
-                     head_dim=hd, quantized=quantized, scale=scale)
-
-    def kv_spec():      # one block of one layer, straight out of the pool
-        return pl.BlockSpec(
-            (None, None, bs, width),
-            lambda bi, si, pi, tref, lref, yref: (
-                yref[0], tref[bi, si * bps + pi], 0, 0))
-
-    # the scales of 8 slots (one f32 sublane tile; [L, blocks, BS] has
-    # no smaller legal block): the kernel takes its slot's row
-    srows = min(8, nb)
-
-    def scale_spec():
-        return pl.BlockSpec(
-            (None, srows, bs),
-            lambda bi, si, pi, tref, lref, yref: (
-                yref[0], tref[bi, si * bps + pi] // srows, 0))
+    kernel = partial(_decode_kernel, bps=bps, nblk=nblk, fold=_FOLD,
+                     block_size=bs, heads=h, head_dim=hd, quantized=quantized,
+                     scale=scale)
 
     def row_spec(*block):       # a [b, splits, ...] output's (b, s) block
         return pl.BlockSpec(
             (None, None) + block,
-            lambda bi, si, pi, tref, lref, yref: (bi, si, 0, 0))
+            lambda bi, si, tref, lref, yref: (bi, si, 0, 0))
 
-    in_specs = [
+    whole = pl.BlockSpec(memory_space=pl.ANY)   # stays in HBM; the kernel
+    in_specs = [                                # copies the blocks it reads
         pl.BlockSpec((None, 1, width),
-                     lambda bi, si, pi, tref, lref, yref: (bi, 0, 0)),
-        kv_spec(), kv_spec(),
+                     lambda bi, si, tref, lref, yref: (bi, 0, 0)),
+        whole, whole,
     ]
     operands = [q.reshape(b, 1, width), kp, vp]
+    w, r = _lane_chunks(h, hd)
+    scratch_shapes = [
+        pltpu.VMEM((h, width), cd),                  # block-diagonal q
+        pltpu.VMEM((width // w, r, w), jnp.float32),  # accumulator
+        pltpu.VMEM((2, _FOLD, bs, rowed), kp.dtype),  # K, double-buffered
+        pltpu.VMEM((2, _FOLD, bs, rowed), vp.dtype),  # V
+        pltpu.SemaphoreType.DMA((2,)),               # one a buffer
+    ]
     if quantized:
-        in_specs += [scale_spec(), scale_spec()]
-        operands += [k_pool.scale, v_pool.scale]
+        # Mosaic copies whole 128-lane rows out of HBM and a block's
+        # scales are BS lanes of [L, blocks, BS], so XLA gathers the
+        # tables' scale rows (B x nblk x BS floats) and a row's arrive
+        # with its query, an iteration's side by side along the lanes
+        cols = splits * bps
+        padded = jnp.pad(tables, ((0, 0), (0, cols - nblk)))
+        in_specs += [pl.BlockSpec(
+            (None, cols // _FOLD, _FOLD * bs),
+            lambda bi, si, tref, lref, yref: (bi, 0, 0))] * 2
+        operands += [p.scale[layer, padded].reshape(b, cols // _FOLD, -1)
+                     for p in (k_pool, v_pool)]
 
     out_shape = [
         jax.ShapeDtypeStruct((b, splits, 1, width), jnp.float32),
@@ -335,16 +421,12 @@ def _flash_decode(q, k_pool, v_pool, layer, tables, lengths, *, scale,
         jax.ShapeDtypeStruct((b, splits, h, 1), jnp.float32),
     ]
 
-    w, r = _lane_chunks(h, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, splits, bps),
+        grid=(b, splits),
         in_specs=in_specs,
         out_specs=[row_spec(1, width), row_spec(h, 1), row_spec(h, 1)],
-        scratch_shapes=[
-            pltpu.VMEM((h, width), cd),                  # block-diagonal q
-            pltpu.VMEM((width // w, r, w), jnp.float32),  # accumulator
-        ],
+        scratch_shapes=scratch_shapes,
     )
     with jax.enable_x64(False):
         acc, m, l = pl.pallas_call(
@@ -353,7 +435,7 @@ def _flash_decode(q, k_pool, v_pool, layer, tables, lengths, *, scale,
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
         )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
           layer.reshape(1), *operands)

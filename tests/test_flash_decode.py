@@ -15,6 +15,11 @@ tests pin the kernel's numerics — not a Python re-implementation:
   kernel walks a block in 128-lane chunks and multiplies in bf16;
 * fp8 QuantPool in-kernel dequantization matches the dense fp8 read
   (both read the same payload/scale pairs);
+* the walk over a row's blocks is a loop inside the kernel, bounded by
+  the row's length: the grid is ``(rows, splits)`` whatever the tables'
+  width, ragged rows (empty, one token, a block's edge, the table's last
+  column, one live row of 32) match dense, and dead columns or unused
+  table entries full of NaN change nothing;
 * the ``default_split_k`` heuristic: serial up to 8 blocks, then
   partitions of <= 8 blocks each, capped at 8 streams;
 * end-to-end: an engine configured with ``attn_impl="flash_interpret"``
@@ -29,8 +34,8 @@ import jax.numpy as jnp
 from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.serve import kvcache
-from mxnet_tpu.serve.flash_decode import (_lane_chunks, _split_bf16,
-                                          default_split_k,
+from mxnet_tpu.serve.flash_decode import (_flash_decode, _lane_chunks,
+                                          _split_bf16, default_split_k,
                                           flash_decode_attention)
 
 LAYER = 2       # of 3: the layer read; layers 0 and 1 hold decoys
@@ -44,12 +49,14 @@ def _fresh_telemetry():
 
 
 def _setup(seed, B, H, HD, BS, nblk_per_req, npool=64, dtype=jnp.float32,
-           quant=None):
+           quant=None, lengths=None, max_blocks=None):
     """Paged pools (``make_pools``, filled through ``write_prefill``)
-    with per-request ragged lengths; returns the dense reader's output
-    at ``LAYER`` alongside the paged operands."""
+    with per-request ragged lengths (drawn, unless ``lengths`` gives
+    them); returns the dense reader's output at ``LAYER`` alongside the
+    paged operands."""
     rng = np.random.RandomState(seed)
-    max_blocks = max(nblk_per_req)
+    given = lengths
+    max_blocks = max_blocks or max(nblk_per_req)
     q = jnp.asarray(rng.randn(B, H, HD), dtype)
     tables = np.zeros((B, max_blocks), np.int32)
     lengths = np.zeros(B, np.int32)
@@ -58,9 +65,13 @@ def _setup(seed, B, H, HD, BS, nblk_per_req, npool=64, dtype=jnp.float32,
         tables[b, :nb] = [next(free) for _ in range(nb)]
         # ragged: last block partially filled (at least one slot)
         lengths[b] = (nb - 1) * BS + int(rng.randint(1, BS + 1))
+    if given is not None:
+        lengths[:] = given
     kp, vp = kvcache.make_pools(3, npool, BS, H, HD, dtype=dtype, quant=quant)
     for layer in range(3):
         for b, nb in enumerate(nblk_per_req):
+            if not nb:
+                continue
             ks, vs = (jnp.asarray(rng.randn(nb * BS, H, HD), dtype)
                       for _ in range(2))
             row, full = jnp.asarray(tables[b]), jnp.int32(nb * BS)
@@ -87,6 +98,100 @@ def test_flash_matches_dense(nblk_per_req, split_k):
     out = np.asarray(flash_decode_attention(
         *args, split_k=split_k, interpret=True))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("nblk,split_k,splits", [
+    (4, None, 1), (16, None, 2), (64, None, 8), (128, None, 8),
+    (16, 4, 4), (64, 4, 4), (128, 1, 1), (5, 8, 5),
+])
+def test_the_grid_does_not_grow_with_the_tables(nblk, split_k, splits):
+    """The walk over a row's table columns is a loop in the kernel, not
+    a grid dimension: the ``pallas_call`` has ``rows x splits`` grid
+    steps however wide the tables are."""
+    b, h, hd, bs = 6, 2, 16, 4
+    sds = jax.ShapeDtypeStruct
+    pool = sds((3, 2 * nblk, bs, h * hd), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, t, n: flash_decode_attention(
+        q, k, v, 1, t, n, split_k=split_k, interpret=True))(
+        sds((b, h, hd), jnp.float32), pool, pool,
+        sds((b, nblk), jnp.int32), sds((b,), jnp.int32))
+
+    def calls(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (call,) = calls(jaxpr.jaxpr)
+    assert call.params["name"] == "mxtpu_flash_decode"
+    assert tuple(call.params["grid_mapping"].grid) == (b, splits)
+
+
+# rows of a 6-column table of 4-token blocks: empty, one token, a block's
+# edge (two ways), the table's last column (partly and wholly filled)
+_RAGGED = [0, 1, 4, 8, 21, 24, 13]
+
+
+@pytest.mark.parametrize("quant", [None, "fp8"])
+@pytest.mark.parametrize("split_k", [None, 1, 2, 4])
+def test_flash_ragged_rows_match_dense(split_k, quant):
+    args, ref = _setup(
+        seed=21, B=len(_RAGGED), H=2, HD=16, BS=4,
+        nblk_per_req=[-(-n // 4) for n in _RAGGED], lengths=_RAGGED,
+        max_blocks=6, quant=quant)
+    out = np.asarray(flash_decode_attention(
+        *args, split_k=split_k, interpret=True))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+    assert not out[0].any()         # nothing cached: zeros, not NaN
+
+
+@pytest.mark.parametrize("quant", [None, "fp8"])
+@pytest.mark.parametrize("split_k", [None, 1, 2, 4])
+def test_flash_one_live_row_of_32(split_k, quant):
+    """A decode bucket nearly empty: only its last row holds anything."""
+    lengths = [0] * 31 + [37]
+    args, ref = _setup(
+        seed=22, B=32, H=2, HD=16, BS=4, nblk_per_req=[0] * 31 + [10],
+        lengths=lengths, max_blocks=12, quant=quant)
+    out = np.asarray(flash_decode_attention(
+        *args, split_k=split_k, interpret=True))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+    assert not out[:31].any() and out[31].any()
+
+
+@pytest.mark.parametrize("quant", [None, "fp8"])
+@pytest.mark.parametrize("split_k", [None, 1, 3])
+def test_flash_never_reads_a_dead_block(split_k, quant):
+    """Table columns past a row's length, and every pool block no row's
+    live prefix lists (the trash block among them), hold NaN: the result
+    is bit for bit what clean ones give."""
+    nblk = [9, 0, 3, 1]
+    (q, kp, vp, layer, tables, lengths), _ = _setup(
+        seed=23, B=4, H=2, HD=16, BS=4, nblk_per_req=nblk, max_blocks=12,
+        quant=quant)
+    clean = np.asarray(flash_decode_attention(
+        q, kp, vp, layer, tables, lengths, split_k=split_k, interpret=True))
+    tables = np.array(tables)
+    live = np.unique(np.concatenate(
+        [tables[b, :n] for b, n in enumerate(nblk)]))
+    dead = np.setdiff1d(np.arange(64), live)
+    for b, n in enumerate(nblk):
+        tables[b, n:] = dead[b::4][:12 - n]
+
+    def poisoned(pool):
+        if quant:
+            return kvcache.QuantPool(*(x.at[:, dead].set(np.nan)
+                                       for x in pool))
+        return pool.at[:, dead].set(np.nan)
+
+    kp, vp = poisoned(kp), poisoned(vp)
+    assert np.isnan(np.asarray(
+        (kp.scale if quant else kp)[LAYER, 0], np.float32)).all()
+    out = np.asarray(flash_decode_attention(
+        q, kp, vp, layer, jnp.asarray(tables), lengths, split_k=split_k,
+        interpret=True))
+    np.testing.assert_array_equal(out, clean)
 
 
 @pytest.mark.parametrize("layer", [0, 1, 2])

@@ -147,3 +147,42 @@ def test_program_neither_copies_nor_slices_a_pool(topo, kind, bucket,
                  if 'custom_call_target="tpu_custom_call"' in ln
                  and "mxtpu_flash_decode" in ln]
         assert len(calls) == DEPTH[kv_quant], len(calls)
+
+
+@pytest.mark.parametrize("h,hd", [(32, 64), (8, 128), (2, 32)])
+@pytest.mark.parametrize("pool", ["f32", "bf16", "fp8"])
+def test_kernel_compiles_for_a_v5e(topo, pool, h, hd):
+    """Mosaic COMPILES ``mxtpu_flash_decode`` (lowering alone,
+    ``tests/test_flash_decode.py::test_kernel_lowers_for_tpu``, does not
+    show what it refuses: a copy out of an array in HBM whose rows are
+    not whole 128-lane rows passes there and fails here), for every kind
+    of pool, at the stand-in's width, at one head a lane row and at a
+    width under one row, and the call keeps no pool-sized temporary at a
+    deployed width."""
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.serve import kvcache
+    from mxnet_tpu.serve.flash_decode import flash_decode_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, bs, nl, nb, nblk = 32, 16, 3, 768, 128
+    dtype = jnp.dtype({"f32": "float32"}.get(pool, "bfloat16"))
+    if pool == "fp8":
+        kv = kvcache.QuantPool(sds((nl, nb, bs, h * hd), jnp.float8_e4m3fn),
+                               sds((nl, nb, bs), jnp.float32))
+    else:
+        kv = sds((nl, nb, bs, h * hd), dtype)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        comp = jax.jit(lambda q, k, v, t, n: flash_decode_attention(
+            q, k, v, 1, t, n)).trace(
+            sds((b, h, hd), dtype), kv, kv, sds((b, nblk), jnp.int32),
+            sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "mxtpu_flash_decode" in comp.as_text()
+    if h * hd % 128 == 0:
+        assert comp.memory_analysis().temp_size_in_bytes < 4e6

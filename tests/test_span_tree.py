@@ -139,6 +139,23 @@ def test_engine_step_is_one_span_tree(kind, tmp_path):
     assert info["events"] == len(spans)
 
 
+@pytest.mark.parametrize("kind", ["chunked", "whole"])
+def test_decode_spans_count_live_and_table_blocks(kind):
+    """``serve.decode`` says how much of its tables the step's attention
+    reads: each active row's ``length + 1`` positions, against ``bucket
+    x max_blocks`` columns (``tools/trace_report.py`` prints the
+    window's ratio)."""
+    eng, spans = _recorded(kind)
+    decodes = [ev["args"] for ev in spans.values()
+               if ev["name"] == "serve.decode"]
+    assert decodes
+    for args in decodes:
+        assert args["table_blocks"] == args["bucket"] * eng.max_blocks
+        assert args["active"] <= args["live_blocks"] <= args["table_blocks"]
+    # the longest request alone: 10 prompt + 5 fed tokens, blocks of 4
+    assert max(a["live_blocks"] for a in decodes if a["active"] == 1) >= 3
+
+
 # -- (b) the clock stops after the fetch -------------------------------------
 
 @pytest.mark.parametrize("kind", ["chunked", "whole"])

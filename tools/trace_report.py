@@ -21,7 +21,9 @@ anywhere.  It prints what ``PERF.md`` section 5 is written from:
   ``tf_op`` stat (the HLO ``op_name``), with what no scope claims broken
   down by instruction;
 * the span ring's medians beside the device's, so a span that stopped
-  covering its program shows.
+  covering its program shows, and the window's ratio of the
+  ``serve.decode`` spans' ``live_blocks`` to ``table_blocks`` (what the
+  decode attention read of what its tables could hold).
 
 It measures nothing the benchmark reports and changes no number of it.
 """
@@ -244,6 +246,16 @@ def read(stem: str) -> None:
         for k in sorted(durs):
             print(f"  {k:34s} {statistics.median(durs[k]):10.3f}  "
                   f"({len(durs[k])})")
+        # how much of its tables the decode attention had to read
+        walks = [ev["args"] for ev in spans if ev["name"] == "serve.decode"
+                 and "live_blocks" in ev["args"]]
+        if walks:
+            live = sum(a["live_blocks"] for a in walks)
+            table = sum(a["table_blocks"] for a in walks)
+            print(f"[blocks] {len(walks)} serve.decode spans: live_blocks "
+                  f"{live} of table_blocks {table} = {100 * live / table:.2f} %"
+                  f" ({live / len(walks):.1f} of {table / len(walks):.0f} a "
+                  "step)")
 
 
 def main() -> int:
