@@ -31,6 +31,9 @@ class Run:
     compiles: Any                       # device.CompileCounter
     scratch: str                        # this run's own temporary directory
     held_bytes: int = 0                 # most the allocator held, see below
+    # every number ``correct`` compared, beside its limit: name ->
+    # (value, limit); the result line's last key and stderr's last lines
+    compared: Dict[str, Any] = field(default_factory=dict)
 
     @property
     def chips(self) -> int:

@@ -106,10 +106,19 @@ def probe(run: Run, eng, params, ref) -> Tuple[bool, List[str]]:
         f"token is at most {worst:.4f} under the reference's maximum "
         f"(tolerance {tol}; logits' std {spread:.3f}); "
         f"{agree:.1%} are the reference's argmax")
+    run.compared["logit_deficit"] = (worst, tol)
     if not np.isfinite(deficit).all() or worst > tol:
         notes.append(f"engine tokens fall {worst:.4f} under the reference's "
                      f"maximum logit, tolerance {tol}")
     return not notes, notes
+
+
+def preemptions() -> int:
+    """The program's count of requests it evicted to free KV blocks
+    (``serve.preemptions``, ``Engine._preempt``), since the process
+    started: a caller takes the difference over its own stretch."""
+    from mxnet_tpu import telemetry
+    return int(telemetry.counter("serve.preemptions").value())
 
 
 class StepLog:

@@ -8,9 +8,9 @@ token whatever the seed; ``--seed`` draws the order they come in, the
 token ids, the weights and the sampling seeds.  A seed is therefore
 another sample of the same traffic: which request meets which
 neighbours, and when the bursts fall, differ from seed to seed.  What a
-seed may NOT change is the amount of work in a window (a window holds
-some tens of requests at the rates this system sustains, and lengths
-drawn independently would move its load by a fifth).
+seed may NOT change is the amount of work in a window (lengths drawn
+independently would move the load of a window of some tens of requests
+by a fifth, and of several hundred by a twentieth).
 
 The inverse-transform power law and the identity-folded request seed
 are copied from ``mxnet_tpu/serve/traffic.py`` (``_power_law``,

@@ -187,7 +187,7 @@ def main(argv=None) -> int:
     ref = spec.load_module("reference", cfg["family"])
     serve = cfg["serve"]
     dtype = jnp.dtype(serve["weights_dtype"])
-    run = types.SimpleNamespace(config=cfg, seed=args.seed)
+    run = types.SimpleNamespace(config=cfg, seed=args.seed, compared={})
     print(f"[reading] {args.config}, seed {args.seed}, on "
           f"{jax.devices()[0].device_kind}; logit_tolerance "
           f"{serve['logit_tolerance']}, state_tolerance "

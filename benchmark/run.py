@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 import copy
 import json
+import math
 import os
 import shutil
 import sys
@@ -176,6 +177,14 @@ def main(argv=None) -> int:
         say(f"[trace] window {summary.window_s:.3f} s, busy "
             f"{summary.busy_s:.3f} s on {summary.chips} chip(s); starting "
             f"the profiler took {cost[0]:.2f} s, stopping it {cost[1]:.2f} s")
+    # each number compared beside its limit: the line's last key, and
+    # the last lines on standard error
+    line["compared"] = {}
+    for k, (v, lim) in run.compared.items():
+        v = float(v)                # a non-finite number is no JSON
+        line["compared"][k] = {"value": v if math.isfinite(v) else repr(v),
+                               "limit": float(lim)}
+        print(f"[compared] {k} {v} limit {lim}", file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

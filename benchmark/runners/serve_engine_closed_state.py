@@ -107,6 +107,7 @@ def run(run: Run) -> Result:
     say(f"[correct] {STEPS} decode updates of {ROWS} rows over a pool like "
         f"the engine's: |y - attention form| / |attention form| = "
         f"{seen['error']:.3g} (tolerance {tol})")
+    run.compared["state_error"] = (seen["error"], tol)
     if not seen["error"] <= tol:
         result.notes.append(
             f"the decode update strays {seen['error']:.3g} from the "

@@ -100,6 +100,7 @@ def run(run: Run) -> Result:
     notes = []
     say(f"[correct] first-step loss {got:.5f} vs reference {want:.5f} "
         f"(|diff| {abs(got - want):.5f}, tolerance {tol})")
+    run.compared["first_loss_diff"] = (abs(got - want), tol)
     if not (math.isfinite(got) and abs(got - want) <= tol):
         notes.append(f"first-step loss {got} differs from the reference's "
                      f"{want} by more than {tol}")

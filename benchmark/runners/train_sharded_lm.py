@@ -143,6 +143,7 @@ def run(run: Run) -> Result:
         f"against the reference's update from the batch's first half "
         f"alone, as gradients not summed over the data axis would "
         f"leave it: {half:.4f}")
+    run.compared["first_update_error"] = (got, tol)
     if not got <= tol:
         result.notes.append(
             f"the trainer's first update differs from the reference's by "

@@ -36,8 +36,8 @@ def topo():
 @pytest.fixture(scope="module")
 def engine():
     """The engine of the configuration file, built on the CPU from SHAPES
-    (no 2.8 GB of weights are made): only its program builders and avals
-    are used."""
+    (no 2.8 GB of weights, no 2.4 GB of pools are made): only its program
+    builders and avals are used."""
     import jax
     import jax.numpy as jnp
 
@@ -62,9 +62,9 @@ def engine():
             try:
                 eng_mod.jnp.asarray = lambda v, *a, **k: (
                     v if isinstance(v, sds) else real_asarray(v, *a, **k))
-                eng_mod.kvcache.make_pools = (
-                    lambda nl, nb, bs, h, hd, dtype, quant=None: (
-                        sds((nl, nb, bs, h, hd), dtype),) * 2)
+                # the pools' shapes are ``kvcache.make_pools``'s own
+                eng_mod.kvcache.make_pools = lambda *a, **k: jax.eval_shape(
+                    lambda: real_pools(*a, **k))
                 super().__init__({k: sds(s, dtype) for k, s in shapes.items()},
                                  ecfg)
             finally:

@@ -30,7 +30,18 @@ def test_rehearsal_runs_and_reports_no_metric(cell, trace):
     assert line["device"]["platform"] == "cpu"
     assert line["attempted"] > 0 and line["failed"] == 0
     assert "in-window compiles 0" in out.stdout
+    # every number ``correct`` compared, beside its limit: the line's last
+    # key and the last lines on standard error
+    assert list(line)[-1] == "compared" and line["compared"]
+    for name, c in line["compared"].items():
+        assert c["value"] <= c["limit"], (name, c)
+    said = [l for l in out.stderr.strip().splitlines()][-len(line["compared"]):]
+    assert [l.split()[:2] for l in said] == [
+        ["[compared]", name] for name in line["compared"]], out.stderr[-500:]
     mix = spec.load_traffic(spec.find_cell(BENCH, cell)["traffic"])
+    if mix["kind"] == "serve_engine_open":
+        assert "preemptions 0 in the lead-in, 0 from" in out.stdout
+        assert "queue at the window's close 0" in out.stdout
     if mix["kind"].startswith("serve_engine_closed"):
         # the tiny engine is the fast one: its clients go through dozens
         # of rounds where the chip's stay in round 0

@@ -92,6 +92,25 @@ def test_prefetch_wait_is_the_median_wait_of_the_consumer():
     assert prefetch_wait.read({"spans": spans}) == pytest.approx(0.3)
 
 
+def test_the_steps_line_counts_the_gaps_that_hold_a_chunk():
+    """A step's decoded rows each close a gap; the rows of the steps that
+    also ran a prefill chunk are the gaps ``itl_p95_ms`` is meant to
+    read (ISSUE 34: under 5 % of them the percentile sits on an edge)."""
+    open_loop = spec.load_module("runners", "serve_engine_open")
+    spans = [span("serve.step", 1, 0, 9000, rows=3, chunk=0),
+             span("serve.step", 2, 9000, 25000, rows=5, chunk=1),
+             span("serve.step", 3, 34000, 9000, rows=2, chunk=0),
+             span("serve.step", 4, 43000, 100),          # cut at the edge
+             span("serve.decode", 5, 100, 8000, active=3),
+             span("serve.decode", 6, 20000, 8000, active=5)]
+    said = open_loop.describe_steps(spans)
+    assert "3 serve.step spans in the window, 1 with a prefill chunk" in said
+    assert "10 decoded rows, 5 of them (50.0%)" in said
+    assert "mean active rows a decode step 4.00" in said
+    assert said.endswith("the longest step 25.0 ms, spans inside it ms "
+                         "serve.decode 8.0")
+
+
 BENCH = spec.load_benchmark()
 NEW = {"serve-chat": "step_host_ms.chat", "serve-batch": "step_host_ms.batch",
        "resnet50-train": "prefetch_wait_ms"}

@@ -95,6 +95,24 @@ def test_open_loop_has_the_same_gaps_for_every_seed_in_another_order():
         traffic.arrival_gaps({"process": "poisson", "rate_per_s": 1.0}, 10)
 
 
+def test_chat_open_runs_at_four_fifths_of_the_knee_its_file_states():
+    """The rate and the knee it was derived from may not drift apart
+    again (until PR 34 the cell ran at 0.8 x a knee swept when a decode
+    step took ten times as long): ``rate_per_s`` is 0.8 x
+    ``knee_req_per_s`` to two significant digits, or under that where
+    the file says why (``rate_below_four_fifths_because``), and the
+    cell's ``why`` states both numbers."""
+    rate, knee = CHAT["arrivals"]["rate_per_s"], CHAT["knee_req_per_s"]
+    four_fifths = float(f"{0.8 * knee:.2g}")
+    if "rate_below_four_fifths_because" in CHAT:
+        assert 0 < rate < four_fifths
+    else:
+        assert rate == four_fifths
+    cell = spec.find_cell(spec.load_benchmark(), "serve-chat")
+    assert cell["traffic"] == "chat-open"
+    assert f"{rate} req/s" in cell["why"] and f"knee {knee}" in cell["why"]
+
+
 def test_training_batches_replay_from_a_seed():
     inputs = {"data": {"shape": [2, 3, 4], "kind": "uniform"},
               "softmax_label": {"shape": [2], "kind": "int", "high": 50}}
