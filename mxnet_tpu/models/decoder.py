@@ -19,19 +19,43 @@ tuple with one name per layer):
   learned per-kv-head forget gate (``models/retention.py``); the cache
   is one fixed-size state per request and layer (kind
   ``recurrent_state``).
+* ``"latent"`` — multi-head latent attention: low-rank queries
+  (``q_lora_rank``; None: one full projection) and ONE compressed
+  key/value row a position, ``[c | k_r]`` of ``kv_lora_rank +
+  qk_rope_head_dim`` values shared by all heads (the normed latent and
+  one rotary key), from which every head's no-position key and its
+  value are up-projected; rotary positions (optionally YaRN-scaled) on
+  ``qk_rope_head_dim`` channels only.  The cache is that row, paged
+  (kind ``paged_latent``).
 
 A window/global or hybrid model adds a kind here and a cache kind in
 ``serve.kvcache``; it does not add a file of twins.
+
+The FFN of a layer is dense (``ffn``: ReLU or gated SiLU) or, where
+``ffn_layers`` says ``"routed"``, an expert layer
+(``models/experts.py``): a router over ``n_routed_experts`` with
+``experts_per_token`` chosen (``group_limited_greedy`` over ``n_group``
+groups of which ``topk_group`` are kept), shared experts beside them,
+and **the experts this chip holds** (``experts_held`` = first, count):
+the layer routes over all of them and computes the held ones' part.
 
 Parameter names (``layer{i}_`` prefix; FullyConnected weights are
 ``[out, in]``): ``q/k/v/proj_weight`` (+ ``_bias`` when ``bias``),
 ``ln1/ln2_gamma`` (+ ``_beta`` for LayerNorm), ``ffn1/ffn2`` (ReLU) or
 ``ffn_gate/ffn_up/ffn_down`` (gated SiLU), ``q_norm/k_norm_gamma``
 (``qk_norm``), ``gate_weight`` ``[kv_heads, d]`` + ``gate_bias``
-(retention layers); ``embed_weight``, ``final_ln_*``, ``lm_head_*``.
+(retention layers); ``q_a/q_b_weight`` + ``q_a_norm_gamma`` (or
+``q_weight``), ``kv_a/kv_b_weight`` + ``kv_a_norm_gamma`` (latent
+layers; ``kv_b`` is ``[heads * (nope + v), kv_lora_rank]``, a head's
+key rows before its value rows); ``router_weight`` ``[experts, d]``,
+``shared_gate/up/down_weight``, ``experts_gate/up_weight`` ``[held, d,
+width]`` and ``experts_down_weight`` ``[held, width, d]`` (routed
+layers: a held expert's matrices are ``[in, out]``, as the grouped
+product reads them); ``embed_weight``, ``final_ln_*``, ``lm_head_*``.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, fields
 from typing import Any, Callable, Dict, Optional, Tuple, Union
 
@@ -43,11 +67,14 @@ import jax.numpy as jnp
 from ..base import MXNetError
 
 __all__ = ["ModelSpec", "decoder_forward", "lm_config_from_params",
-           "SOFTMAX", "POWER_RETENTION"]
+           "SOFTMAX", "POWER_RETENTION", "LATENT", "DENSE", "ROUTED"]
 
 SOFTMAX = "softmax"
 POWER_RETENTION = "power_retention"
-_ATTENTION_KINDS = (SOFTMAX, POWER_RETENTION)
+LATENT = "latent"
+_ATTENTION_KINDS = (SOFTMAX, POWER_RETENTION, LATENT)
+DENSE = "dense"
+ROUTED = "routed"
 
 _LN_EPS = 1e-5   # LayerNorm op default (ops/nn_ops.py)
 
@@ -68,6 +95,24 @@ class ModelSpec:
     rope_theta: float = 10000.0
     attention: Union[str, Tuple[str, ...]] = SOFTMAX
     retention_eps: float = 1e-6         # the retention normaliser's eps
+    # -- latent attention (the fields above it that it reads: heads,
+    # norm_eps, rope_theta; head_dim is not read) --
+    q_lora_rank: Optional[int] = None   # None: one full q projection
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # a config.json's ``rope_scaling`` (type "yarn"), frozen to sorted pairs
+    rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+    # -- the FFN of each layer, and the expert layer --
+    ffn_layers: Optional[Tuple[str, ...]] = None   # None: every layer dense
+    n_routed_experts: int = 0
+    experts_per_token: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = False
+    experts_held: Optional[Tuple[int, int]] = None  # (first, count); None: all
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -87,6 +132,48 @@ class ModelSpec:
                                  f"one of {_ATTENTION_KINDS}")
         if not isinstance(self.attention, str):
             object.__setattr__(self, "attention", kinds)
+        if LATENT in kinds:
+            sizes = (self.kv_lora_rank, self.qk_nope_head_dim,
+                     self.qk_rope_head_dim, self.v_head_dim)
+            if min(sizes) < 1 or self.qk_rope_head_dim % 2:
+                raise MXNetError(
+                    "latent attention needs kv_lora_rank, qk_nope_head_dim, "
+                    f"an even qk_rope_head_dim and v_head_dim, got {sizes}")
+            if self.norm != "rmsnorm" or self.bias:
+                raise MXNetError("latent attention is described with "
+                                 "norm='rmsnorm' and bias=False")
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        if self.rope_scaling is not None and self.yarn.get("type") != "yarn":
+            raise MXNetError("ModelSpec.rope_scaling: the one type known is "
+                             f"'yarn', got {self.yarn.get('type')!r}")
+        if self.ffn_layers is not None:
+            object.__setattr__(self, "ffn_layers", tuple(self.ffn_layers))
+            for k in self.ffn_layers:
+                if k not in (DENSE, ROUTED):
+                    raise MXNetError(f"ModelSpec.ffn_layers kind {k!r}: "
+                                     f"expected {DENSE!r} or {ROUTED!r}")
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held",
+                               tuple(int(x) for x in self.experts_held))
+        if self.ffn_layers and ROUTED in self.ffn_layers:
+            n, g = self.n_routed_experts, self.n_group
+            if (n < 1 or not 1 <= self.experts_per_token <= n or g < 1
+                    or n % g or not 1 <= self.topk_group <= g):
+                raise MXNetError(
+                    f"a routed layer needs n_routed_experts ({n}) a "
+                    f"multiple of n_group ({g}), 1 <= topk_group "
+                    f"({self.topk_group}) <= n_group and 1 <= "
+                    f"experts_per_token ({self.experts_per_token}) <= "
+                    "n_routed_experts")
+            if self.ffn != "silu_gated" or self.bias:
+                raise MXNetError("experts are gated SiLU FFNs without "
+                                 "biases: ffn='silu_gated', bias=False")
+            first, count = self.held
+            if first < 0 or count < 1 or first + count > n:
+                raise MXNetError(f"experts_held {self.experts_held}: not "
+                                 f"a range of the {n} routed experts")
         kv = self.heads if self.kv_heads is None else int(self.kv_heads)
         if kv < 1 or self.heads % kv:
             raise MXNetError(f"heads {self.heads} not a multiple of "
@@ -108,8 +195,9 @@ class ModelSpec:
                 raise MXNetError(f"ModelSpec has no field(s) {extra}; it "
                                  f"has {sorted(known)}")
             model = dict(model)
-            if isinstance(model.get("attention"), list):
-                model["attention"] = tuple(model["attention"])
+            for name in ("attention", "ffn_layers", "experts_held"):
+                if isinstance(model.get(name), list):
+                    model[name] = tuple(model[name])
             model.setdefault("heads", int(heads))
             return cls(**model)
         raise MXNetError(f"cannot read a ModelSpec from {type(model)}")
@@ -127,6 +215,43 @@ class ModelSpec:
                              f"{self.heads}")
         return self.heads, self.num_kv_heads, d_model // self.heads
 
+    @property
+    def yarn(self) -> Dict[str, Any]:
+        return dict(self.rope_scaling or ())
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this chip holds."""
+        if self.experts_held is None:
+            return 0, self.n_routed_experts
+        return self.experts_held
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches a position: ``[c | k_r]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def latent_scale(self) -> np.float32:
+        """The latent scores' scale: ``(nope + rope)^-0.5``, times
+        ``mscale^2`` under YaRN with ``mscale_all_dim`` (the published
+        attention corrects its temperature for the stretched rotary
+        range there)."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        y = self.yarn
+        if y.get("mscale_all_dim"):
+            m = yarn_mscale(float(y["factor"]), float(y["mscale_all_dim"]))
+            scale *= m * m
+        return np.float32(scale)
+
+    def ffn_kinds(self, num_layers: int) -> Tuple[str, ...]:
+        if self.ffn_layers is None:
+            return (DENSE,) * num_layers
+        if len(self.ffn_layers) != num_layers:
+            raise MXNetError(
+                f"ModelSpec.ffn_layers names {len(self.ffn_layers)} layers, "
+                f"the parameters hold {num_layers}")
+        return self.ffn_layers
+
     def layer_kinds(self, num_layers: int) -> Tuple[str, ...]:
         if isinstance(self.attention, str):
             return (self.attention,) * num_layers
@@ -139,10 +264,22 @@ class ModelSpec:
     def signature(self) -> str:
         """A short stable string for program-cache fingerprints; empty
         for the in-tree LM, whose keys predate the description."""
-        if self == ModelSpec(heads=self.heads):
+        default = ModelSpec(heads=self.heads)
+        if self == default:
             return ""
-        return ":" + ",".join(f"{f.name}={getattr(self, f.name)}"
-                              for f in fields(self) if f.name != "heads")
+        # the fields a description had when the first keys were made are
+        # always spelt out; a later field only where it is not its
+        # default, so those keys stay what they were
+        return ":" + ",".join(
+            f"{f.name}={getattr(self, f.name)}" for f in fields(self)
+            if f.name != "heads" and (
+                f.name in _FIRST_FIELDS
+                or getattr(self, f.name) != getattr(default, f.name)))
+
+
+_FIRST_FIELDS = ("kv_heads", "head_dim", "norm", "norm_eps", "qk_norm", "bias",
+                 "ffn", "position", "rope_theta", "attention",
+                 "retention_eps")
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +300,9 @@ def lm_config_from_params(params):
     come from the caller's :class:`ModelSpec`)."""
     embed = _param(params, "embed_weight")
     n = 0
-    while f"layer{n}_q_weight" in params:
+    # a latent layer with low-rank queries has q_a/q_b in q's place
+    while (f"layer{n}_q_weight" in params
+           or f"layer{n}_q_a_weight" in params):
         n += 1
     if n == 0:
         raise MXNetError("no layer0_q_weight: not transformer_lm params")
@@ -214,17 +353,53 @@ def _linear(spec, params, name, x):
                 _param(params, name + "_bias") if spec.bias else None)
 
 
-def rope(x, positions, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: Dict[str, Any]):
+    """YaRN's rotary frequencies for ``dim`` channels (float32
+    ``[dim / 2]``): channel pairs that turn more than ``beta_fast``
+    times over the original context keep their frequency, those that
+    turn fewer than ``beta_slow`` times have it divided by ``factor``,
+    a linear ramp between; and the factor on cos and sin."""
+    half = dim // 2
+    base, orig = float(theta), float(scaling["original_max_position_embeddings"])
+
+    def turn(beta):     # the pair index that turns ``beta`` times
+        return dim * math.log(orig / (beta * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(turn(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(turn(float(scaling.get("beta_slow", 1)))), dim - 1)
+    i = np.arange(half, dtype=np.float64)
+    keep = 1.0 - np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    extra = base ** (-2.0 * i / dim)
+    factor = float(scaling["factor"])
+    inv = (1.0 - keep) * extra / factor + keep * extra
+    mscale = (yarn_mscale(factor, float(scaling.get("mscale", 1)))
+              / yarn_mscale(factor, float(scaling.get("mscale_all_dim", 0))))
+    return inv.astype(np.float32), np.float32(mscale)
+
+
+def rope(x, positions, theta: float, scaling: Optional[Dict[str, Any]] = None):
     """Rotary positions on ``x`` [..., heads, hd] at integer
     ``positions`` [...] (the leading shape of ``x``): the half-split
     ("rotate half") convention of the Qwen/Llama family, float32
-    inside."""
+    inside.  ``scaling`` (a ``rope_scaling`` of type yarn) changes the
+    frequencies and may scale cos and sin."""
     hd = x.shape[-1]
     half = hd // 2
-    inv = np.float32(theta) ** (np.arange(half, dtype=np.float32)
-                                * np.float32(-2.0 / hd))
+    if scaling:
+        inv, mscale = yarn_inv_freq(hd, theta, scaling)
+    else:
+        inv = np.float32(theta) ** (np.arange(half, dtype=np.float32)
+                                    * np.float32(-2.0 / hd))
+        mscale = None
     ang = positions.astype(jnp.float32)[..., None, None] * inv   # [..,1,half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if mscale is not None and mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
@@ -237,45 +412,92 @@ def embed(params, tokens):
                         tokens.astype(jnp.int32), axis=0)
 
 
+def _latent_states(spec: ModelSpec, params, name, hn, positions):
+    """What a latent layer makes of the normed hidden states ``hn``
+    [..., d]: the queries [..., H, nope + rope] with their rotary part
+    rotated, and the row it caches, ``[c | k_r]`` [..., kv_lora_rank +
+    rope]: the normed latent and the ONE rotary key all heads share."""
+    lead = hn.shape[:-1]
+    dn, dr, rank = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                    spec.kv_lora_rank)
+    with jax.named_scope("mla_q"):
+        if spec.q_lora_rank is None:
+            q = _fcm(hn, _param(params, name("q_weight")))
+        else:
+            cq = _rmsm(_fcm(hn, _param(params, name("q_a_weight"))),
+                       _param(params, name("q_a_norm_gamma")), spec.norm_eps)
+            q = _fcm(cq, _param(params, name("q_b_weight")))
+        q = q.reshape(lead + (spec.heads, dn + dr))
+        q = jnp.concatenate(
+            [q[..., :dn], rope(q[..., dn:], positions, spec.rope_theta,
+                               spec.yarn)], axis=-1)
+    with jax.named_scope("mla_kv"):
+        ckr = _fcm(hn, _param(params, name("kv_a_weight")))
+        c = _rmsm(ckr[..., :rank], _param(params, name("kv_a_norm_gamma")),
+                  spec.norm_eps)
+        k_r = rope(ckr[..., None, rank:], positions, spec.rope_theta,
+                   spec.yarn)[..., 0, :]
+        return q, jnp.concatenate([c, k_r], axis=-1)
+
+
 def block(spec: ModelSpec, params, i: int, kind: str, h, positions,
-          attend: Callable):
+          attend: Callable, ffn_kind: str = DENSE,
+          routed: Optional[Callable] = None):
     """One decoder block on hidden states ``h`` ([..., d]).
 
     ``attend(q, k, v, gate)`` receives the per-head states (q
     [..., H, hd]; k, v [..., KV, hd]; ``gate`` the log forget gate
     [..., KV] in float32 for a retention layer, else None), owns the
-    cache, and returns the attention output [..., H, hd].  The parts are
-    ``jax.named_scope``s, so a device trace names them."""
+    cache, and returns the attention output [..., H, hd].  A latent
+    layer hands it ``(q, row, None, None)``: the queries [..., H, nope +
+    rope] and the row it caches ([..., kv_lora_rank + rope]), and gets
+    [..., H, v_head_dim] back.  ``routed(x)`` is the expert layer of a
+    ``"routed"`` block on the normed states (shared experts included).
+    The parts are ``jax.named_scope``s, so a device trace names them."""
     heads, kv, hd = spec.dims(h.shape[-1])
     lead = h.shape[:-1]
 
     def name(suffix):
         return f"layer{i}_{suffix}"
 
-    with jax.named_scope("qkv"):
-        hn = _norm(spec, params, name("ln1"), h)
-        q, k, v = (_linear(spec, params, name(nm), hn)
-                   for nm in ("q", "k", "v"))
-        gate = None
-        if kind == POWER_RETENTION:
-            logit = (_fcm(hn, _param(params, name("gate_weight")))
-                     .astype(jnp.float32)
-                     + _param(params, name("gate_bias")).astype(jnp.float32))
-            gate = jax.nn.log_sigmoid(logit)
-    q = q.reshape(lead + (heads, hd))
-    k = k.reshape(lead + (kv, hd))
-    v = v.reshape(lead + (kv, hd))
-    if spec.qk_norm:
+    if kind == LATENT:
+        with jax.named_scope("mla_q"):
+            hn = _norm(spec, params, name("ln1"), h)
+        q, row = _latent_states(spec, params, name, hn, positions)
+        att = attend(q, row, None, None).reshape(
+            lead + (heads * spec.v_head_dim,))
+    else:
         with jax.named_scope("qkv"):
-            q = _rmsm(q, _param(params, name("q_norm_gamma")), spec.norm_eps)
-            k = _rmsm(k, _param(params, name("k_norm_gamma")), spec.norm_eps)
-    if spec.position == "rope":
-        with jax.named_scope("rope"):
-            q = rope(q, positions, spec.rope_theta)
-            k = rope(k, positions, spec.rope_theta)
-    att = attend(q, k, v, gate).reshape(lead + (heads * hd,))
+            hn = _norm(spec, params, name("ln1"), h)
+            q, k, v = (_linear(spec, params, name(nm), hn)
+                       for nm in ("q", "k", "v"))
+            gate = None
+            if kind == POWER_RETENTION:
+                logit = (_fcm(hn, _param(params, name("gate_weight")))
+                         .astype(jnp.float32)
+                         + _param(params, name("gate_bias")).astype(
+                             jnp.float32))
+                gate = jax.nn.log_sigmoid(logit)
+        q = q.reshape(lead + (heads, hd))
+        k = k.reshape(lead + (kv, hd))
+        v = v.reshape(lead + (kv, hd))
+        if spec.qk_norm:
+            with jax.named_scope("qkv"):
+                q = _rmsm(q, _param(params, name("q_norm_gamma")),
+                          spec.norm_eps)
+                k = _rmsm(k, _param(params, name("k_norm_gamma")),
+                          spec.norm_eps)
+        if spec.position == "rope":
+            with jax.named_scope("rope"):
+                q = rope(q, positions, spec.rope_theta)
+                k = rope(k, positions, spec.rope_theta)
+        att = attend(q, k, v, gate).reshape(lead + (heads * hd,))
     with jax.named_scope("proj"):
         h = h + _linear(spec, params, name("proj"), att)
+    if ffn_kind == ROUTED:
+        with jax.named_scope("router"):
+            hn = _norm(spec, params, name("ln2"), h)
+        return h + routed(hn).astype(h.dtype)
     with jax.named_scope("ffn"):
         hn = _norm(spec, params, name("ln2"), h)
         if spec.ffn == "relu":
@@ -294,19 +516,35 @@ def lm_head(spec: ModelSpec, params, h):
 
 
 def decoder_forward(spec: ModelSpec, params: Dict[str, Any], tokens,
-                    positions, attend: Callable):
+                    positions, attend: Callable, *,
+                    routed: Optional[Callable] = None,
+                    select: Optional[Callable] = None):
     """Logits [..., V] for ``tokens`` [...] (one position per decode row
     ``[B]``, or a chunk / verify window ``[B, C]``) over a caller-owned
     cache.  ``positions`` has the tokens' shape (absolute positions; read
-    only where ``spec.position`` is ``"rope"`` — may be None otherwise).
+    only where ``spec.position`` is ``"rope"`` or a layer is latent --
+    may be None otherwise).
     ``attend(layer, kind, q, k, v, gate)`` extends the caller's cache
     with the new states and returns each position's attention over the
-    cached prefix, itself included (see :func:`block`)."""
+    cached prefix, itself included (see :func:`block`).
+    ``routed(layer, x)`` runs a routed layer's experts on the normed
+    states (None: the plain form of ``models/experts.py``, every held
+    expert over every token).  ``select(h)`` picks the hidden states the
+    head is computed for (None: all)."""
     _, num_layers, _ = lm_config_from_params(params)
     kinds = spec.layer_kinds(num_layers)
+    ffns = spec.ffn_kinds(num_layers)
+    if routed is None and ROUTED in ffns:
+        from .experts import routed_ffn
+
+        def routed(i, x):
+            return routed_ffn(spec, params, i, x)[0]
     h = embed(params, tokens)
-    for i, kind in enumerate(kinds):
+    for i, (kind, ffn_kind) in enumerate(zip(kinds, ffns)):
         h = block(spec, params, i, kind, h, positions,
                   lambda q, k, v, g, i=i, kind=kind: attend(i, kind, q, k,
-                                                           v, g))
+                                                           v, g),
+                  ffn_kind, lambda x, i=i: routed(i, x))
+    if select is not None:
+        h = select(h)
     return lm_head(spec, params, h)

@@ -50,7 +50,7 @@ from .. import chaos as chaos_mod
 from .. import compile_cache as cc
 from .. import telemetry
 from ..base import MXNetError
-from ..models.decoder import ModelSpec, decoder_forward
+from ..models.decoder import LATENT, ROUTED, ModelSpec, decoder_forward
 from ..models.retention import chunk_form
 from ..models.transformer import (lm_config_from_params,
                                   transformer_lm_decode,
@@ -99,6 +99,14 @@ class EngineConfig:
     is refused).  ``Engine.num_layers`` /
     ``heads`` / ``head_dim`` are the model's; ``Request.cached`` counts
     the tokens a request's state has absorbed.
+
+    For a model whose layers are latent (``kvcache`` kind
+    ``paged_latent``) every field reads as for paged K/V: blocks of
+    ``block_size`` rows of ONE pool, ``dtype`` the rows' type; a larger
+    ``block_size`` (128) suits it, since a row is shared by all heads
+    and the decode kernel copies a block at a time.  It ingests prompts
+    through the chunk program (``prefill_chunk > 0``) and refuses
+    ``prefix_cache``, ``speculate`` and ``kv_quant`` by name.
     """
     heads: int = 4
     model: Any = None             # ModelSpec | dict of its fields | None
@@ -367,17 +375,47 @@ class Engine:
         self.cache = kvcache.CacheSpec.for_attention(
             self.model.layer_kinds(self.num_layers))
         self.recurrent = self.cache.recurrent
-        if self.recurrent:
+        self.latent = self.cache.kind == kvcache.PAGED_LATENT
+        self._routed_layers = self.model.ffn_kinds(self.num_layers).count(
+            ROUTED)
+        if self._routed_layers and "experts_held" in self._params:
+            # weights that say which experts they are: the description's
+            # share has to be the same one
+            first, count = self.model.held
+            told = np.asarray(self._params["experts_held"]).tolist()
+            if told != list(range(first, first + count)):
+                raise MXNetError(
+                    f"the parameters hold experts {told[:3]}..{told[-1:]} "
+                    f"({len(told)}), the description says experts_held="
+                    f"{self.model.held}")
+        # which options each cache kind refuses, and why (paged_kv, the
+        # in-tree LM's, takes them all)
+        refused = {
+            kvcache.RECURRENT_STATE: (
+                "needs paged K/V; this model's layers keep a recurrent "
+                f"state ({self.model.attention}): a state has no "
+                "per-token rows to share, roll back or quantize"),
+            kvcache.PAGED_LATENT: (
+                "is not served on a latent cache (kind paged_latent) yet: "
+                "its rows are paged like K/V, but the prefix index has "
+                "not been tried on them, no verify program reads them "
+                "and an 8-bit latent row is another result (ROADMAP R4)"),
+        }.get(self.cache.kind)
+        if refused is not None:
             for name, on in (("prefix_cache", config.prefix_cache),
                              ("speculate", config.speculate),
                              ("kv_quant", config.kv_quant)):
                 if on:
-                    raise ServeError(
-                        "unsupported", -1,
-                        f"EngineConfig.{name} needs paged K/V; this "
-                        "model's layers keep a recurrent state "
-                        f"({self.model.attention}): a state has no "
-                        "per-token rows to share, roll back or quantize")
+                    raise ServeError("unsupported", -1,
+                                     f"EngineConfig.{name} {refused}")
+        if self.latent:
+            if not config.prefill_chunk:
+                raise MXNetError(
+                    "a described model on a paged cache ingests prompts "
+                    "through the chunk program: set prefill_chunk > 0")
+            self.head_dim = (self.model.qk_nope_head_dim
+                             + self.model.qk_rope_head_dim)
+        elif self.recurrent:
             if not config.prefill_chunk:
                 raise MXNetError(
                     "a recurrent-state model ingests prompts through the "
@@ -447,6 +485,11 @@ class Engine:
             self._caches = (kvcache.make_state_pool(
                 self.num_layers, config.num_blocks, self.kv_heads,
                 self.head_dim),)
+        elif self.latent:
+            self._caches = kvcache.make_pools(
+                self.num_layers, config.num_blocks, bs, self.heads,
+                self.head_dim, dtype=config.dtype,
+                latent_width=self.model.latent_width)
         else:
             self._caches = kvcache.make_pools(
                 self.num_layers, config.num_blocks, bs, self.heads,
@@ -521,9 +564,11 @@ class Engine:
                 kvcache.pool_nbytes(self.state) // config.num_blocks)
         else:
             telemetry.gauge("kv_bytes_per_token").set(
-                kvcache.kv_bytes_per_token(self.num_layers, self.heads,
-                                           self.head_dim, config.kv_quant,
-                                           dtype=config.dtype))
+                kvcache.kv_bytes_per_token(
+                    self.num_layers, self.heads, self.head_dim,
+                    config.kv_quant, dtype=config.dtype,
+                    latent_width=(self.model.latent_width if self.latent
+                                  else None)))
 
     # the cache arrays by name
     @property
@@ -537,6 +582,12 @@ class Engine:
     @property
     def state(self):
         """The recurrent-state pool (``kvcache.make_state_pool``)."""
+        return self._caches[0]
+
+    @property
+    def latents(self):
+        """The latent pool (``kvcache.make_pools`` with a
+        ``latent_width``): the one cache array of a latent model."""
         return self._caches[0]
 
     def _run(self, kind: str, bucket: int, *args):
@@ -668,6 +719,8 @@ class Engine:
         whole-prompt program's)."""
         if self.recurrent:
             return self._make_state_chunk_fn(cb)
+        if self.latent:
+            return self._make_paged_chunk_fn(cb)
         heads, nl = self.heads, self.num_layers
         from ..models.transformer import transformer_lm_prefill_chunk
 
@@ -780,9 +833,147 @@ class Engine:
 
         return fn_decode
 
+    # -- a DESCRIBED model on a paged cache --------------------------------
+    # The two makers below run any ``ModelSpec`` through
+    # ``decoder_forward`` over block tables; what a layer's ``attend``
+    # does is chosen by its kind.  The latent kind is the one they know
+    # today; a described softmax layer (position offsets and grouped
+    # heads through the K/V pools, ROADMAP R1) is one more branch of
+    # ``_paged_attend``, not a further family of programs.
+
+    def _routed(self, params, live, stats):
+        """``decoder_forward``'s ``routed`` for this engine: the form
+        whose work follows the assignments where the kernels run (on the
+        chip, or interpreted), the plain form elsewhere.  ``live`` [T]
+        marks the positions that are some request's; each routed layer
+        appends ``(experts_hit, assigned_here)`` to ``stats``."""
+        if not self._routed_layers:
+            return None
+        spec, impl = self.model, self.attn_impl
+        if impl in ("flash", "flash_interpret"):
+            from .moe_experts import routed_ffn
+            kw = dict(interpret=impl == "flash_interpret")
+        else:
+            from ..models.experts import routed_ffn
+            kw = {}
+
+        def routed(i, x):
+            out, hit, here = routed_ffn(spec, params, i, x, live=live, **kw)
+            stats.append((hit, here))
+            return out
+
+        return routed
+
+    def _paged_attend(self, params, pools, write, read):
+        """``decoder_forward``'s ``attend`` over the paged cache:
+        ``write(pool, layer, rows)`` scatters the new positions' rows,
+        ``read(layer, q, w_kvb)`` attends over the cache."""
+        def attend(i, kind, q, row, _v, _gate):
+            if kind != LATENT:
+                raise MXNetError(
+                    f"the paged programs of a described model serve latent "
+                    f"layers; layer {i} is {kind!r} (ROADMAP R1)")
+            with jax.named_scope("latent_write"):
+                pools[0] = write(pools[0], i,
+                                 kvcache.latent_rows(pools[0], row))
+            return read(i, q, params[f"layer{i}_kv_b_weight"])
+
+        return attend
+
+    def _make_paged_chunk_fn(self, cb: int):
+        """The chunk program of a described model on a paged cache: one
+        ``[1, cb]`` slice of a prompt at absolute offset ``start``; the
+        chunk's rows are written, then its positions attend causally
+        over the request's cached prefix in the up-projected form
+        (``kvcache.latent_prefill_attention``).  The head is computed
+        for the one position that is sampled."""
+        spec = self.model
+        rank, nope = spec.kv_lora_rank, spec.qk_nope_head_dim
+        scale = spec.latent_scale()
+
+        def fn_prefill_chunk(pool, params, tokens, start, length, table_row,
+                             key, temp, topk):
+            self.trace_counts[f"prefill_chunk@{cb}"] += 1
+            pools = [pool]
+            positions = start + jnp.arange(cb, dtype=jnp.int32)[None, :]
+            last = jnp.clip(length - 1 - start, 0, cb - 1)
+
+            def write(p, i, rows):
+                return kvcache.write_prefill(p, i, rows[0], table_row,
+                                             length, start=start)
+
+            def read(i, q, w_kvb):
+                return kvcache.latent_prefill_attention(
+                    q[0], pools[0], i, table_row, start, length, w_kvb,
+                    rank=rank, nope=nope, scale=scale)[None]
+
+            logits = decoder_forward(
+                spec, params, tokens, positions,
+                self._paged_attend(params, pools, write, read),
+                routed=self._routed(params, (positions < length)[0], []),
+                select=lambda h: jax.lax.dynamic_slice_in_dim(h, last, 1, 1))
+            with jax.named_scope("sample"):
+                last_logits = logits[0, 0]
+                tok = _sample_row(last_logits, key, temp, topk, length)
+                ok = jnp.all(jnp.isfinite(last_logits.astype(jnp.float32)))
+            return pools[0], tok, ok
+
+        return fn_prefill_chunk
+
+    def _make_paged_decode_fn(self, bb: int):
+        """The decode program of a described model on a paged cache: each
+        row's new latent row is written, then its one query attends over
+        the row's cached ones in the absorbed form (the Pallas kernel
+        ``mxtpu_mla_decode`` with ``attn_impl="flash"``).  Beside the
+        tokens it returns ``[experts_hit, assigned_here]`` summed over
+        the routed layers (zeros where the model has none), so that the
+        one fetch brings them."""
+        spec, impl = self.model, self.attn_impl
+        rank, nope = spec.kv_lora_rank, spec.qk_nope_head_dim
+        scale = spec.latent_scale()
+
+        def fn_decode(pool, params, tokens, tables, lengths, slots, offsets,
+                      active, keys, temps, topks):
+            self.trace_counts[f"decode@{bb}"] += 1
+            pools, stats = [pool], []
+            # a row past ``active`` attends nothing: the kernel reads no
+            # block for it
+            attended = jnp.where(active, lengths + 1, 0)
+
+            def write(p, i, rows):
+                return kvcache.write_decode(p, i, rows, slots, offsets,
+                                            active)
+
+            def read(i, q, w_kvb):
+                with jax.named_scope("attn"):
+                    qa = kvcache.latent_absorb(q, w_kvb, nope)
+                y = kvcache.latent_decode_attention(
+                    qa, pools[0], i, tables, attended, rank=rank,
+                    scale=scale, impl=impl)
+                with jax.named_scope("attn"):
+                    return kvcache.latent_expand(y, w_kvb, nope)
+
+            logits = decoder_forward(
+                spec, params, tokens, lengths,
+                self._paged_attend(params, pools, write, read),
+                routed=self._routed(params, active, stats))
+            with jax.named_scope("sample"):
+                toks = _sample_batch(logits, keys, temps, topks,
+                                     lengths + 1)
+                oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
+                              axis=-1)
+            moe = (jnp.stack([sum(h for h, _ in stats),
+                              sum(n for _, n in stats)]).astype(jnp.int32)
+                   if stats else jnp.zeros((2,), jnp.int32))
+            return pools[0], toks, oks, moe
+
+        return fn_decode
+
     def _make_decode_fn(self, bb: int):
         if self.recurrent:
             return self._make_state_decode_fn(bb)
+        if self.latent:
+            return self._make_paged_decode_fn(bb)
         heads, impl = self.heads, self.attn_impl
 
         def fn_decode(kpool, vpool, params, tokens, tables, lengths, slots,
@@ -923,6 +1114,19 @@ class Engine:
                         i32(b))
             raise MXNetError(f"a recurrent-state model has no {kind!r} "
                              "program")
+        if self.latent:
+            i32 = lambda *s: sds(s, jnp.int32)
+            if kind == "prefill_chunk":
+                return (pool, params, i32(1, bucket), i32(), i32(),
+                        i32(self.max_blocks), key, sds((), jnp.float32),
+                        i32())
+            if kind == "decode":
+                b = bucket
+                return (pool, params, i32(b), i32(b, self.max_blocks),
+                        i32(b), i32(b), i32(b), sds((b,), jnp.bool_),
+                        sds((b, 2), jnp.uint32), sds((b,), jnp.float32),
+                        i32(b))
+            raise MXNetError(f"a latent model has no {kind!r} program")
         if kind == "prefill":
             return (pool, pool, params, sds((1, bucket), jnp.int32),
                     sds((), jnp.int32), sds((self.max_blocks,), jnp.int32),
@@ -1625,11 +1829,19 @@ class Engine:
                         table_blocks=bb * self.max_blocks)
             t0 = time.monotonic()
             with telemetry.span("serve.dispatch", kind="decode", bucket=bb):
-                toks, oks = self._run("decode", bb, tokens, *where, keys,
-                                      temps, topks)
+                toks, oks, *more = self._run("decode", bb, tokens, *where,
+                                             keys, temps, topks)
             with telemetry.span("serve.fetch"):
                 toks = np.asarray(toks)
                 oks = np.asarray(oks)
+                if self._routed_layers:
+                    # the routed layers' counts came with the tokens
+                    hit, here = (int(x) for x in np.asarray(more[0]))
+                    offered = (len(active) * self.model.experts_per_token
+                               * self._routed_layers)
+                    decode_span.annotate(experts_hit=hit, assigned_here=here)
+                    telemetry.counter("serve.moe.assignments").inc(offered)
+                    telemetry.counter("serve.moe.assignments_here").inc(here)
             step_ms = (time.monotonic() - t0) * 1e3
             hist = telemetry.histogram("serve.token_ms")
             with telemetry.span("serve.emit"):

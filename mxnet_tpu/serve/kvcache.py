@@ -1,5 +1,14 @@
 """Paged/blocked KV-cache for autoregressive serving (docs/serving.md).
 
+**Three kinds of per-layer cache** (:class:`CacheSpec`), one
+:class:`BlockAllocator` and one block table a request for all of them:
+``paged_kv`` (softmax attention: K and V rows of ``heads * head_dim``
+lanes in two pools), ``paged_latent`` (latent attention: ONE pool whose
+row is the compressed ``[c | k_r]`` all heads share, under the same
+tables and the same ``write_*`` scatters) and ``recurrent_state`` (one
+fixed-size float32 state a request, a slot and no table).  A model's
+layers are all of one kind; the mix is refused by name.
+
 vLLM-style paging on top of the repo's blockwise-attention machinery:
 key/value states live in **preallocated device pools** of fixed-size
 blocks, and each in-flight request owns a host-side **block table** —
@@ -20,7 +29,10 @@ exit from each program).  No helper here ever takes one layer's slice of
 a pool: readers get the whole pool and the layer, and put the layer among
 the indices of their gather (or of the kernel's block map); writers
 scatter at ``[layer, slot, offset]``.  The head geometry is never read
-off a pool: it comes with the query states (``[..., H, hd]``).
+off a pool: it comes with the query states (``[..., H, hd]``).  A latent
+pool is ``[num_layers, num_blocks, block_size, lanes]`` with ``lanes``
+the row's ``kv_lora_rank + rope`` values rounded up to whole 128-lane
+rows (Mosaic copies whole rows out of HBM): 576 values on 640 lanes.
 
 The device side is three pure functions, all shape-static so the serve
 engine's decode program never retraces:
@@ -73,7 +85,9 @@ from ..parallel.flash_attention import NEG_INF
 from .. import quant as quantmod
 
 __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
-           "PAGED_KV", "RECURRENT_STATE", "CacheSpec", "make_state_pool",
+           "PAGED_KV", "RECURRENT_STATE", "PAGED_LATENT", "CacheSpec",
+           "make_state_pool", "latent_lanes", "latent_decode_attention",
+           "latent_prefill_attention",
            "PrefixIndex", "make_pools", "is_quantized",
            "pool_nbytes", "kv_bytes_per_token", "softmax_scale",
            "paged_attention",
@@ -86,9 +100,10 @@ __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
 #: device-side write unconditional (no retrace-prone masking branches).
 TRASH_BLOCK = 0
 
-#: the two kinds of per-layer cache (:class:`CacheSpec`)
+#: the three kinds of per-layer cache (:class:`CacheSpec`)
 PAGED_KV = "paged_kv"                 # K and V rows that grow with the sequence
 RECURRENT_STATE = "recurrent_state"   # one fixed-size state a request
+PAGED_LATENT = "paged_latent"         # one compressed row a position, paged
 
 #: supported quantized-pool storage formats ("fp8" = e4m3 payload + one
 #: f32 scale per cached position; see :class:`QuantPool`).
@@ -146,13 +161,24 @@ def pool_nbytes(*pools: Pool) -> int:
     return total
 
 
+def latent_lanes(latent_width: int) -> int:
+    """Lanes a latent row is stored on: whole 128-lane rows."""
+    return -(-int(latent_width) // 128) * 128
+
+
 def kv_bytes_per_token(num_layers: int, heads: int, head_dim: int,
                        quant: Optional[str] = None,
-                       dtype=jnp.float32) -> int:
+                       dtype=jnp.float32,
+                       latent_width: Optional[int] = None) -> int:
     """HBM bytes one cached token position occupies across both pools
     (K and V, all layers) — the number the decode path streams per
     token per request.  fp8 pools pay 1 byte/element plus one f32 scale
-    per (layer, position, pool)."""
+    per (layer, position, pool).  ``latent_width`` (a latent model's
+    ``kv_lora_rank + rope``): the ONE pool's row as stored, padding
+    lanes included, since the kernel moves them."""
+    if latent_width is not None:
+        return (num_layers * latent_lanes(latent_width)
+                * jnp.dtype(dtype).itemsize)
     per_pos = heads * head_dim
     if quant is None:
         return 2 * num_layers * per_pos * jnp.dtype(dtype).itemsize
@@ -173,13 +199,18 @@ class CacheSpec(NamedTuple):
     the block pools of :func:`make_pools`; a request owns
     ``ceil(tokens / block_size)`` blocks and grows by one as it decodes.
 
+    ``paged_latent`` (latent attention): the layer's compressed rows
+    (``[c | k_r]``, shared by all heads) live in the ONE pool
+    :func:`make_pools` makes for a ``latent_width``, under the same
+    blocks, tables and growth as ``paged_kv``.
+
     ``recurrent_state`` (power retention): the layer keeps one
     fixed-size float32 state a request, in the pool of
     :func:`make_state_pool`; a request owns ONE state slot from
     admission to finish, cancel, failure or preemption, whatever its
     length.
 
-    Both are handed out by the one :class:`BlockAllocator`: a state
+    All are handed out by the one :class:`BlockAllocator`: a state
     slot is a physical slot that holds any number of tokens
     (``block_size`` = the engine's ``max_seq_len``), so ``num_used``,
     ``check`` and the engine's drain check see slots as they see
@@ -191,22 +222,31 @@ class CacheSpec(NamedTuple):
 
     @classmethod
     def for_attention(cls, attention_kinds: Sequence[str]) -> "CacheSpec":
-        from ..models.decoder import POWER_RETENTION, SOFTMAX
-        table = {SOFTMAX: PAGED_KV, POWER_RETENTION: RECURRENT_STATE}
+        from ..models.decoder import LATENT, POWER_RETENTION, SOFTMAX
+        table = {SOFTMAX: PAGED_KV, POWER_RETENTION: RECURRENT_STATE,
+                 LATENT: PAGED_LATENT}
         return cls(tuple(table[k] for k in attention_kinds))
 
     @property
-    def recurrent(self) -> bool:
-        """Every layer keeps a recurrent state (False: every layer is
-        paged; a model that mixes the two needs blocks AND a slot a
-        request, which no model here asks for yet)."""
+    def kind(self) -> str:
+        """The one kind all layers are of.  A mix is refused: paged_kv
+        with paged_latent needs two pools of different rows under one
+        table (window / global hybrids: ROADMAP R4), and either with
+        recurrent_state needs blocks AND a slot a request; no model here
+        asks for one yet."""
         kinds = set(self.kinds)
         if len(kinds) != 1:
             raise MXNetError(
                 f"a model mixing cache kinds {sorted(kinds)} is not served "
-                "yet: every layer must be paged_kv or every layer "
-                "recurrent_state")
-        return kinds == {RECURRENT_STATE}
+                "yet: every layer must be paged_kv, every layer "
+                "paged_latent or every layer recurrent_state")
+        return self.kinds[0]
+
+    @property
+    def recurrent(self) -> bool:
+        """Every layer keeps a recurrent state (False: every layer is
+        paged, K/V or latent; see :attr:`kind` for the mix)."""
+        return self.kind == RECURRENT_STATE
 
 
 def make_state_pool(num_layers: int, num_slots: int, kv_heads: int,
@@ -549,17 +589,29 @@ class PrefixIndex:
 
 def make_pools(num_layers: int, num_blocks: int, block_size: int,
                heads: int, head_dim: int, dtype=jnp.float32,
-               quant: Optional[str] = None) -> Tuple[Pool, Pool]:
+               quant: Optional[str] = None,
+               latent_width: Optional[int] = None) -> Tuple[Pool, ...]:
     """Preallocate the K and V pools in the stored form:
     ``[num_layers, num_blocks, block_size, heads * head_dim]`` (the
     module docstring says why the heads are flattened onto the minor
     dimension: lanes).
+
+    ``latent_width`` (a latent model's ``kv_lora_rank + rope``): ONE
+    pool, ``[num_layers, num_blocks, block_size,
+    latent_lanes(latent_width)]``: a row all heads share, its first
+    ``kv_lora_rank`` lanes the values too, padded with zero lanes to
+    whole 128-lane rows.  Returned as a 1-tuple; never quantized.
 
     ``quant="fp8"`` returns :class:`QuantPool` pairs instead — e4m3
     payload plus per-position f32 scales — halving cache bytes per token
     (4B -> 1B payload + amortized scale).  Each pool gets its own fresh
     buffers: the engine donates both, and aliased donations are illegal.
     """
+    if latent_width is not None:
+        if quant is not None:
+            raise MXNetError("a latent pool is not quantized (kv_quant)")
+        return (jnp.zeros((num_layers, num_blocks, block_size,
+                           latent_lanes(latent_width)), dtype),)
     shape = (num_layers, num_blocks, block_size, heads * head_dim)
     if quant is None:
         return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
@@ -612,6 +664,18 @@ def _gather_blocks(pool: Pool, layer: int, idx, shape):
 def _flat_heads(states):
     """``[..., H, hd]`` states as the pools store them: ``[..., H * hd]``."""
     return states.reshape(states.shape[:-2] + (-1,))
+
+
+def latent_rows(pool, rows):
+    """Latent rows ``[..., width]`` as the latent ``pool`` stores them:
+    ``[..., 1, lanes]`` in its type, zero lanes after the values (the
+    writers' ``[..., H, hd]`` with one "head" as wide as a row, so
+    :func:`write_prefill` and :func:`write_decode` scatter them like any
+    states)."""
+    pad = pool.shape[-1] - rows.shape[-1]
+    rows = jnp.pad(rows.astype(pool.dtype),
+                   ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
+    return rows[..., None, :]
 
 
 def _attend_blocks(q, read_block, nblk: int, block_size: int, lengths,
@@ -796,6 +860,151 @@ def paged_verify_attention(q, k_pool, v_pool, layer: int, tables, lengths, *,
         l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
         out = jnp.einsum("bchl,blhd->bchd", p, v.astype(f32))
         return (out / l[..., None]).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention over the paged latent pool (kind ``paged_latent``)
+# ---------------------------------------------------------------------------
+
+def _split_kvb(w_kvb, heads: int):
+    """The latents' up-projection ``[heads * (nope + v), rank]`` as one
+    matrix a head, ``[H, nope + v, rank]``: a head's ``nope`` key rows,
+    then its value rows."""
+    return w_kvb.reshape(heads, -1, w_kvb.shape[-1])
+
+
+def latent_absorb(q, w_kvb, nope: int):
+    """The decode form's queries: ``q`` [B, H, nope + rope] -> ``[q~ |
+    q_r]`` [B, H, rank + rope] with ``q~ = q_n W_kvb[keys]``, so that a
+    head's score over a cached row ``[c | k_r]`` is one dot product and
+    no key is ever up-projected."""
+    w = _split_kvb(w_kvb, q.shape[1])[:, :nope]         # [H, nope, rank]
+    qa = jnp.einsum("bhd,hdr->bhr", q[..., :nope], w.astype(q.dtype),
+                    preferred_element_type=jnp.float32)
+    return jnp.concatenate([qa.astype(q.dtype), q[..., nope:]], axis=-1)
+
+
+def latent_expand(y, w_kvb, nope: int):
+    """``softmax(s) c`` [B, H, rank] -> each head's output [B, H, v]: the
+    value half of the up-projection, applied after the attention."""
+    w = _split_kvb(w_kvb, y.shape[1])[:, nope:]         # [H, v, rank]
+    return jnp.einsum("bhr,hvr->bhv", y, w.astype(y.dtype),
+                      preferred_element_type=jnp.float32).astype(y.dtype)
+
+
+def latent_decode_attention(q, pool, layer: int, tables, lengths, *,
+                            rank: int, scale, impl: str = "dense"):
+    """One-token-per-request latent attention, absorbed form.
+
+    ``q``: the absorbed queries [B, H, rank + rope]
+    (:func:`latent_absorb`); ``pool``: the WHOLE latent pool and
+    ``layer`` the layer to read; ``tables`` [B, max_blocks]; ``lengths``
+    [B] valid rows, the current one (already written) included.  Returns
+    ``softmax(s) c`` [B, H, rank] (:func:`latent_expand` makes the
+    heads' outputs of it).
+
+    ``impl``: ``"flash"`` / ``"flash_interpret"`` the Pallas kernel
+    (``serve/mla_decode.py``); anything else gathers the tables' blocks
+    and runs one masked softmax over ``[B, H, L_max]`` in XLA (the CPU
+    engines' reader and the kernel's reference)."""
+    b, h, width = q.shape
+    scale_ = np.float32(scale)
+    if impl in ("flash", "flash_interpret"):
+        from .mla_decode import mla_decode_attention
+        with jax.named_scope("attn"):
+            return mla_decode_attention(
+                q, pool, layer, tables, lengths, rank=rank, scale=scale_,
+                interpret=(impl == "flash_interpret"))
+    f32 = jnp.float32
+    nblk, bs = tables.shape[1], pool.shape[2]
+    rows = _gather_blocks(pool, layer, tables, (b, nblk * bs, pool.shape[-1]))
+    with jax.named_scope("attn"):
+        s = jnp.einsum("bhw,blw->bhl", q, rows[..., :width].astype(q.dtype),
+                       preferred_element_type=f32) * scale_
+        valid = jnp.arange(nblk * bs)[None, :] < lengths[:, None]
+        s = jnp.where(valid[:, None, :], s, NEG_INF)
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(valid[:, None, :], jnp.exp(s - m[..., None]), 0.0)
+        l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
+        out = jnp.einsum("bhl,blr->bhr", p, rows[..., :rank].astype(f32))
+        return (out / l[..., None]).astype(q.dtype)
+
+
+def latent_prefill_attention(q, pool, layer: int, table_row, start, length,
+                             w_kvb, *, rank: int, nope: int, scale,
+                             ctx_block: int = 512, head_group: int = 32):
+    """Causal latent attention for one **prefill chunk**, the
+    up-projected form: per-head keys and values are made of the gathered
+    latents, ``[k_n | v] = W_kvb c``, and each head attends with ``[q_n
+    | q_r] . [k_n | k_r]``.
+
+    ``q``: [C, H, nope + rope] at absolute positions ``start ..
+    start+C-1``; ``pool`` / ``layer``: the whole latent pool and the
+    layer (the chunk's own rows already written); ``table_row``
+    [max_blocks]; ``length``: total valid rows.  Returns [C, H, v].
+
+    The context is walked ``ctx_block`` positions at a time under an
+    online softmax, only as far as the chunk's last query can see
+    (a loop whose trip count is data, so a first chunk does not pay for
+    the table's width), and the heads ``head_group`` at a time: the
+    scores alive at once are ``[head_group, C, ctx_block]`` float32,
+    never heads x chunk x context."""
+    c, h, dq = q.shape
+    nblk, (bs, lanes) = table_row.shape[0], pool.shape[2:]
+    rope_w = dq - nope
+    w = _split_kvb(w_kvb, h).astype(q.dtype)         # [H, nope + v, rank]
+    dv = w.shape[1] - nope
+    per = max(1, ctx_block // bs)                          # pool blocks a walk
+    kb = per * bs
+    walks = -(-nblk // per)
+    table_p = jnp.pad(table_row, (0, walks * per - nblk))  # the trash block
+    g = head_group if h % head_group == 0 else h
+    f32 = jnp.float32
+    qpos = start + jnp.arange(c)
+    seen = jnp.minimum(length, start + c)                  # rows any query sees
+    # heads first, the scale folded in: one batched product a walk and
+    # group, and no pass over the scores to scale them
+    qh = (q.astype(f32) * np.float32(scale)).astype(q.dtype).transpose(1, 0, 2)
+
+    def group(qg, wg):
+        """``qg`` [g, C, nope + rope], ``wg`` [g, nope + v, rank]: these
+        heads over the context, an online softmax a walk."""
+        def walk(j, carry):
+            m, l, acc = carry                    # [g, C], [g, C], [g, C, v]
+            slots = jax.lax.dynamic_slice(table_p, (j * per,), (per,))
+            rows = _gather_blocks(pool, layer, slots, (kb, lanes)).astype(
+                q.dtype)
+            pos = j * kb + jnp.arange(kb)
+            valid = (pos[None, :] <= qpos[:, None]) & (pos[None, :] < length)
+            with jax.named_scope("attn"):
+                kv = jnp.einsum("lr,gdr->gld", rows[:, :rank], wg,
+                                preferred_element_type=f32).astype(q.dtype)
+                keys = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(
+                        rows[None, :, rank:rank + rope_w], (g, kb, rope_w))],
+                    axis=-1)                                  # [g, kb, dq]
+                s = jnp.einsum("gcd,gld->gcl", qg, keys,
+                               preferred_element_type=f32)
+                s = jnp.where(valid[None], s, NEG_INF)
+                # every query sees position 0, so the maximum is a score
+                # from the first walk on and a masked entry's exp is 0
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new[..., None])
+                l = l * alpha + jnp.sum(p, axis=-1)
+                acc = acc * alpha[..., None] + jnp.einsum(
+                    "gcl,glv->gcv", p.astype(q.dtype), kv[..., nope:],
+                    preferred_element_type=f32)
+            return m_new, l, acc
+
+        init = (jnp.full((g, c), NEG_INF, f32), jnp.zeros((g, c), f32),
+                jnp.zeros((g, c, dv), f32))
+        _, l, acc = jax.lax.fori_loop(0, -(-seen // kb), walk, init)
+        return acc / jnp.maximum(l, 1e-30)[..., None]
+
+    out = jnp.concatenate([group(qh[i:i + g], w[i:i + g])
+                           for i in range(0, h, g)], axis=0)   # [H, C, v]
+    return out.transpose(1, 0, 2).astype(q.dtype)
 
 
 def dense_attention(q, k_buf, v_buf, lengths, *, block_size: int,

@@ -114,6 +114,64 @@ def test_recurrent_state_program_makes_no_float64(kind, impl):
     _assert_no_float64(closed, f"{kind}@{bucket} (recurrent state, {impl})")
 
 
+# a latent model with routed experts (ISSUE 35): low-rank queries, one
+# cached row a position, YaRN rotary channels, a dense layer then a
+# routed one; its two programs, with the XLA readers and with both
+# kernels interpreted
+_LATENT = dict(norm="rmsnorm", norm_eps=1e-6, bias=False, ffn="silu_gated",
+               position="rope", rope_theta=10000.0, attention="latent",
+               q_lora_rank=12, kv_lora_rank=16, qk_nope_head_dim=8,
+               qk_rope_head_dim=4, v_head_dim=8,
+               rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                             "beta_slow": 1, "mscale": 0.707,
+                             "mscale_all_dim": 0.707,
+                             "original_max_position_embeddings": 4096},
+               ffn_layers=["dense", "routed"], n_routed_experts=8,
+               experts_per_token=2, n_group=4, topk_group=2,
+               routed_scaling_factor=16.0, experts_held=[2, 4])
+
+
+def _latent_params(seed=0):
+    rng = np.random.RandomState(seed)
+    f, fe = 48, 16
+    shapes = {"embed_weight": (V, D), "final_ln_gamma": (D,),
+              "lm_head_weight": (V, D)}
+    for i in range(NL):
+        p = f"layer{i}_"
+        shapes.update({
+            p + "q_a_weight": (12, D), p + "q_a_norm_gamma": (12,),
+            p + "q_b_weight": (H * 12, 12), p + "kv_a_weight": (20, D),
+            p + "kv_a_norm_gamma": (16,), p + "kv_b_weight": (H * 16, 16),
+            p + "proj_weight": (D, H * 8), p + "ln1_gamma": (D,),
+            p + "ln2_gamma": (D,)})
+    shapes.update({
+        "layer0_ffn_gate_weight": (f, D), "layer0_ffn_up_weight": (f, D),
+        "layer0_ffn_down_weight": (D, f), "layer1_router_weight": (8, D),
+        "layer1_shared_gate_weight": (2 * fe, D),
+        "layer1_shared_up_weight": (2 * fe, D),
+        "layer1_shared_down_weight": (D, 2 * fe),
+        "layer1_experts_gate_weight": (4, D, fe),
+        "layer1_experts_up_weight": (4, D, fe),
+        "layer1_experts_down_weight": (4, fe, D)})
+    return {n: (rng.randn(*s) * 0.05).astype(np.float32)
+            for n, s in shapes.items()}
+
+
+@pytest.mark.parametrize("kind,impl", [("prefill_chunk", "dense"),
+                                       ("prefill_chunk", "flash_interpret"),
+                                       ("decode", "dense"),
+                                       ("decode", "flash_interpret")])
+def test_latent_program_makes_no_float64(kind, impl):
+    eng = Engine(_latent_params(), EngineConfig(
+        heads=H, model=_LATENT, block_size=4, num_blocks=24, max_batch=4,
+        max_prompt_len=16, max_seq_len=48, prefill_chunk=8, attn_impl=impl))
+    bucket = 8 if kind == "prefill_chunk" else 4
+    fn = getattr(eng, _MAKERS[kind])(bucket)
+    closed = jax.make_jaxpr(fn)(*eng._avals(kind, bucket))
+    assert len(closed.jaxpr.eqns) > 20, "nothing was traced"
+    _assert_no_float64(closed, f"{kind}@{bucket} (latent pool, {impl})")
+
+
 @pytest.mark.parametrize("kind,pool,impl", _CASES,
                          ids=["-".join(c) for c in _CASES])
 def test_serving_program_makes_no_float64(kind, pool, impl):

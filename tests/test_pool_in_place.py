@@ -12,7 +12,15 @@ itself once a layer.  Until that PR the pools were stored
 ``[..., heads, head_dim]`` and read through ``pool[layer]``: the decode
 program re-laid both pools out on entry, copied them back, and
 materialised 48 slices (7.38 GB of temporaries).
+
+The same for a LATENT pool (ISSUE 35): ``decode``@64 and
+``prefill_chunk``@1024 of ``benchmark/configs/deepseek-v2-ep4-5of60.json``
+(5 layers x 5120, 128 heads, a row of 576 values on 640 lanes, 2,560
+blocks of 128, 40 of 160 experts held), and both of its kernels
+(``mxtpu_mla_decode``, ``mxtpu_moe_experts``) compiled by Mosaic.
 """
+import importlib.util
+import json
 import os
 import re
 
@@ -186,3 +194,142 @@ def test_kernel_compiles_for_a_v5e(topo, pool, h, hd):
     assert "mxtpu_flash_decode" in comp.as_text()
     if h * hd % 128 == 0:
         assert comp.memory_analysis().temp_size_in_bytes < 4e6
+
+
+# ---------------------------------------------------------------------------
+# the latent pool and the expert layer (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LATENT_CONFIG = os.path.join(REPO, "benchmark", "configs",
+                             "deepseek-v2-ep4-5of60.json")
+
+
+def _latent_shape_engine():
+    """The engine of the latent configuration, from SHAPES: the
+    parameters' shapes are the benchmark reference's own (loaded by path),
+    the pool's ``kvcache.make_pools``'s."""
+    import mxnet_tpu.serve.engine as eng_mod
+    from mxnet_tpu.serve import Engine, EngineConfig
+
+    with open(LATENT_CONFIG) as f:
+        cfg = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "deepseek_v2_reference",
+        os.path.join(REPO, "benchmark", "reference", "deepseek_v2.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    sds = jax.ShapeDtypeStruct
+    real_asarray, real_pools = jnp.asarray, eng_mod.kvcache.make_pools
+    try:
+        eng_mod.jnp.asarray = lambda v, *a, **k: (
+            v if isinstance(v, sds) else real_asarray(v, *a, **k))
+        eng_mod.kvcache.make_pools = lambda *a, **k: jax.eval_shape(
+            lambda: real_pools(*a, **k))
+        engine = dict(cfg["serve"]["engine"], attn_impl="flash")
+        return Engine(
+            {k: sds(s, jnp.bfloat16) for k, s in ref.param_shapes(cfg).items()},
+            EngineConfig(heads=int(cfg["num_attention_heads"]),
+                         dtype=jnp.bfloat16, **engine))
+    finally:
+        eng_mod.jnp.asarray = real_asarray
+        eng_mod.kvcache.make_pools = real_pools
+
+
+@pytest.mark.parametrize("kind,bucket", [("decode", 64),
+                                         ("prefill_chunk", 1024)])
+def test_latent_program_keeps_its_pool_in_place(topo, kind, bucket):
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.serve import kvcache
+
+    eng = _latent_shape_engine()
+    assert eng.latent and len(eng._caches) == 1
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    make = {"decode": eng._make_decode_fn,
+            "prefill_chunk": eng._make_chunk_prefill_fn}[kind]
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._avals(kind, bucket))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        comp = jax.jit(make(bucket), donate_argnums=(0,)).trace(
+            *avals).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    pool = eng._pool_aval()
+    assert pool.shape == (5, 2561, 128, 640)
+    m = comp.memory_analysis()
+    print(kind, bucket, "latent GB: arguments %.2f aliased %.2f temporaries "
+          "%.3f" % (m.argument_size_in_bytes / 1e9, m.alias_size_in_bytes / 1e9,
+                    m.temp_size_in_bytes / 1e9))
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes) < CHIP_BYTES
+    assert m.alias_size_in_bytes >= kvcache.pool_nbytes(pool)
+    # a copy of the pool would be 2.1 GB
+    assert m.temp_size_in_bytes < 1.5e9, m.temp_size_in_bytes
+    sizes = {_counts(pool.shape), _counts(pool.shape[1:])}
+    moved = []
+    for line in comp.as_text().splitlines():
+        # (a ``bitcast`` moves nothing: the chunk's scatter sees the pool
+        # as [positions, lanes] through one)
+        hit = re.search(r"= \(?(\w+)\[([\d,]+)\]\S* (copy|slice|"
+                        r"dynamic-slice|transpose)\(", line)
+        if hit and _counts(int(d) for d in hit.group(2).split(",")) in sizes:
+            moved.append(line.strip()[:200])
+    assert not moved, moved[:3]
+    calls = [ln for ln in comp.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    # the grouped product twice a routed layer (gate and up in one pass,
+    # then down), the decode kernel on the pool once a layer
+    assert sum("mxtpu_moe_experts" in ln for ln in calls) == 2 * 4
+    assert sum("mxtpu_mla_decode" in ln for ln in calls) == (
+        5 if kind == "decode" else 0)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_latent_kernels_compile_for_a_v5e(topo, dtype):
+    """Mosaic COMPILES ``mxtpu_mla_decode`` and ``mxtpu_moe_experts`` at
+    the published widths (128 heads over rows of 640 lanes in blocks of
+    128; 40 experts of 5120 x 1536, decode's and a chunk's row tiles),
+    and neither call keeps a pool- or weight-sized temporary."""
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.serve import moe_experts
+    from mxnet_tpu.serve.mla_decode import mla_decode_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    dt = jnp.dtype(dtype)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compiled(fn, *avals):
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            return jax.jit(fn).trace(*avals).lower(
+                lowering_platforms=("tpu",)).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+
+    comp = compiled(
+        lambda q, pool, t, n: mla_decode_attention(
+            q, pool, 1, t, n, rank=512, scale=0.1147),
+        sds((64, 128, 576), dt), sds((3, 256, 128, 640), dt),
+        sds((64, 48), jnp.int32), sds((64,), jnp.int32))
+    assert "mxtpu_mla_decode" in comp.as_text()
+    assert comp.memory_analysis().temp_size_in_bytes < 40e6
+
+    held, d, f = 8, 5120, 1536
+    for tokens in (64, 1024):
+        def experts(x, local, mask, wg, wu, wd):
+            tm = moe_experts.row_tile(local.size, wg.dtype)
+            p = moe_experts.plan(local, mask, held, tm)
+            xs = jnp.take(x, p.src, axis=0)
+            h = moe_experts.grouped_matmul(xs, (wg, wu), p, tm,
+                                           column_tile=512)
+            return moe_experts.grouped_matmul(h, (wd,), p, tm,
+                                              column_tile=1280)
+        comp = compiled(
+            experts, sds((tokens, d), dt), sds((tokens, 6), jnp.int32),
+            sds((tokens, 6), jnp.bool_), sds((held, d, f), dt),
+            sds((held, d, f), dt), sds((held, f, d), dt))
+        assert comp.as_text().count("mxtpu_moe_experts") >= 2

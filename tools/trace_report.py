@@ -23,7 +23,9 @@ anywhere.  It prints what ``PERF.md`` section 5 is written from:
 * the span ring's medians beside the device's, so a span that stopped
   covering its program shows, and the window's ratio of the
   ``serve.decode`` spans' ``live_blocks`` to ``table_blocks`` (what the
-  decode attention read of what its tables could hold).
+  decode attention read of what its tables could hold) and, for a
+  model with routed layers, ``[experts]``: ``experts_hit`` and
+  ``assigned_here`` a step.
 
 It measures nothing the benchmark reports and changes no number of it.
 """
@@ -256,6 +258,18 @@ def read(stem: str) -> None:
                   f"{live} of table_blocks {table} = {100 * live / table:.2f} %"
                   f" ({live / len(walks):.1f} of {table / len(walks):.0f} a "
                   "step)")
+        # what the routed layers' held experts were given
+        routed = [ev["args"] for ev in spans if ev["name"] == "serve.decode"
+                  and "experts_hit" in ev["args"]]
+        if routed:
+            hit = sum(a["experts_hit"] for a in routed) / len(routed)
+            here = sum(a["assigned_here"] for a in routed) / len(routed)
+            rows = sum(a["active"] for a in routed) / len(routed)
+            print(f"[experts] {len(routed)} serve.decode spans: experts_hit "
+                  f"{hit:.1f} a step (held experts with an assignment, "
+                  f"summed over the routed layers), assigned_here {here:.1f} "
+                  f"a step = {here / max(hit, 1e-9):.2f} rows a hit expert, "
+                  f"{here / max(rows, 1e-9):.2f} a decoded row")
 
 
 def main() -> int:
