@@ -199,6 +199,48 @@ class EngineConfig:
         return impl
 
 
+def _kth_largest(x, k):
+    """The ``k``-th largest value along the last axis of float32 ``x``
+    (``k`` clipped to 1..V; one for each leading index, or one for all
+    of them) by selection: the float, bit for bit, that
+    ``flip(sort(x))[k - 1]`` holds, without ordering the rest.
+
+    A float's bits, with the sign bit set on a non-negative value and
+    all of them inverted on a negative one, order as unsigned integers
+    the way the values do.  The answer's image is the largest ``t`` with
+    ``count(image >= t) >= k``; the count only falls as ``t`` grows, so
+    ``t`` is built a bit at a time from the top: 32 passes of a compare
+    and a row sum, whatever ``k`` is.  (A sort calls -0.0 and 0.0 equal
+    and this orders them; ``x < kth`` reads the same either way.)
+    """
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    image = jnp.where(bits >= top, ~bits, bits | top)
+    k = jnp.broadcast_to(jnp.clip(k, 1, x.shape[-1]), x.shape[:-1])
+
+    def keep_bit(i, t):
+        cand = t | (top >> i.astype(jnp.uint32))
+        count = jnp.sum(image >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(count >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, keep_bit, jnp.zeros(k.shape, jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(t >= top, t ^ top, ~t), jnp.float32)
+
+
+def _greedy(logits):
+    """The token of a ``temp == 0`` row: both branches of
+    :func:`_sample` take it by this ``argmax`` of the float32 logits."""
+    return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+
+
+def _topk_masked(scaled, topk):
+    """``scaled`` with everything under its ``topk``-th largest value
+    masked out (ties at that value stay); ``topk <= 0`` keeps all."""
+    kth = _kth_largest(scaled, topk)[..., None]
+    return jnp.where((topk > 0)[..., None] & (scaled < kth), _NEG, scaled)
+
+
 def _sample_row(logits, key, temp, topk, pos):
     """Greedy / temperature / top-k sampling for one row.
 
@@ -206,18 +248,37 @@ def _sample_row(logits, key, temp, topk, pos):
     function of the request key and the logits — independent of batch
     composition, admission order, or preemption restarts.
     """
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temp, 1e-6)
-    vocab = logits.shape[-1]
-    kth = jnp.flip(jnp.sort(scaled), -1)[jnp.clip(topk - 1, 0, vocab - 1)]
-    masked = jnp.where((topk > 0) & (scaled < kth), _NEG, scaled)
+    scaled = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
     sampled = jax.random.categorical(
-        jax.random.fold_in(key, pos), masked).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy)
+        jax.random.fold_in(key, pos),
+        _topk_masked(scaled, topk)).astype(jnp.int32)
+    return jnp.where(temp > 0, sampled, _greedy(logits))
 
 
 _sample_batch = jax.vmap(_sample_row, in_axes=(0, 0, 0, 0, 0))
+
+
+def _sample(logits, keys, temps, topks, pos):
+    """Sample one row (``logits`` [V], scalars beside it) or a batch
+    ([B, V]).  When no row has ``temp > 0`` — the common serving case,
+    padding rows included — the program takes the ``argmax`` and nothing
+    else: ``lax.cond`` executes only the taken branch, so no division,
+    no selection, no threefry.  A greedy row's token is ``_greedy`` of
+    the same logits in both branches, so the branch taken can never
+    change a stream; :func:`_sampler_branch` names it on the host."""
+    rows = _sample_row if logits.ndim == 1 else _sample_batch
+    return jax.lax.cond(
+        jnp.any(temps > 0.0),
+        lambda: rows(logits, keys, temps, topks, pos),
+        lambda: _greedy(logits))
+
+
+def _sampler_branch(temps) -> str:
+    """Which branch of :func:`_sample` (or of :func:`_spec_accept`) a
+    step's program takes, by the program's own predicate on the host's
+    copy of ``temps``: the ``serve.decode`` span's ``sampler`` arg."""
+    return "select" if np.any(temps > 0.0) else "greedy"
+
 
 # PRNG salts: acceptance-u and residual draws fold one extra constant
 # into the per-position key chain (``fold_in(key, pos)``), so they are
@@ -256,15 +317,12 @@ def _spec_accept_row(logits, toks, live, key, temp, topk, length):
     emitted tokens (accepted drafts + the correction/bonus token).
     """
     logits = logits.astype(jnp.float32)
-    c, vocab = logits.shape
+    c = logits.shape[0]
     k = c - 1
     draft = toks[1:]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    greedy = _greedy(logits)
     scaled = logits / jnp.maximum(temp, 1e-6)
-    kth = jnp.take_along_axis(
-        jnp.flip(jnp.sort(scaled, axis=-1), -1),
-        jnp.full((c, 1), jnp.clip(topk - 1, 0, vocab - 1)), axis=-1)
-    masked = jnp.where((topk > 0) & (scaled < kth), _NEG, scaled)
+    masked = _topk_masked(scaled, topk)
     probs = jax.nn.softmax(masked, axis=-1)
     pos = length + 1 + jnp.arange(c)
 
@@ -306,7 +364,7 @@ def _spec_accept_row_greedy(logits, toks, live):
     """Greedy-only acceptance: for temp == 0 the full rule collapses
     to pure argmax (accept iff draft == argmax; both the correction
     and the bonus token ARE ``argmax(logits[a])``), so an all-greedy
-    batch needs no sort, no softmax, no PRNG.  Produces exactly the
+    batch needs no selection, no softmax, no PRNG.  Produces exactly the
     integers :func:`_spec_accept_row` produces at temp == 0 — the
     verify program picks this branch under ``lax.cond``, so greedy
     byte-identity is preserved by construction."""
@@ -314,7 +372,7 @@ def _spec_accept_row_greedy(logits, toks, live):
     c = logits.shape[0]
     k = c - 1
     draft = toks[1:]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    greedy = _greedy(logits)
     acc = (greedy[:k] == draft) & (jnp.arange(k) < live)
     a = jnp.sum(jnp.cumprod(acc.astype(jnp.int32)))
     idx = jnp.arange(c)
@@ -332,7 +390,7 @@ def _spec_accept(logits, tokens, live, keys, temps, topks, lengths):
     """Batch acceptance with an all-greedy fast path.  ``lax.cond``
     executes only the taken branch, so a greedy batch (the common
     serving case, and the accept-friendly bench row) skips the top-k
-    sort, softmax, and threefry chains entirely; any temperature row
+    selection, softmax, and threefry chains entirely; any temperature row
     in the batch routes the whole batch through the full rule.  Both
     branches emit identical integers for temp == 0 rows, so the
     branch choice can never change a stream."""
@@ -705,7 +763,7 @@ class Engine:
                                               table_row, length)
             with jax.named_scope("sample"):
                 last = jnp.take(logits[0], length - 1, axis=0)
-                tok = _sample_row(last, key, temp, topk, length)
+                tok = _sample(last, key, temp, topk, length)
                 ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
             return kpool, vpool, tok, ok
 
@@ -750,7 +808,7 @@ class Engine:
                 last = jnp.take(logits[0],
                                 jnp.clip(length - 1 - start, 0, cb - 1),
                                 axis=0)
-                tok = _sample_row(last, key, temp, topk, length)
+                tok = _sample(last, key, temp, topk, length)
                 ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
             return pools[0], pools[1], tok, ok
 
@@ -792,7 +850,7 @@ class Engine:
                 last = jnp.take(logits[0],
                                 jnp.clip(length - 1 - start, 0, cb - 1),
                                 axis=0)
-                tok = _sample_row(last, key, temp, topk, length)
+                tok = _sample(last, key, temp, topk, length)
                 ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)))
             return pool[0], tok, ok
 
@@ -825,8 +883,7 @@ class Engine:
 
             logits = decoder_forward(spec, params, tokens, lengths, attend)
             with jax.named_scope("sample"):
-                toks = _sample_batch(logits, keys, temps, topks,
-                                     lengths + 1)
+                toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
                               axis=-1)
             return pool[0], toks, oks
@@ -914,7 +971,7 @@ class Engine:
                 select=lambda h: jax.lax.dynamic_slice_in_dim(h, last, 1, 1))
             with jax.named_scope("sample"):
                 last_logits = logits[0, 0]
-                tok = _sample_row(last_logits, key, temp, topk, length)
+                tok = _sample(last_logits, key, temp, topk, length)
                 ok = jnp.all(jnp.isfinite(last_logits.astype(jnp.float32)))
             return pools[0], tok, ok
 
@@ -958,8 +1015,7 @@ class Engine:
                 self._paged_attend(params, pools, write, read),
                 routed=self._routed(params, active, stats))
             with jax.named_scope("sample"):
-                toks = _sample_batch(logits, keys, temps, topks,
-                                     lengths + 1)
+                toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
                               axis=-1)
             moe = (jnp.stack([sum(h for h, _ in stats),
@@ -995,8 +1051,7 @@ class Engine:
             logits = transformer_lm_decode(params, tokens, heads=heads,
                                            attend=attend)
             with jax.named_scope("sample"):
-                toks = _sample_batch(logits, keys, temps, topks,
-                                     lengths + 1)
+                toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
                               axis=-1)
             return pools[0], pools[1], toks, oks
@@ -1813,6 +1868,7 @@ class Engine:
                     keys[i] = req.key
                     temps[i] = req.temperature
                     topks[i] = req.top_k
+                decode_span.annotate(sampler=_sampler_branch(temps))
                 if self.recurrent:
                     # ``slots`` are the rows' state slots (a request's
                     # one "block"); rows past ``active`` keep the trash
@@ -1891,7 +1947,7 @@ class Engine:
             self.spec.propose([r.seed_tokens for r in active], k),
             np.int32)
         with telemetry.span("serve.decode", step=self.step_idx, bucket=bb,
-                            active=len(active), spec_k=k):
+                            active=len(active), spec_k=k) as decode_span:
             with telemetry.span("serve.build"):
                 # drafter hygiene: a wrong draft is wasted width, an
                 # out-of-range id would be an invalid embedding lookup
@@ -1914,6 +1970,7 @@ class Engine:
                     keys[i] = req.key
                     temps[i] = req.temperature
                     topks[i] = req.top_k
+                decode_span.annotate(sampler=_sampler_branch(temps))
             t0 = time.monotonic()
             with telemetry.span("serve.dispatch", kind="verify", bucket=bb):
                 out, nem, oks = self._run(

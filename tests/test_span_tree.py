@@ -156,6 +156,23 @@ def test_decode_spans_count_live_and_table_blocks(kind):
     assert max(a["live_blocks"] for a in decodes if a["active"] == 1) >= 3
 
 
+@pytest.mark.parametrize("kind", sorted(_KINDS))
+def test_decode_spans_name_the_samplers_branch(kind):
+    """``serve.decode`` says which branch of the sampler its program
+    took, by the program's predicate on the host's ``temps``: the one
+    sampled request (5 tokens, the first its prefill's) opens the
+    sampled branch in the steps that decode it; a step of greedy rows
+    alone takes the ``argmax``."""
+    _, spans = _recorded(kind)
+    branches = [ev["args"]["sampler"] for ev in spans.values()
+                if ev["name"] == "serve.decode"]
+    assert set(branches) == {"greedy", "select"}
+    if kind == "speculative":       # a verify step emits 1..K+1 tokens
+        assert 1 <= branches.count("select") <= 4
+    else:
+        assert branches.count("select") == 4
+
+
 # -- (b) the clock stops after the fetch -------------------------------------
 
 @pytest.mark.parametrize("kind", ["chunked", "whole"])

@@ -23,9 +23,11 @@ anywhere.  It prints what ``PERF.md`` section 5 is written from:
 * the span ring's medians beside the device's, so a span that stopped
   covering its program shows, and the window's ratio of the
   ``serve.decode`` spans' ``live_blocks`` to ``table_blocks`` (what the
-  decode attention read of what its tables could hold) and, for a
-  model with routed layers, ``[experts]``: ``experts_hit`` and
-  ``assigned_here`` a step.
+  decode attention read of what its tables could hold), ``[sampler]``:
+  the steps by the branch their program's sampler took (``greedy``: an
+  ``argmax`` alone; ``select``: a row samples) and, for a model with
+  routed layers, ``[experts]``: ``experts_hit`` and ``assigned_here`` a
+  step.
 
 It measures nothing the benchmark reports and changes no number of it.
 """
@@ -258,6 +260,16 @@ def read(stem: str) -> None:
                   f"{live} of table_blocks {table} = {100 * live / table:.2f} %"
                   f" ({live / len(walks):.1f} of {table / len(walks):.0f} a "
                   "step)")
+        # which branch of the sampler each step's program took
+        branches = collections.Counter(
+            ev["args"]["sampler"] for ev in spans
+            if ev["name"] == "serve.decode" and "sampler" in ev["args"])
+        if branches:
+            n = sum(branches.values())
+            print(f"[sampler] {n} serve.decode spans by the sampler's "
+                  "branch: " + ", ".join(
+                      f"{k} {v} ({100 * v / n:.2f} %)"
+                      for k, v in branches.most_common()))
         # what the routed layers' held experts were given
         routed = [ev["args"] for ev in spans if ev["name"] == "serve.decode"
                   and "experts_hit" in ev["args"]]
