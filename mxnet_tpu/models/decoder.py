@@ -13,8 +13,12 @@ programs are the ones the twins traced before (same ops, same order).
 Attention kinds (``ModelSpec.attention``, one name for all layers or a
 tuple with one name per layer):
 
-* ``"softmax"`` — causal softmax attention; the cache is paged K and V
-  (``serve.kvcache`` kind ``paged_kv``).
+* ``"softmax"`` — causal softmax attention over the whole prefix; the
+  cache is paged K and V (``serve.kvcache`` kind ``paged_kv``).
+* ``"sliding"`` — causal softmax attention over the last
+  ``sliding_window`` positions (itself included); its K and V rows live
+  in a ring of blocks a request (kind ``paged_window``).  A model may mix
+  it with ``"softmax"`` layers (window and global layers, one allocator).
 * ``"power_retention"`` — linear attention with a degree-2 kernel and a
   learned per-kv-head forget gate (``models/retention.py``); the cache
   is one fixed-size state per request and layer (kind
@@ -28,14 +32,23 @@ tuple with one name per layer):
   ``qk_rope_head_dim`` channels only.  The cache is that row, paged
   (kind ``paged_latent``).
 
-A window/global or hybrid model adds a kind here and a cache kind in
+Softmax layers may have grouped heads (``kv_heads`` < ``heads``: query
+head ``i`` reads key/value head ``i // (heads / kv_heads)``), rotary
+positions on every layer (``position="rope"``) or on the sliding ones
+only (``"rope_sliding"``), an output gate (``attn_gate``: ``o = W_o
+(sigmoid(W_g u) * attention)``) and a "sandwich" of norms
+(``sandwich_norm``: an RMSNorm of the attention's and of the FFN's
+output before each residual add).  ``embed_scale`` multiplies the
+embedding.  A hybrid model adds a kind here and a cache kind in
 ``serve.kvcache``; it does not add a file of twins.
 
 The FFN of a layer is dense (``ffn``: ReLU or gated SiLU) or, where
 ``ffn_layers`` says ``"routed"``, an expert layer
 (``models/experts.py``): a router over ``n_routed_experts`` with
 ``experts_per_token`` chosen (``group_limited_greedy`` over ``n_group``
-groups of which ``topk_group`` are kept), shared experts beside them,
+groups of which ``topk_group`` are kept; scores ``score_func`` softmax
+or sigmoid, and with ``router_bias`` a selection bias that decides the
+choice and not the weights), shared experts beside them,
 and **the experts this chip holds** (``experts_held`` = first, count):
 the layer routes over all of them and computes the held ones' part.
 
@@ -43,11 +56,14 @@ Parameter names (``layer{i}_`` prefix; FullyConnected weights are
 ``[out, in]``): ``q/k/v/proj_weight`` (+ ``_bias`` when ``bias``),
 ``ln1/ln2_gamma`` (+ ``_beta`` for LayerNorm), ``ffn1/ffn2`` (ReLU) or
 ``ffn_gate/ffn_up/ffn_down`` (gated SiLU), ``q_norm/k_norm_gamma``
-(``qk_norm``), ``gate_weight`` ``[kv_heads, d]`` + ``gate_bias``
+(``qk_norm``), ``attn_gate_weight`` ``[heads * hd, d]`` (``attn_gate``),
+``post_attn_norm/post_ffn_norm_gamma`` (``sandwich_norm``),
+``gate_weight`` ``[kv_heads, d]`` + ``gate_bias``
 (retention layers); ``q_a/q_b_weight`` + ``q_a_norm_gamma`` (or
 ``q_weight``), ``kv_a/kv_b_weight`` + ``kv_a_norm_gamma`` (latent
 layers; ``kv_b`` is ``[heads * (nope + v), kv_lora_rank]``, a head's
-key rows before its value rows); ``router_weight`` ``[experts, d]``,
+key rows before its value rows); ``router_weight`` ``[experts, d]``
+(+ ``router_bias`` ``[experts]`` float32),
 ``shared_gate/up/down_weight``, ``experts_gate/up_weight`` ``[held, d,
 width]`` and ``experts_down_weight`` ``[held, width, d]`` (routed
 layers: a held expert's matrices are ``[in, out]``, as the grouped
@@ -67,12 +83,14 @@ import jax.numpy as jnp
 from ..base import MXNetError
 
 __all__ = ["ModelSpec", "decoder_forward", "lm_config_from_params",
-           "SOFTMAX", "POWER_RETENTION", "LATENT", "DENSE", "ROUTED"]
+           "SOFTMAX", "SLIDING", "POWER_RETENTION", "LATENT", "DENSE",
+           "ROUTED"]
 
 SOFTMAX = "softmax"
+SLIDING = "sliding"
 POWER_RETENTION = "power_retention"
 LATENT = "latent"
-_ATTENTION_KINDS = (SOFTMAX, POWER_RETENTION, LATENT)
+_ATTENTION_KINDS = (SOFTMAX, SLIDING, POWER_RETENTION, LATENT)
 DENSE = "dense"
 ROUTED = "routed"
 
@@ -91,7 +109,7 @@ class ModelSpec:
     qk_norm: bool = False               # per-head RMSNorm of q and k
     bias: bool = True                   # projections, FFN and head biased
     ffn: str = "relu"                   # "relu" | "silu_gated"
-    position: str = "none"              # "none" | "rope"
+    position: str = "none"              # "none" | "rope" | "rope_sliding"
     rope_theta: float = 10000.0
     attention: Union[str, Tuple[str, ...]] = SOFTMAX
     retention_eps: float = 1e-6         # the retention normaliser's eps
@@ -113,6 +131,13 @@ class ModelSpec:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = False
     experts_held: Optional[Tuple[int, int]] = None  # (first, count); None: all
+    score_func: str = "softmax"         # router scores: softmax | sigmoid
+    router_bias: bool = False           # a selection bias: chooses only
+    # -- window layers, and what a softmax block adds around attention --
+    sliding_window: int = 0             # positions a "sliding" query sees
+    attn_gate: bool = False             # o = W_o(sigmoid(W_g u) * attention)
+    sandwich_norm: bool = False         # norm attention's, FFN's outputs
+    embed_scale: float = 1.0            # h_0 = embed_scale * E[token]
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -121,9 +146,12 @@ class ModelSpec:
         if self.ffn not in ("relu", "silu_gated"):
             raise MXNetError(f"ModelSpec.ffn {self.ffn!r}: expected 'relu' "
                              "or 'silu_gated'")
-        if self.position not in ("none", "rope"):
+        if self.position not in ("none", "rope", "rope_sliding"):
             raise MXNetError(f"ModelSpec.position {self.position!r}: "
-                             "expected 'none' or 'rope'")
+                             "expected 'none', 'rope' or 'rope_sliding'")
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise MXNetError(f"ModelSpec.score_func {self.score_func!r}: "
+                             "expected 'softmax' or 'sigmoid'")
         kinds = ((self.attention,) if isinstance(self.attention, str)
                  else tuple(self.attention))
         for k in kinds:
@@ -132,6 +160,9 @@ class ModelSpec:
                                  f"one of {_ATTENTION_KINDS}")
         if not isinstance(self.attention, str):
             object.__setattr__(self, "attention", kinds)
+        if SLIDING in kinds and self.sliding_window < 1:
+            raise MXNetError("a sliding layer needs sliding_window >= 1, "
+                             f"got {self.sliding_window}")
         if LATENT in kinds:
             sizes = (self.kv_lora_rank, self.qk_nope_head_dim,
                      self.qk_rope_head_dim, self.v_head_dim)
@@ -260,6 +291,11 @@ class ModelSpec:
                 f"ModelSpec.attention names {len(self.attention)} layers, "
                 f"the parameters hold {num_layers}")
         return self.attention
+
+    def rotates(self, kind: str) -> bool:
+        """Whether a softmax layer of ``kind`` takes rotary positions."""
+        return self.position == "rope" or (
+            self.position == "rope_sliding" and kind == SLIDING)
 
     def signature(self) -> str:
         """A short stable string for program-cache fingerprints; empty
@@ -487,17 +523,24 @@ def block(spec: ModelSpec, params, i: int, kind: str, h, positions,
                           spec.norm_eps)
                 k = _rmsm(k, _param(params, name("k_norm_gamma")),
                           spec.norm_eps)
-        if spec.position == "rope":
+        if spec.rotates(kind):
             with jax.named_scope("rope"):
                 q = rope(q, positions, spec.rope_theta)
                 k = rope(k, positions, spec.rope_theta)
         att = attend(q, k, v, gate).reshape(lead + (heads * hd,))
+        if spec.attn_gate:
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid(_fcm(hn, _param(params, name(
+                    "attn_gate_weight"))).astype(jnp.float32))
+                att = (att.astype(jnp.float32) * g).astype(att.dtype)
     with jax.named_scope("proj"):
-        h = h + _linear(spec, params, name("proj"), att)
+        h = _residual(spec, params, name("post_attn_norm"), h,
+                      _linear(spec, params, name("proj"), att))
     if ffn_kind == ROUTED:
         with jax.named_scope("router"):
             hn = _norm(spec, params, name("ln2"), h)
-        return h + routed(hn).astype(h.dtype)
+        return _residual(spec, params, name("post_ffn_norm"), h,
+                         routed(hn).astype(h.dtype))
     with jax.named_scope("ffn"):
         hn = _norm(spec, params, name("ln2"), h)
         if spec.ffn == "relu":
@@ -506,7 +549,15 @@ def block(spec: ModelSpec, params, i: int, kind: str, h, positions,
             return h + _linear(spec, params, name("ffn2"), f)
         f = (jax.nn.silu(_linear(spec, params, name("ffn_gate"), hn))
              * _linear(spec, params, name("ffn_up"), hn))
-        return h + _linear(spec, params, name("ffn_down"), f)
+        return _residual(spec, params, name("post_ffn_norm"), h,
+                         _linear(spec, params, name("ffn_down"), f))
+
+
+def _residual(spec: ModelSpec, params, norm: str, h, out):
+    """``h + out``, the output normed first under ``sandwich_norm``."""
+    if spec.sandwich_norm:
+        out = _rmsm(out, _param(params, norm + "_gamma"), spec.norm_eps)
+    return h + out
 
 
 def lm_head(spec: ModelSpec, params, h):
@@ -540,6 +591,9 @@ def decoder_forward(spec: ModelSpec, params: Dict[str, Any], tokens,
         def routed(i, x):
             return routed_ffn(spec, params, i, x)[0]
     h = embed(params, tokens)
+    if spec.embed_scale != 1.0:
+        h = (h.astype(jnp.float32) * np.float32(spec.embed_scale)).astype(
+            h.dtype)
     for i, (kind, ffn_kind) in enumerate(zip(kinds, ffns)):
         h = block(spec, params, i, kind, h, positions,
                   lambda q, k, v, g, i=i, kind=kind: attend(i, kind, q, k,

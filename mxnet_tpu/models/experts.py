@@ -1,10 +1,13 @@
 """A routed expert layer of a described decoder (``ModelSpec.ffn_layers``
 ``"routed"``), told **which experts this chip holds**.
 
-    p   = softmax(W_g x) over all ``n_routed_experts``, float32
-    keep the ``topk_group`` groups (of ``n_group``) whose largest p is
-    largest, zero the rest; choose the ``experts_per_token`` largest p
-    w_e = routed_scaling_factor * p_e   (/ their sum if norm_topk_prob)
+    p   = softmax(W_g x) over all ``n_routed_experts`` (or sigmoid(W_g x)
+          with ``score_func`` "sigmoid"), float32;  c = p (+ b, the
+          selection bias, with ``router_bias``)
+    keep the ``topk_group`` groups (of ``n_group``) whose largest c is
+    largest, zero the rest; choose the ``experts_per_token`` largest c
+    w_e = routed_scaling_factor * p_e   (/ the chosen p's sum if
+          norm_topk_prob: over all chosen, held here or not)
     out = shared(x) + sum over the chosen e HELD HERE of w_e expert_e(x)
 
 The router keeps its published width and its experts per token whatever
@@ -29,7 +32,8 @@ import numpy as np
 
 from .decoder import ModelSpec, _fcm, _param
 
-__all__ = ["router_logits", "route", "held_assignments", "shared_ffn", "routed_ffn"]
+__all__ = ["router_logits", "route", "router_bias", "held_assignments",
+           "shared_ffn", "routed_ffn"]
 
 
 def router_logits(x, w_router):
@@ -41,23 +45,34 @@ def router_logits(x, w_router):
         precision=jax.lax.Precision.HIGHEST)
 
 
-def route(spec: ModelSpec, x, w_router):
+def route(spec: ModelSpec, x, w_router, bias=None):
     """``x`` [T, d] -> the chosen experts ``[T, k]`` int32 (ids among
-    all ``n_routed_experts``) and their weights ``[T, k]`` float32."""
+    all ``n_routed_experts``) and their weights ``[T, k]`` float32.
+    ``bias`` [experts] (``spec.router_bias``): added to the scores for
+    the choice alone; a chosen expert's weight is its score."""
     n, g, k = spec.n_routed_experts, spec.n_group, spec.experts_per_token
-    p = jax.nn.softmax(router_logits(x, w_router), axis=-1)
+    logits = router_logits(x, w_router)
+    p = (jax.nn.sigmoid(logits) if spec.score_func == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    p_in = p if bias is None else p + bias.astype(jnp.float32)
     if g > 1:
-        best = jnp.max(p.reshape(-1, g, n // g), axis=-1)          # [T, g]
+        best = jnp.max(p_in.reshape(-1, g, n // g), axis=-1)       # [T, g]
         _, kept = jax.lax.top_k(best, spec.topk_group)
         mask = jnp.zeros_like(best).at[
             jnp.arange(best.shape[0])[:, None], kept].set(1.0)
-        p_in = (p.reshape(-1, g, n // g) * mask[..., None]).reshape(-1, n)
-    else:
-        p_in = p
+        p_in = (p_in.reshape(-1, g, n // g) * mask[..., None]).reshape(-1, n)
     w, idx = jax.lax.top_k(p_in, k)
+    if bias is not None:
+        w = jnp.take_along_axis(p, idx, axis=1)
     if spec.norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + np.float32(1e-20))
     return idx.astype(jnp.int32), w * np.float32(spec.routed_scaling_factor)
+
+
+def router_bias(spec: ModelSpec, params, i: int):
+    """Layer ``i``'s selection bias, or None where the router has none."""
+    return (_param(params, f"layer{i}_router_bias") if spec.router_bias
+            else None)
 
 
 def held_assignments(spec: ModelSpec, idx, live=None):
@@ -91,7 +106,8 @@ def routed_ffn(spec: ModelSpec, params, i: int, x, live=None):
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
     with jax.named_scope("router"):
-        idx, w = route(spec, xt, _param(params, f"layer{i}_router_weight"))
+        idx, w = route(spec, xt, _param(params, f"layer{i}_router_weight"),
+                       router_bias(spec, params, i))
         local, held = held_assignments(spec, idx, live)
     with jax.named_scope("experts"):
         count = spec.held[1]

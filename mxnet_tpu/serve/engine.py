@@ -50,7 +50,8 @@ from .. import chaos as chaos_mod
 from .. import compile_cache as cc
 from .. import telemetry
 from ..base import MXNetError
-from ..models.decoder import LATENT, ROUTED, ModelSpec, decoder_forward
+from ..models.decoder import (ROUTED, SLIDING, ModelSpec,
+                              decoder_forward)
 from ..models.retention import chunk_form
 from ..models.transformer import (lm_config_from_params,
                                   transformer_lm_decode,
@@ -107,6 +108,16 @@ class EngineConfig:
     and the decode kernel copies a block at a time.  It ingests prompts
     through the chunk program (``prefill_chunk > 0``) and refuses
     ``prefix_cache``, ``speculate`` and ``kv_quant`` by name.
+
+    A described model of softmax layers (any ``model`` but the in-tree
+    LM's; ``kvcache`` kinds ``paged_kv`` and ``paged_window``) keeps its
+    global layers' K/V in ``num_blocks`` blocks of ``block_size`` rows
+    under a table a request, and its window layers' in a ring of
+    ``kvcache.ring_width(sliding_window, prefill_chunk, block_size)``
+    blocks a request, from a window pool of ``max_batch`` rings + the
+    trash block (so a window block never runs short), both from ONE
+    ``kvcache.WindowAllocator``.  It too ingests prompts through the
+    chunk program and refuses the three options by name.
     """
     heads: int = 4
     model: Any = None             # ModelSpec | dict of its fields | None
@@ -401,6 +412,15 @@ def _spec_accept(logits, tokens, live, keys, temps, topks, lengths):
         lambda: _spec_accept_batch_greedy(logits, tokens, live))
 
 
+def _moe_counts(stats):
+    """``[experts_hit, assigned_here]`` summed over a program's routed
+    layers (zeros where the model has none)."""
+    if not stats:
+        return jnp.zeros((2,), jnp.int32)
+    return jnp.stack([sum(h for h, _ in stats),
+                      sum(n for _, n in stats)]).astype(jnp.int32)
+
+
 class Engine:
     """Continuous-batching autoregressive server for ``transformer_lm``
     parameter dicts.  See the module docstring for the step anatomy."""
@@ -434,6 +454,10 @@ class Engine:
             self.model.layer_kinds(self.num_layers))
         self.recurrent = self.cache.recurrent
         self.latent = self.cache.kind == kvcache.PAGED_LATENT
+        # a described softmax model: grouped heads, window and global
+        # layers through the paged pools (the in-tree LM keeps its twins)
+        self.described_kv = (not self.recurrent and not self.latent
+                             and self.model != ModelSpec(heads=self.heads))
         self._routed_layers = self.model.ffn_kinds(self.num_layers).count(
             ROUTED)
         if self._routed_layers and "experts_held" in self._params:
@@ -459,6 +483,13 @@ class Engine:
                 "not been tried on them, no verify program reads them "
                 "and an 8-bit latent row is another result (ROADMAP R4)"),
         }.get(self.cache.kind)
+        if self.described_kv:
+            refused = (
+                "is not served on a described softmax model's tables "
+                "(kinds paged_kv and paged_window) yet: a window block "
+                "holds other positions as a request grows, so the prefix "
+                "index cannot publish it, no verify program reads the "
+                "ring, and an 8-bit row is another result")
         if refused is not None:
             for name, on in (("prefix_cache", config.prefix_cache),
                              ("speculate", config.speculate),
@@ -466,11 +497,12 @@ class Engine:
                 if on:
                     raise ServeError("unsupported", -1,
                                      f"EngineConfig.{name} {refused}")
-        if self.latent:
+        if self.latent or self.described_kv:
             if not config.prefill_chunk:
                 raise MXNetError(
                     "a described model on a paged cache ingests prompts "
                     "through the chunk program: set prefill_chunk > 0")
+        if self.latent:
             self.head_dim = (self.model.qk_nope_head_dim
                              + self.model.qk_rope_head_dim)
         elif self.recurrent:
@@ -485,12 +517,6 @@ class Engine:
                     "the recurrent state is kept in float32 (every decode "
                     "step rounds it again, so a narrower state is another "
                     "result, not a faster one)")
-        elif self.model != ModelSpec(heads=self.heads):
-            raise MXNetError(
-                "the paged-K/V programs serve the in-tree transformer-lm "
-                f"description only; {self.model} has softmax layers they "
-                "cannot run yet (position offsets and grouped heads "
-                "through the paged pools: ROADMAP R1)")
         # a state slot holds any number of tokens: one "block" a request
         bs = config.max_seq_len if self.recurrent else config.block_size
         self.max_blocks = -(-config.max_seq_len // bs)
@@ -500,7 +526,26 @@ class Engine:
         if self.prefill_chunk < 0:
             raise MXNetError(f"prefill_chunk must be >= 0, "
                              f"got {self.prefill_chunk}")
-        self.alloc = kvcache.BlockAllocator(config.num_blocks, bs)
+        if self.described_kv:
+            kinds = self.model.layer_kinds(self.num_layers)
+            window = self.model.sliding_window if SLIDING in kinds else 0
+            ring = (kvcache.ring_width(window, self.prefill_chunk, bs)
+                    if window else 1)
+            self.alloc = kvcache.WindowAllocator(
+                config.num_blocks, 1 + config.max_batch * ring, bs, ring)
+            # layer -> (index of its K pool among the caches, its layer
+            # there): window layers in the first pair, global ones in
+            # the second
+            seen = {0: 0, 2: 0}
+            self._kv_slot = {}
+            for i, kind in enumerate(kinds):
+                at = 0 if kind == SLIDING else 2
+                self._kv_slot[i] = (at, seen[at])
+                seen[at] += 1
+        else:
+            self.alloc = kvcache.BlockAllocator(config.num_blocks, bs)
+            # a latent model's layer i is layer i of its one pool
+            self._kv_slot = {i: (0, i) for i in range(self.num_layers)}
         # -- round-18 cross-request prefix cache --
         self.prefix: Optional[kvcache.PrefixIndex] = None
         self._prefix_hits = 0
@@ -548,6 +593,15 @@ class Engine:
                 self.num_layers, config.num_blocks, bs, self.heads,
                 self.head_dim, dtype=config.dtype,
                 latent_width=self.model.latent_width)
+        elif self.described_kv:
+            # (K, V) of the window layers, then (K, V) of the global ones
+            self._caches = (
+                kvcache.make_pools(seen[0], self.alloc.window.num_blocks, bs,
+                                   self.kv_heads, self.head_dim,
+                                   dtype=config.dtype)
+                + kvcache.make_pools(seen[2], config.num_blocks, bs,
+                                     self.kv_heads, self.head_dim,
+                                     dtype=config.dtype))
         else:
             self._caches = kvcache.make_pools(
                 self.num_layers, config.num_blocks, bs, self.heads,
@@ -623,7 +677,7 @@ class Engine:
         else:
             telemetry.gauge("kv_bytes_per_token").set(
                 kvcache.kv_bytes_per_token(
-                    self.num_layers, self.heads, self.head_dim,
+                    self.num_layers, self.kv_heads, self.head_dim,
                     config.kv_quant, dtype=config.dtype,
                     latent_width=(self.model.latent_width if self.latent
                                   else None)))
@@ -777,7 +831,7 @@ class Engine:
         whole-prompt program's)."""
         if self.recurrent:
             return self._make_state_chunk_fn(cb)
-        if self.latent:
+        if self.latent or self.described_kv:
             return self._make_paged_chunk_fn(cb)
         heads, nl = self.heads, self.num_layers
         from ..models.transformer import transformer_lm_prefill_chunk
@@ -891,12 +945,10 @@ class Engine:
         return fn_decode
 
     # -- a DESCRIBED model on a paged cache --------------------------------
-    # The two makers below run any ``ModelSpec`` through
-    # ``decoder_forward`` over block tables; what a layer's ``attend``
-    # does is chosen by its kind.  The latent kind is the one they know
-    # today; a described softmax layer (position offsets and grouped
-    # heads through the K/V pools, ROADMAP R1) is one more branch of
-    # ``_paged_attend``, not a further family of programs.
+    # The makers below run a ``ModelSpec`` through ``decoder_forward``
+    # over block tables: latent layers over the one latent pool, softmax
+    # layers (grouped heads, window and global) over two pairs of K/V
+    # pools, the window layers' under a ring a request.
 
     def _routed(self, params, live, stats):
         """``decoder_forward``'s ``routed`` for this engine: the form
@@ -921,114 +973,156 @@ class Engine:
 
         return routed
 
-    def _paged_attend(self, params, pools, write, read):
-        """``decoder_forward``'s ``attend`` over the paged cache:
-        ``write(pool, layer, rows)`` scatters the new positions' rows,
-        ``read(layer, q, w_kvb)`` attends over the cache."""
-        def attend(i, kind, q, row, _v, _gate):
-            if kind != LATENT:
-                raise MXNetError(
-                    f"the paged programs of a described model serve latent "
-                    f"layers; layer {i} is {kind!r} (ROADMAP R1)")
-            with jax.named_scope("latent_write"):
-                pools[0] = write(pools[0], i,
-                                 kvcache.latent_rows(pools[0], row))
-            return read(i, q, params[f"layer{i}_kv_b_weight"])
+    def _paged_attend(self, pools, write, read):
+        """``decoder_forward``'s ``attend`` over the paged cache.  Layer
+        ``i`` lives at ``self._kv_slot[i] = (at, j)``: layer ``j`` of the
+        pools from ``pools[at]`` on.  ``write(pool, j, rows, sliding)``
+        scatters the new positions' rows (a latent layer's one row, a
+        softmax layer's K, then its V), then ``read(q, at, j, sliding)``
+        attends over the cache."""
+        def attend(i, kind, q, k, v, _gate):
+            at, j = self._kv_slot[i]
+            sliding = kind == SLIDING
+            if self.latent:
+                # every layer is latent: ``kvcache.CacheSpec`` refuses a mix
+                with jax.named_scope("latent_write"):
+                    pools[0] = write(pools[0], j,
+                                     kvcache.latent_rows(pools[0], k), False)
+            else:
+                pools[at] = write(pools[at], j, k, sliding)
+                pools[at + 1] = write(pools[at + 1], j, v, sliding)
+            return read(q, at, j, sliding)
 
         return attend
 
+    def _paged_sizes(self):
+        """What the paged makers' readers take from the description: the
+        score scale, the latent's sizes, the window and the ring."""
+        spec = self.model
+        if self.latent:
+            return dict(scale=spec.latent_scale(), rank=spec.kv_lora_rank,
+                        nope=spec.qk_nope_head_dim, window=0, ring=0)
+        return dict(scale=kvcache.softmax_scale(self.head_dim), rank=0,
+                    nope=0, window=spec.sliding_window, ring=self.alloc.ring)
+
     def _make_paged_chunk_fn(self, cb: int):
         """The chunk program of a described model on a paged cache: one
-        ``[1, cb]`` slice of a prompt at absolute offset ``start``; the
-        chunk's rows are written, then its positions attend causally
-        over the request's cached prefix in the up-projected form
-        (``kvcache.latent_prefill_attention``).  The head is computed
-        for the one position that is sampled."""
-        spec = self.model
-        rank, nope = spec.kv_lora_rank, spec.qk_nope_head_dim
-        scale = spec.latent_scale()
+        ``[1, cb]`` slice of a prompt at absolute offset ``start`` over
+        the cache's pools (one latent pool; or the window layers' K and
+        V, then the global layers'), the request's table row and, for
+        the window kind, its ring row.  Each layer writes the chunk's
+        rows (a window layer into its ring), then its positions attend
+        causally over the request's cached prefix: a latent layer in the
+        up-projected form (``kvcache.latent_prefill_attention``), a
+        softmax layer a key/value head's query group at a time, a window
+        layer's over the last ``sliding_window`` positions
+        (``kvcache.gqa_prefill_attention``); both walk the context in
+        XLA.  The head is computed for the one position that is
+        sampled."""
+        spec, n, z = self.model, len(self._caches), self._paged_sizes()
 
-        def fn_prefill_chunk(pool, params, tokens, start, length, table_row,
-                             key, temp, topk):
+        def fn_prefill_chunk(*args):
             self.trace_counts[f"prefill_chunk@{cb}"] += 1
-            pools = [pool]
+            pools = list(args[:n])
+            (params, tokens, start, length, table_row, *ring_row, key, temp,
+             topk) = args[n:]
             positions = start + jnp.arange(cb, dtype=jnp.int32)[None, :]
             last = jnp.clip(length - 1 - start, 0, cb - 1)
 
-            def write(p, i, rows):
-                return kvcache.write_prefill(p, i, rows[0], table_row,
-                                             length, start=start)
+            def write(p, j, rows, sliding):
+                return kvcache.write_prefill(
+                    p, j, rows[0], ring_row[0] if sliding else table_row,
+                    length, start=start, ring=z["ring"] if sliding else 0)
 
-            def read(i, q, w_kvb):
-                return kvcache.latent_prefill_attention(
-                    q[0], pools[0], i, table_row, start, length, w_kvb,
-                    rank=rank, nope=nope, scale=scale)[None]
+            def read(q, at, j, sliding):
+                if self.latent:
+                    return kvcache.latent_prefill_attention(
+                        q[0], pools[0], j, table_row, start, length,
+                        params[f"layer{j}_kv_b_weight"], rank=z["rank"],
+                        nope=z["nope"], scale=z["scale"])[None]
+                return kvcache.gqa_prefill_attention(
+                    q[0], pools[at], pools[at + 1], j,
+                    ring_row[0] if sliding else table_row, start, length,
+                    scale=z["scale"], window=z["window"] if sliding else 0,
+                    ring=z["ring"] if sliding else 0)[None]
 
             logits = decoder_forward(
                 spec, params, tokens, positions,
-                self._paged_attend(params, pools, write, read),
+                self._paged_attend(pools, write, read),
                 routed=self._routed(params, (positions < length)[0], []),
                 select=lambda h: jax.lax.dynamic_slice_in_dim(h, last, 1, 1))
             with jax.named_scope("sample"):
                 last_logits = logits[0, 0]
                 tok = _sample(last_logits, key, temp, topk, length)
                 ok = jnp.all(jnp.isfinite(last_logits.astype(jnp.float32)))
-            return pools[0], tok, ok
+            return (*pools, tok, ok)
 
         return fn_prefill_chunk
 
     def _make_paged_decode_fn(self, bb: int):
-        """The decode program of a described model on a paged cache: each
-        row's new latent row is written, then its one query attends over
-        the row's cached ones in the absorbed form (the Pallas kernel
-        ``mxtpu_mla_decode`` with ``attn_impl="flash"``).  Beside the
-        tokens it returns ``[experts_hit, assigned_here]`` summed over
-        the routed layers (zeros where the model has none), so that the
-        one fetch brings them."""
+        """The decode program of a described model on a paged cache, over
+        ``_make_paged_chunk_fn``'s pools and, for the window kind, the
+        rows' ring tables and the ring slots written.  Each row's new
+        rows are written (a window layer's into its ring), then its one
+        query attends over the row's cached positions: a latent layer's
+        in the absorbed form (the Pallas kernel ``mxtpu_mla_decode``
+        with ``attn_impl="flash"``), a softmax layer's a key/value
+        head's query group at a time over the row's live blocks, a
+        window layer's from the window's first block on
+        (``mxtpu_gqa_decode``).  Beside the tokens it returns
+        ``[experts_hit, assigned_here]`` summed over the routed layers
+        (zeros where the model has none), so that the one fetch brings
+        them."""
         spec, impl = self.model, self.attn_impl
-        rank, nope = spec.kv_lora_rank, spec.qk_nope_head_dim
-        scale = spec.latent_scale()
+        n, z = len(self._caches), self._paged_sizes()
 
-        def fn_decode(pool, params, tokens, tables, lengths, slots, offsets,
-                      active, keys, temps, topks):
+        def fn_decode(*args):
             self.trace_counts[f"decode@{bb}"] += 1
-            pools, stats = [pool], []
+            pools, stats = list(args[:n]), []
+            (params, tokens, tables, lengths, slots, offsets, active,
+             *ring_args, keys, temps, topks) = args[n:]
+            rings, ring_slots = ring_args or (None, None)
             # a row past ``active`` attends nothing: the kernel reads no
             # block for it
             attended = jnp.where(active, lengths + 1, 0)
 
-            def write(p, i, rows):
-                return kvcache.write_decode(p, i, rows, slots, offsets,
-                                            active)
+            def write(p, j, rows, sliding):
+                return kvcache.write_decode(
+                    p, j, rows, ring_slots if sliding else slots, offsets,
+                    active)
 
-            def read(i, q, w_kvb):
-                with jax.named_scope("attn"):
-                    qa = kvcache.latent_absorb(q, w_kvb, nope)
-                y = kvcache.latent_decode_attention(
-                    qa, pools[0], i, tables, attended, rank=rank,
-                    scale=scale, impl=impl)
-                with jax.named_scope("attn"):
-                    return kvcache.latent_expand(y, w_kvb, nope)
+            def read(q, at, j, sliding):
+                if self.latent:
+                    w_kvb = params[f"layer{j}_kv_b_weight"]
+                    with jax.named_scope("attn"):
+                        qa = kvcache.latent_absorb(q, w_kvb, z["nope"])
+                    y = kvcache.latent_decode_attention(
+                        qa, pools[0], j, tables, attended, rank=z["rank"],
+                        scale=z["scale"], impl=impl)
+                    with jax.named_scope("attn"):
+                        return kvcache.latent_expand(y, w_kvb, z["nope"])
+                return kvcache.gqa_decode_attention(
+                    q, pools[at], pools[at + 1], j,
+                    rings if sliding else tables, attended, scale=z["scale"],
+                    window=z["window"] if sliding else 0,
+                    ring=z["ring"] if sliding else 0, impl=impl)
 
             logits = decoder_forward(
                 spec, params, tokens, lengths,
-                self._paged_attend(params, pools, write, read),
+                self._paged_attend(pools, write, read),
                 routed=self._routed(params, active, stats))
             with jax.named_scope("sample"):
                 toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
                               axis=-1)
-            moe = (jnp.stack([sum(h for h, _ in stats),
-                              sum(n for _, n in stats)]).astype(jnp.int32)
-                   if stats else jnp.zeros((2,), jnp.int32))
-            return pools[0], toks, oks, moe
+            return (*pools, toks, oks, _moe_counts(stats))
 
         return fn_decode
 
     def _make_decode_fn(self, bb: int):
         if self.recurrent:
             return self._make_state_decode_fn(bb)
-        if self.latent:
+        if self.latent or self.described_kv:
             return self._make_paged_decode_fn(bb)
         heads, impl = self.heads, self.attn_impl
 
@@ -1147,10 +1241,10 @@ class Engine:
         out = self._programs[("draft", bb)](self.spec.params, padw, padl)
         return np.asarray(out)[:n]
 
-    def _pool_aval(self):
+    def _pool_aval(self, i: int = 0):
         sds = jax.ShapeDtypeStruct
         return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
-                                      self._caches[0])
+                                      self._caches[i])
 
     def _avals(self, kind: str, bucket: int):
         sds = jax.ShapeDtypeStruct
@@ -1169,19 +1263,26 @@ class Engine:
                         i32(b))
             raise MXNetError(f"a recurrent-state model has no {kind!r} "
                              "program")
-        if self.latent:
+        if self.latent or self.described_kv:
+            # the caches, then the paged makers' operands; the window kind
+            # adds its ring tables (and decode the ring slots written)
             i32 = lambda *s: sds(s, jnp.int32)
+            pools = tuple(self._pool_aval(i) for i in range(len(self._caches)))
+            ring = self.alloc.ring if self.described_kv else 0
             if kind == "prefill_chunk":
-                return (pool, params, i32(1, bucket), i32(), i32(),
-                        i32(self.max_blocks), key, sds((), jnp.float32),
-                        i32())
+                rings = (i32(ring),) if ring else ()
+                return pools + (params, i32(1, bucket), i32(), i32(),
+                                i32(self.max_blocks), *rings, key,
+                                sds((), jnp.float32), i32())
             if kind == "decode":
                 b = bucket
-                return (pool, params, i32(b), i32(b, self.max_blocks),
-                        i32(b), i32(b), i32(b), sds((b,), jnp.bool_),
-                        sds((b, 2), jnp.uint32), sds((b,), jnp.float32),
-                        i32(b))
-            raise MXNetError(f"a latent model has no {kind!r} program")
+                rings = (i32(b, ring), i32(b)) if ring else ()
+                return pools + (params, i32(b), i32(b, self.max_blocks),
+                                i32(b), i32(b), i32(b), sds((b,), jnp.bool_),
+                                *rings, sds((b, 2), jnp.uint32),
+                                sds((b,), jnp.float32), i32(b))
+            raise MXNetError(f"a described model on a paged cache has no "
+                             f"{kind!r} program")
         if kind == "prefill":
             return (pool, pool, params, sds((1, bucket), jnp.int32),
                     sds((), jnp.int32), sds((self.max_blocks,), jnp.int32),
@@ -1462,6 +1563,11 @@ class Engine:
         if self.recurrent:
             telemetry.gauge("serve.state.slots_used").set(
                 self.alloc.num_used)
+        if self.described_kv:
+            telemetry.gauge("serve.kv.window_blocks_used").set(
+                self.alloc.window.num_used)
+            telemetry.gauge("serve.kv.global_blocks_used").set(
+                self.alloc.global_used)
         if self.prefix is not None:
             telemetry.gauge("serve.prefix.cached_frac").set(
                 self.alloc.num_cached / (self.config.num_blocks - 1))
@@ -1522,8 +1628,13 @@ class Engine:
                      and (self.prefix is None
                           or not self.prefix.contains_block(b))]
             scrub += [kvcache.TRASH_BLOCK]
-            self._caches = tuple(kvcache.scrub_blocks(pool, scrub)
-                                 for pool in self._caches)
+            # a described softmax model's window pools (the first pair)
+            # are under the request's ring
+            ring = req.ring + [kvcache.TRASH_BLOCK]
+            self._caches = tuple(
+                kvcache.scrub_blocks(
+                    pool, ring if self.described_kv and i < 2 else scrub)
+                for i, pool in enumerate(self._caches))
         self._finish(req, "error", FAILED)
 
     # -- prefix cache (round 18) ------------------------------------------
@@ -1620,20 +1731,26 @@ class Engine:
         budget is ``num_available`` (free + evictable cached): parked
         prefix blocks are extra capacity, never admission pressure."""
         reserved = 0
+        reserved_ring = 0     # a described softmax model's window blocks
 
         def can_place(req: Request) -> bool:
-            nonlocal reserved
+            nonlocal reserved, reserved_ring
             toks = req.seed_tokens
             total = self.alloc.blocks_for_tokens(len(toks))
+            ring = (self.alloc.ring_blocks(len(toks)) if self.described_kv
+                    else 0)
             hits = self._probe(toks)
             for b in hits:
                 self.alloc.addref(b, req.id)
             need = total - len(hits)
-            if reserved + need > self.alloc.num_available:
+            if (reserved + need > self.alloc.num_available
+                    or (ring and reserved_ring + ring
+                        > self.alloc.window.num_available)):
                 if hits:       # roll the pins back — admission stops
                     self.alloc.release(hits, req.id)
                 return False
             reserved += need
+            reserved_ring += ring
             req.prefix_blocks = hits
             return True
 
@@ -1692,6 +1809,9 @@ class Engine:
         fresh = self.alloc.alloc(
             self.alloc.blocks_for_tokens(len(toks)) - len(hits), req.id)
         req.blocks = hits + fresh
+        if self.described_kv:
+            req.ring = self.alloc.window.alloc(
+                self.alloc.ring_blocks(len(toks)), req.id)
         req.prefilled = req.cached = len(hits) * self.alloc.block_size
         req.prefix_hit = req.prefilled
         req.published = len(hits)
@@ -1748,11 +1868,16 @@ class Engine:
                 else:
                     where = np.zeros((self.max_blocks,), np.int32)
                     where[:len(req.blocks)] = req.blocks
+                where = (where,)
+                if self.described_kv:
+                    ring = np.zeros((self.alloc.ring,), np.int32)
+                    ring[:len(req.ring)] = req.ring
+                    where += (ring,)
             with telemetry.span("serve.dispatch", kind="prefill_chunk",
                                 bucket=cb):
                 tok, ok = self._run(
                     "prefill_chunk", cb, padded, np.int32(start),
-                    np.int32(plen), where, req.key,
+                    np.int32(plen), *where, req.key,
                     np.float32(req.temperature), np.int32(req.top_k))
             with telemetry.span("serve.fetch"):
                 # one read of both, and the clock below stops after it:
@@ -1763,6 +1888,8 @@ class Engine:
                           else 0.8 * self._chunk_ms + 0.2 * ms)
         req.prefilled = min(start + cb, plen)
         req.cached = req.prefilled
+        if self.described_kv:
+            self._count_ring_reuse(start, req.cached)
         telemetry.counter("serve.prefill_chunks").inc()
         telemetry.histogram("serve.prefill_ms").observe(ms)
         if not ok:
@@ -1792,21 +1919,43 @@ class Engine:
     def _grow_blocks(self, req: Request, extra: int = 1) -> bool:
         """Ensure the request owns blocks through cache index
         ``cached + extra - 1`` (plain decode writes one entry; a
-        speculative step writes up to ``live + 1``).  On pool
-        exhaustion, preempts the youngest-admitted request
-        (recompute-style: blocks freed, request requeued; its sampling
-        replays identically).  Returns False if ``req`` itself was
-        preempted."""
-        while len(req.blocks) * self.alloc.block_size < req.cached + extra:
-            if self.alloc.can_alloc(1):
-                req.blocks += self.alloc.alloc(1, req.id)
+        speculative step writes up to ``live + 1``), and a described
+        softmax model's window ring as many as that needs (at most the
+        ring's width).  On pool exhaustion, preempts the
+        youngest-admitted request (recompute-style: blocks freed,
+        request requeued; its sampling replays identically).  Returns
+        False if ``req`` itself was preempted."""
+        need = req.cached + extra
+        while True:
+            if len(req.blocks) * self.alloc.block_size < need:
+                pool, table = self.alloc, req.blocks
+            elif (self.described_kv
+                  and len(req.ring) < self.alloc.ring_blocks(need)):
+                pool, table = self.alloc.window, req.ring
+            else:
+                return True
+            if pool.can_alloc(1):
+                table += pool.alloc(1, req.id)
                 continue
             victim = max(self.sched.running,
                          key=lambda r: (r.admit_t or 0.0, r.id))
             self._preempt(victim)
             if victim is req:
                 return False
-        return True
+
+    def _release_ring(self, req: Request) -> None:
+        if req.ring:
+            self.alloc.window.release(req.ring, req.id)
+            req.ring = []
+
+    def _count_ring_reuse(self, before: int, after: int) -> None:
+        """Window blocks reused in place while a request's cache grew
+        from ``before`` to ``after`` positions: blocks entered past the
+        ring's width (``serve.kv.window_blocks_reused``)."""
+        bs, ring = self.alloc.block_size, self.alloc.ring
+        n = -(-after // bs) - max(ring, -(-before // bs))
+        if n > 0:
+            telemetry.counter("serve.kv.window_blocks_reused").inc(n)
 
     def _preempt(self, victim: Request) -> None:
         telemetry.counter("serve.preemptions").inc()
@@ -1816,6 +1965,7 @@ class Engine:
         # and gets most of its context back at cached-TTFT cost
         self.alloc.release(victim.blocks, victim.id)
         victim.blocks = []
+        self._release_ring(victim)
         victim.cached = 0
         victim.prefilled = 0
         victim.prefill_target = 0
@@ -1883,6 +2033,20 @@ class Engine:
                         live_blocks=int(np.sum(
                             (lengths // bsz + 1)[:len(active)])),
                         table_blocks=bb * self.max_blocks)
+                if self.described_kv:
+                    ring = self.alloc.ring
+                    rings = np.zeros((bb, ring), np.int32)
+                    ring_slots = np.zeros((bb,), np.int32)
+                    for i, req in enumerate(active):
+                        rings[i, :len(req.ring)] = req.ring
+                        ring_slots[i] = req.ring[(req.cached // bsz) % ring]
+                    where += (rings, ring_slots)
+                    # cached positions a layer of each kind walks
+                    seen = lengths[:len(active)] + 1
+                    decode_span.annotate(
+                        window_rows=int(np.sum(np.minimum(
+                            seen, self.model.sliding_window or seen))),
+                        global_rows=int(np.sum(seen)))
             t0 = time.monotonic()
             with telemetry.span("serve.dispatch", kind="decode", bucket=bb):
                 toks, oks, *more = self._run("decode", bb, tokens, *where,
@@ -1901,6 +2065,9 @@ class Engine:
             step_ms = (time.monotonic() - t0) * 1e3
             hist = telemetry.histogram("serve.token_ms")
             with telemetry.span("serve.emit"):
+                if self.described_kv:
+                    for req in active:
+                        self._count_ring_reuse(req.cached, req.cached + 1)
                 for i, req in enumerate(active):
                     req.cached += 1
                     if not bool(oks[i]):
@@ -2057,6 +2224,7 @@ class Engine:
             # cache for the next request with this prefix
             self.alloc.release(req.blocks, req.id)
             req.blocks = []
+        self._release_ring(req)
         if req.prefix_blocks:
             # admission pinned a prefix but the request died before
             # _prefill_begin consumed it (deadline/cancel sweep)
@@ -2072,9 +2240,13 @@ class Engine:
         number of relocated blocks; outputs are bitwise unaffected."""
         mapping = self.alloc.defrag()
         if mapping:
-            # blocks and state slots alike sit on the pools' axis 1
-            self._caches = tuple(kvcache.compact_pool(pool, mapping)
-                                 for pool in self._caches)
+            # blocks and state slots alike sit on the pools' axis 1; a
+            # described softmax model's window pools (the first pair,
+            # under rings) are not compacted
+            self._caches = tuple(
+                pool if self.described_kv and i < 2
+                else kvcache.compact_pool(pool, mapping)
+                for i, pool in enumerate(self._caches))
             for req in self.sched.running:
                 req.blocks = [mapping.get(b, b) for b in req.blocks]
             if self.prefix is not None:
@@ -2083,8 +2255,12 @@ class Engine:
 
     def check_tables(self) -> None:
         """Allocator/table integrity audit (raises on any violation)."""
-        self.alloc.check({r.id: r.blocks for r in self.sched.running
-                          if r.blocks})
+        tables = {r.id: r.blocks for r in self.sched.running if r.blocks}
+        if self.described_kv:
+            self.alloc.check(tables, {r.id: r.ring for r in self.sched.running
+                                      if r.ring})
+        else:
+            self.alloc.check(tables)
 
     def stats(self) -> Dict[str, Any]:
         return {

@@ -22,7 +22,9 @@ becomes **one fused Pallas program** that
   (``pltpu.make_async_copy``) while the ones before them are folded, so
   a live block crosses HBM -> VMEM once (the row's last up to ``_FOLD``
   times, to fill the last iteration with something finite) and a dead
-  column, or a pool block no live prefix lists, is never read.  A
+  column, or a pool block no live prefix lists, is never read (the
+  walk is :func:`walk_live_blocks`, which ``mla_decode.py`` and
+  ``gqa_decode.py`` take too: only what a kernel folds differs).  A
   ``[block_size, heads * head_dim]`` block fills every one of the 128
   lanes (at 32 heads x 64 it is one bf16 sublane tile by 16 lane rows);
 * runs **split-K across block partitions**: the grid is ``(batch,
@@ -137,6 +139,56 @@ def _split_bf16(x):
     return jnp.concatenate([x, r1, r2], axis=0).astype(bf16)
 
 
+def walk_live_blocks(block, first, live, trips, fold, srcs, bufs, sems,
+                     start, body):
+    """PR 32's walk over a row's LIVE blocks, the one every paged decode
+    kernel takes (``mxtpu_flash_decode``, ``mxtpu_mla_decode``,
+    ``mxtpu_gqa_decode``).  ``trips`` iterations; iteration ``i`` takes
+    logical blocks ``j = first + i * fold`` on, ``fold`` of them, and past
+    ``live - 1`` that block again (the body masks by position: what
+    multiplies a zero probability has to be finite).  Pool block
+    ``block(logical)`` of each source in ``srcs`` (a layer of a pool, left
+    in HBM) is copied into the same place of its buffer in ``bufs``
+    (VMEM, ``[2, fold, ...]``), all on the slot's semaphore in ``sems``:
+    double-buffered, iteration ``i + 1``'s copies start before ``i``'s
+    are waited for.  ``start()`` runs once the first copies are on their
+    way and returns the loop's first carry; ``body(j, slot, carry)``
+    folds an iteration's blocks, waited for in slot ``slot``.  Returns
+    the last carry."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def copies(i, slot):
+        """The copies that bring iteration ``i``'s blocks into ``slot``
+        (built anew to be waited for)."""
+        # (a traced index costs a ref's every use some tracing: once each)
+        dsts, sem = [buf.at[slot] for buf in bufs], sems.at[slot]
+        out = []
+        for g in range(fold):
+            blk = block(jnp.minimum(first + i * fold + g, live - 1))
+            out += [pltpu.make_async_copy(src.at[blk], dst.at[g], sem)
+                    for src, dst in zip(srcs, dsts)]
+        return out
+
+    def fetch(i, slot):
+        for copy in copies(i, slot):
+            copy.start()
+
+    def fold_in(i, carry):
+        j, slot = first + i * fold, i % 2
+
+        @pl.when(i + 1 < trips)
+        def _next():
+            fetch(i + 1, 1 - slot)
+
+        for copy in copies(i, slot):
+            copy.wait()
+        return body(j, slot, carry)
+
+    fetch(0, 0)
+    return jax.lax.fori_loop(0, trips, fold_in, start())
+
+
 def _decode_kernel(*refs, bps: int, nblk: int, fold: int, block_size: int,
                    heads: int, head_dim: int, quantized: bool,
                    scale: np.float32):
@@ -157,7 +209,6 @@ def _decode_kernel(*refs, bps: int, nblk: int, fold: int, block_size: int,
     kscale_ref, vscale_ref = scale_refs if quantized else (None, None)
 
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
     width = heads * head_dim
@@ -208,37 +259,10 @@ def _decode_kernel(*refs, bps: int, nblk: int, fold: int, block_size: int,
 
     k_layer, v_layer = k_hbm.at[layer_ref[0]], v_hbm.at[layer_ref[0]]
 
-    def copies(i, slot):
-        """The copies that bring iteration ``i``'s blocks into ``slot``
-        (all on the slot's semaphore; built anew to be waited for).  Past
-        the row's last block it is that block again, masked by position:
-        what multiplies a zero probability has to be finite."""
-        # (a traced index costs a ref's every use some tracing: once each)
-        kdst, vdst, sem = kbuf.at[slot], vbuf.at[slot], sems.at[slot]
-        out = []
-        for g in range(fold):
-            blk = tables_ref[b, jnp.minimum(first + i * fold + g, live - 1)]
-            out += [pltpu.make_async_copy(k_layer.at[blk], kdst.at[g], sem),
-                    pltpu.make_async_copy(v_layer.at[blk], vdst.at[g], sem)]
-        return out
-
-    def fetch(i, slot):
-        for copy in copies(i, slot):
-            copy.start()
-
-    def fold_in(i, carry):
-        """Blocks ``first + i * fold ..``, waited for in their buffer,
-        into (m, l) and the accumulator; the ones after them are already
-        on their way."""
+    def fold_in(j, slot, carry):
+        """Blocks ``j ..``, waited for in their buffer, into (m, l) and
+        the accumulator; the ones after them are already on their way."""
         m_prev, l_prev = carry                                   # [H, 1]
-        j, slot = first + i * fold, i % 2
-
-        @pl.when(i + 1 < trips)
-        def _next():
-            fetch(i + 1, 1 - slot)
-
-        for copy in copies(i, slot):
-            copy.wait()
         kblocks, vblocks = kbuf.at[slot], vbuf.at[slot]
 
         # scores [H, fold * BS], heads on sublanes and positions on
@@ -290,18 +314,21 @@ def _decode_kernel(*refs, bps: int, nblk: int, fold: int, block_size: int,
 
     @pl.when(count > 0)
     def _live():
-        fetch(0, 0)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        # the query as a block-diagonal [H, H*hd]: row h keeps head h's
-        # lanes, so q.k for every head is ONE contraction over the lanes
-        # of a chunk, on the MXU, with exact products
-        for c in range(nchunk):
-            qc = jnp.broadcast_to(q_ref[:, lanes(c)].astype(f32), (r, w))
-            qm_ref[rows(group(c)), lanes(c)] = jnp.where(
-                owned(c), qc, np.float32(0.0)).astype(cd)
-        m, l = jax.lax.fori_loop(
-            0, trips, fold_in, (jnp.full(m_ref.shape, NEG_INF, f32),
-                                jnp.zeros(l_ref.shape, f32)))
+        def start():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            # the query as a block-diagonal [H, H*hd]: row h keeps head
+            # h's lanes, so q.k for every head is ONE contraction over the
+            # lanes of a chunk, on the MXU, with exact products
+            for c in range(nchunk):
+                qc = jnp.broadcast_to(q_ref[:, lanes(c)].astype(f32), (r, w))
+                qm_ref[rows(group(c)), lanes(c)] = jnp.where(
+                    owned(c), qc, np.float32(0.0)).astype(cd)
+            return (jnp.full(m_ref.shape, NEG_INF, f32),
+                    jnp.zeros(l_ref.shape, f32))
+
+        m, l = walk_live_blocks(
+            lambda n: tables_ref[b, n], first, live, trips, fold,
+            (k_layer, v_layer), (kbuf, vbuf), sems, start, fold_in)
         m_ref[...] = m
         l_ref[...] = l
         for c in range(nchunk):     # each head's own lanes of its row

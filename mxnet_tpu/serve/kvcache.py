@@ -1,13 +1,15 @@
 """Paged/blocked KV-cache for autoregressive serving (docs/serving.md).
 
-**Three kinds of per-layer cache** (:class:`CacheSpec`), one
-:class:`BlockAllocator` and one block table a request for all of them:
-``paged_kv`` (softmax attention: K and V rows of ``heads * head_dim``
-lanes in two pools), ``paged_latent`` (latent attention: ONE pool whose
-row is the compressed ``[c | k_r]`` all heads share, under the same
-tables and the same ``write_*`` scatters) and ``recurrent_state`` (one
-fixed-size float32 state a request, a slot and no table).  A model's
-layers are all of one kind; the mix is refused by name.
+**Four kinds of per-layer cache** (:class:`CacheSpec`), one allocator
+for all of them: ``paged_kv`` (softmax attention: K and V rows of
+``kv_heads * head_dim`` lanes in two pools), ``paged_latent`` (latent
+attention: ONE pool whose row is the compressed ``[c | k_r]`` all heads
+share, under the same tables and the same ``write_*`` scatters),
+``recurrent_state`` (one fixed-size float32 state a request, a slot and
+no table) and ``paged_window`` (a sliding-window layer's K and V rows in
+a RING of blocks a request, :class:`WindowAllocator`; a model may mix it
+with ``paged_kv`` global layers, whose rows are in pools of their own
+under a table that grows).  Any other mix is refused by name.
 
 vLLM-style paging on top of the repo's blockwise-attention machinery:
 key/value states live in **preallocated device pools** of fixed-size
@@ -85,7 +87,9 @@ from ..parallel.flash_attention import NEG_INF
 from .. import quant as quantmod
 
 __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
-           "PAGED_KV", "RECURRENT_STATE", "PAGED_LATENT", "CacheSpec",
+           "PAGED_KV", "RECURRENT_STATE", "PAGED_LATENT", "PAGED_WINDOW",
+           "CacheSpec", "WindowAllocator", "ring_width",
+           "gqa_prefill_attention", "gqa_decode_attention",
            "make_state_pool", "latent_lanes", "latent_decode_attention",
            "latent_prefill_attention",
            "PrefixIndex", "make_pools", "is_quantized",
@@ -100,10 +104,11 @@ __all__ = ["TRASH_BLOCK", "KV_QUANT_FORMATS", "QuantPool", "BlockAllocator",
 #: device-side write unconditional (no retrace-prone masking branches).
 TRASH_BLOCK = 0
 
-#: the three kinds of per-layer cache (:class:`CacheSpec`)
+#: the four kinds of per-layer cache (:class:`CacheSpec`)
 PAGED_KV = "paged_kv"                 # K and V rows that grow with the sequence
 RECURRENT_STATE = "recurrent_state"   # one fixed-size state a request
 PAGED_LATENT = "paged_latent"         # one compressed row a position, paged
+PAGED_WINDOW = "paged_window"         # K and V rows of a window, in a ring
 
 #: supported quantized-pool storage formats ("fp8" = e4m3 payload + one
 #: f32 scale per cached position; see :class:`QuantPool`).
@@ -217,29 +222,39 @@ class CacheSpec(NamedTuple):
     blocks, and slot 0 is the trash slot of both.  A slot's contents
     are discarded before reuse: the first prefill chunk of a request
     (``start == 0``) reads zeros in place of whatever the slot held.
+
+    ``paged_window`` (a sliding-window layer): the layer's K and V rows
+    live in pools of their own, under a request's WINDOW table, a ring
+    of at most :func:`ring_width` blocks (:class:`WindowAllocator`);
+    the model's global (``paged_kv``) layers, if any, keep theirs in
+    pools of their own under the request's growing table.
     """
     kinds: Tuple[str, ...]
 
     @classmethod
     def for_attention(cls, attention_kinds: Sequence[str]) -> "CacheSpec":
-        from ..models.decoder import LATENT, POWER_RETENTION, SOFTMAX
-        table = {SOFTMAX: PAGED_KV, POWER_RETENTION: RECURRENT_STATE,
-                 LATENT: PAGED_LATENT}
+        from ..models.decoder import LATENT, POWER_RETENTION, SLIDING, SOFTMAX
+        table = {SOFTMAX: PAGED_KV, SLIDING: PAGED_WINDOW,
+                 POWER_RETENTION: RECURRENT_STATE, LATENT: PAGED_LATENT}
         return cls(tuple(table[k] for k in attention_kinds))
 
     @property
     def kind(self) -> str:
-        """The one kind all layers are of.  A mix is refused: paged_kv
-        with paged_latent needs two pools of different rows under one
-        table (window / global hybrids: ROADMAP R4), and either with
-        recurrent_state needs blocks AND a slot a request; no model here
-        asks for one yet."""
+        """The kind of the model's cache: the one kind all layers are of,
+        or ``paged_window`` for window layers beside global ones (two
+        tables a request, one allocator).  Any other mix is refused:
+        paged_kv with paged_latent needs two pools of different rows
+        under one table, and either with recurrent_state needs blocks
+        AND a slot a request; no model here asks for one yet."""
         kinds = set(self.kinds)
+        if kinds == {PAGED_WINDOW, PAGED_KV}:
+            return PAGED_WINDOW
         if len(kinds) != 1:
             raise MXNetError(
                 f"a model mixing cache kinds {sorted(kinds)} is not served "
                 "yet: every layer must be paged_kv, every layer "
-                "paged_latent or every layer recurrent_state")
+                "paged_latent, every layer recurrent_state, or window "
+                "layers (paged_window) beside paged_kv ones")
         return self.kinds[0]
 
     @property
@@ -475,6 +490,61 @@ class BlockAllocator:
                 (mapping.get(b, b), None) for b in self._cached)
             self._free = list(range(1 + len(live), self.num_blocks))
         return mapping
+
+
+def ring_width(window: int, chunk: int, block_size: int) -> int:
+    """Blocks a request's window table holds: every position a chunk's
+    queries may see, from ``window - 1`` before its first query to its
+    last, on whatever block boundaries they fall: ``ceil((window +
+    chunk) / block_size) + 1``.  Position ``p`` lives in column ``(p //
+    block_size) % ring``, so the block a write reuses last held
+    positions that no query of the chunk (or of a decode step, ``chunk``
+    1) can see any more."""
+    return -(-(int(window) + int(chunk)) // int(block_size)) + 1
+
+
+class WindowAllocator(BlockAllocator):
+    """The one allocator of a model with window layers (kind
+    ``paged_window``): itself the allocator of the GLOBAL layers' pools,
+    whose table a request grows by a block every ``block_size``
+    positions, with ``window``, the allocator of the WINDOW layers'
+    pools, beside it.  A request's window table is a ring of
+    :func:`ring_width` blocks at most: once it holds ``ring`` of them it
+    takes no more, and the block of the position ``ring`` blocks back is
+    overwritten in place.  ``num_used`` and :meth:`check` count both
+    kinds, so admission, preemption and the engine's drain check see
+    both.  No prefix sharing: a window block holds different positions
+    over a request's life."""
+
+    def __init__(self, num_blocks: int, window_blocks: int, block_size: int,
+                 ring: int):
+        super().__init__(num_blocks, block_size)
+        self.window = BlockAllocator(window_blocks, block_size)
+        self.ring = int(ring)
+
+    @property
+    def global_used(self) -> int:
+        return len(self._refs)
+
+    @property
+    def num_used(self) -> int:
+        return self.global_used + self.window.num_used
+
+    def ring_blocks(self, ntokens: int) -> int:
+        """Window blocks a request of ``ntokens`` positions holds."""
+        return min(self.ring, self.blocks_for_tokens(ntokens))
+
+    def check(self, tables: Dict[object, Sequence[int]],
+              rings: Optional[Dict[object, Sequence[int]]] = None) -> None:
+        """Both audits of :meth:`BlockAllocator.check`, and no ring wider
+        than ``ring``."""
+        super().check(tables)
+        rings = rings or {}
+        for owner, ring in rings.items():
+            if len(ring) > self.ring:
+                raise MXNetError(f"{owner!r}: a window table of {len(ring)} "
+                                 f"blocks, the ring is {self.ring}")
+        self.window.check(rings)
 
 
 # ---------------------------------------------------------------------------
@@ -1007,6 +1077,140 @@ def latent_prefill_attention(q, pool, layer: int, table_row, start, length,
     return out.transpose(1, 0, 2).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Grouped-query softmax attention, bounded by a window (kinds paged_kv and
+# paged_window of a described model)
+# ---------------------------------------------------------------------------
+
+def _window_floor(length, window: int):
+    """The first position a query at ``length - 1`` sees."""
+    return jnp.maximum(length - window, 0) if window else jnp.zeros_like(
+        length)
+
+
+def gqa_prefill_attention(q, k_pool, v_pool, layer: int, table_row, start,
+                          length, *, scale, window: int = 0, ring: int = 0,
+                          ctx_block: int = 512):
+    """Causal grouped-query attention for one **prefill chunk**:
+    ``q`` [C, H, hd] at absolute positions ``start .. start+C-1``; the
+    pools store ``kv_heads * hd`` lanes a position (query head ``i``
+    reads key/value head ``i // (H / kv_heads)``); ``table_row`` a
+    global table [max_blocks], or with ``ring`` a window table (position
+    ``p`` in column ``(p // bs) % ring``); ``length`` the valid
+    positions, the chunk's own already written.  With ``window`` a
+    query at ``p`` sees positions ``p - window < j <= p``.  Returns [C,
+    H, hd].
+
+    The context is walked ``ctx_block`` positions at a time under an
+    online softmax, from the window's first block (or 0) as far as the
+    chunk's last query sees (a loop whose bounds are data), so the
+    scores alive at once are ``[H, C, ctx_block]`` float32."""
+    c, h, hd = q.shape
+    nblk, bs = table_row.shape[0], _block_size_of(k_pool)
+    kv = k_pool.shape[-1] // hd
+    g = h // kv
+    per = max(1, ctx_block // bs)                          # pool blocks a walk
+    kb = per * bs
+    if not ring:
+        # the walk reads whole walks of columns: pad with the trash block
+        table_row = jnp.pad(table_row, (0, -(-nblk // per) * per - nblk))
+    f32 = jnp.float32
+    qpos = start + jnp.arange(c)
+    seen = jnp.minimum(length, start + c)              # rows any query sees
+    lo = _window_floor(start + 1, window)                  # the first query's
+    # [kv, g, C, hd], the scale folded in
+    qg = (q.astype(f32) * np.float32(scale)).astype(q.dtype).reshape(
+        c, kv, g, hd).transpose(1, 2, 0, 3)
+
+    def walk(j, carry):
+        m, l, acc = carry                 # [kv, g, C] twice, [kv, g, C, hd]
+        cols = j * per + jnp.arange(per)
+        slots = jnp.take(table_row, cols % ring if ring else cols)
+        k = _gather_blocks(k_pool, layer, slots, (kb, kv, hd)).astype(q.dtype)
+        v = _gather_blocks(v_pool, layer, slots, (kb, kv, hd)).astype(q.dtype)
+        pos = j * kb + jnp.arange(kb)
+        valid = (pos[None, :] <= qpos[:, None]) & (pos[None, :] < length)
+        if window:
+            valid &= pos[None, :] > qpos[:, None] - window
+        with jax.named_scope("attn"):
+            s = jnp.einsum("kgcd,lkd->kgcl", qg, k, preferred_element_type=f32)
+            s = jnp.where(valid, s, NEG_INF)
+            # a query whose window opens in a later walk folds nothing
+            # in before it: its masked probabilities are zeros
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "kgcl,lkd->kgcd", p.astype(q.dtype), v,
+                preferred_element_type=f32)
+        return m_new, l, acc
+
+    init = (jnp.full((kv, g, c), NEG_INF, f32), jnp.zeros((kv, g, c), f32),
+            jnp.zeros((kv, g, c, hd), f32))
+    _, l, acc = jax.lax.fori_loop(lo // kb, -(-seen // kb), walk, init)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]           # [kv, g, C, hd]
+    return out.transpose(2, 0, 1, 3).reshape(c, h, hd).astype(q.dtype)
+
+
+def _ring_positions(nblk: int, lengths, ring: int, block_size: int):
+    """The absolute position of every slot of a row's window table
+    [B, ring * block_size]: column ``r`` holds the latest logical block
+    ``l <= (length - 1) // block_size`` with ``l % ring == r`` (a column
+    no write reached yet gets a negative, never-valid position)."""
+    last = jnp.maximum(lengths - 1, 0) // block_size              # [B]
+    r = jnp.arange(nblk)
+    logical = last[:, None] - (last[:, None] - r[None, :]) % ring
+    return (logical[..., None] * block_size
+            + jnp.arange(block_size)).reshape(lengths.shape[0], -1)
+
+
+def gqa_decode_attention(q, k_pool, v_pool, layer: int, tables, lengths, *,
+                         scale, window: int = 0, ring: int = 0,
+                         impl: str = "dense"):
+    """One-token-per-row grouped-query attention over a paged cache,
+    bounded by ``window`` (0: the whole prefix).  ``q`` [B, H, hd];
+    the WHOLE pools (``kv_heads * hd`` lanes a position) and the layer;
+    ``tables`` [B, columns], a window table with ``ring``; ``lengths``
+    [B] valid positions, the current one (already written) included, 0
+    for a row that attends nothing.  Returns [B, H, hd].
+
+    ``impl``: ``"flash"`` / ``"flash_interpret"`` the Pallas kernel
+    ``mxtpu_gqa_decode`` (``serve/gqa_decode.py``), whose walk over a
+    row's blocks starts at the window's first block; anything else
+    gathers the table's blocks and runs one masked softmax in XLA (the
+    CPU engines' reader and the kernel's reference)."""
+    b, h, hd = q.shape
+    scale = np.float32(scale)
+    if impl in ("flash", "flash_interpret"):
+        from .gqa_decode import gqa_decode
+        with jax.named_scope("attn"):
+            return gqa_decode(q, k_pool, v_pool, layer, tables, lengths,
+                              scale=scale, window=window, ring=ring,
+                              interpret=impl == "flash_interpret")
+    f32 = jnp.float32
+    nblk, bs = tables.shape[1], _block_size_of(k_pool)
+    kv = k_pool.shape[-1] // hd
+    k = _gather_blocks(k_pool, layer, tables, (b, nblk * bs, kv, hd))
+    v = _gather_blocks(v_pool, layer, tables, (b, nblk * bs, kv, hd))
+    if ring:
+        pos = _ring_positions(nblk, lengths, ring, bs)
+    else:
+        pos = jnp.broadcast_to(jnp.arange(nblk * bs), (b, nblk * bs))
+    valid = ((pos >= _window_floor(lengths, window)[:, None])
+             & (pos < lengths[:, None]))                          # [B, L]
+    with jax.named_scope("attn"):
+        qg = q.reshape(b, kv, h // kv, hd)
+        s = jnp.einsum("bkgd,blkd->bkgl", qg, k.astype(q.dtype),
+                       preferred_element_type=f32) * scale
+        s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(valid[:, None, None, :], jnp.exp(s - m[..., None]), 0.0)
+        l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
+        out = jnp.einsum("bkgl,blkd->bkgd", p, v.astype(f32))
+        return (out / l[..., None]).reshape(b, h, hd).astype(q.dtype)
+
+
 def dense_attention(q, k_buf, v_buf, lengths, *, block_size: int,
                     scale: Optional[float] = None):
     """The dense (non-paged) counterpart: same block scan, but K/V come
@@ -1030,7 +1234,8 @@ def dense_attention(q, k_buf, v_buf, lengths, *, block_size: int,
 
 
 @_scoped("kv_write")
-def write_prefill(pool, layer: int, states, table_row, length, start=0):
+def write_prefill(pool, layer: int, states, table_row, length, start=0,
+                  ring: int = 0):
     """Scatter a prompt's (or prompt chunk's) K or V states into its
     table's slots.
 
@@ -1040,7 +1245,9 @@ def write_prefill(pool, layer: int, states, table_row, length, start=0):
     [max_blocks] int32; ``length``: scalar total valid positions;
     ``start``: absolute position of ``states[0]`` (chunked prefill
     writes chunk *i* with ``start = i * chunk``).  Positions
-    ``>= length`` land in the trash block.  Returns the updated pool
+    ``>= length`` land in the trash block.  ``ring`` (a window table's
+    width, :func:`ring_width`): position ``p`` goes to column ``(p //
+    block_size) % ring``.  Returns the updated pool
     (functional; donate the input).  Quantized pools quantize each
     position row (fp8 payload + f32 scale) and scatter both with the
     same indices.
@@ -1049,9 +1256,12 @@ def write_prefill(pool, layer: int, states, table_row, length, start=0):
     bs = _block_size_of(pool)
     pos = start + jnp.arange(lpad)
     logical = pos // bs
-    # bucket L_pad may exceed table capacity * BS for short prompts;
-    # clamp the logical index — those positions are >= length anyway.
-    logical = jnp.minimum(logical, table_row.shape[0] - 1)
+    if ring:
+        logical = logical % ring
+    else:
+        # bucket L_pad may exceed table capacity * BS for short prompts;
+        # clamp the logical index — those positions are >= length anyway.
+        logical = jnp.minimum(logical, table_row.shape[0] - 1)
     slot = jnp.where(pos < length, jnp.take(table_row, logical),
                      TRASH_BLOCK)
     off = pos % bs

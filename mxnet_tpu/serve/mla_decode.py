@@ -21,11 +21,10 @@ The kernel, ``mxtpu_mla_decode``:
   a loop of its own (``cdiv(length, block_size)`` of them, never the
   table's width), ``fold`` blocks an iteration copied into one of two
   VMEM buffers (``pltpu.make_async_copy``) while the ones before them
-  are folded: PR 32's walk, as ``flash_decode.py`` has it.  The two
-  files keep their walks side by side: the fold differs in every line
+  are folded: PR 32's walk, ``flash_decode.walk_live_blocks``, which
+  all three decode kernels take; what is folded differs in every line
   (one operand for keys and values, all heads one MXU operand, no
-  block-diagonal query, no scales), and sharing the copies alone would
-  change ``mxtpu_flash_decode``'s lowered kernel (ROADMAP Design);
+  block-diagonal query, no scales);
 * both contractions on the MXU with M = heads: ``[H, lanes] x [N,
   lanes]^T`` for the scores and ``[H, N] x [N, rank]`` for the output,
   the values being the first ``rank`` lanes of the very rows the scores
@@ -50,6 +49,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..parallel.flash_attention import NEG_INF
+from .flash_decode import walk_live_blocks
 
 __all__ = ["mla_decode_attention", "default_split_k"]
 
@@ -76,7 +76,6 @@ def _mla_kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm,
     ``s*bps .. min((s+1)*bps, cdiv(length, BS))``, ``fold`` an
     iteration, into the split's online-softmax partial."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
     cd = q_ref.dtype
@@ -94,32 +93,8 @@ def _mla_kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm,
     trips = pl.cdiv(count, fold)
     layer_pool = pool_hbm.at[layer_ref[0]]
 
-    def copies(i, slot):
-        """The copies that bring iteration ``i``'s blocks into ``slot``.
-        Past the row's last block it is that block again, masked by
-        position: what meets a zero probability has to be finite."""
-        dst, sem = buf.at[slot], sems.at[slot]
-        out = []
-        for g in range(fold):
-            blk = tables_ref[b, jnp.minimum(first + i * fold + g, live - 1)]
-            out.append(pltpu.make_async_copy(layer_pool.at[blk], dst.at[g],
-                                             sem))
-        return out
-
-    def fetch(i, slot):
-        for copy in copies(i, slot):
-            copy.start()
-
-    def fold_in(i, carry):
+    def fold_in(j, slot, carry):
         m_prev, l_prev = carry                                   # [H, 1]
-        j, slot = first + i * fold, i % 2
-
-        @pl.when(i + 1 < trips)
-        def _next():
-            fetch(i + 1, 1 - slot)
-
-        for copy in copies(i, slot):
-            copy.wait()
         rows = buf[slot].reshape(fold * block_size, buf.shape[-1])  # [N, lanes]
         # every head's score over the iteration's positions: ONE
         # contraction over a row's lanes (zero lanes meet zero lanes)
@@ -139,11 +114,14 @@ def _mla_kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm,
 
     @pl.when(count > 0)
     def _live():
-        fetch(0, 0)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m, l = jax.lax.fori_loop(
-            0, trips, fold_in, (jnp.full(m_ref.shape, NEG_INF, f32),
-                                jnp.zeros(l_ref.shape, f32)))
+        def start():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            return (jnp.full(m_ref.shape, NEG_INF, f32),
+                    jnp.zeros(l_ref.shape, f32))
+
+        m, l = walk_live_blocks(
+            lambda n: tables_ref[b, n], first, live, trips, fold,
+            (layer_pool,), (buf,), sems, start, fold_in)
         m_ref[...] = m
         l_ref[...] = l
         out_ref[...] = acc_ref[...]

@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.decoder import ModelSpec, _param
-from ..models.experts import held_assignments, route, shared_ffn
+from ..models.experts import held_assignments, route, router_bias, shared_ffn
 
 __all__ = ["Plan", "plan", "grouped_matmul", "routed_ffn", "row_tile"]
 
@@ -180,7 +180,8 @@ def routed_ffn(spec: ModelSpec, params, i: int, x, live=None, *,
     wu = _param(params, f"layer{i}_experts_up_weight")
     wd = _param(params, f"layer{i}_experts_down_weight")
     with jax.named_scope("router"):
-        idx, w = route(spec, xt, _param(params, f"layer{i}_router_weight"))
+        idx, w = route(spec, xt, _param(params, f"layer{i}_router_weight"),
+                       router_bias(spec, params, i))
         local, held = held_assignments(spec, idx, live)
         tm = row_tile(local.size, wg.dtype)
         p = plan(local, held, count, tm)

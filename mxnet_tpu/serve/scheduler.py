@@ -70,6 +70,7 @@ class Request:
     state: str = QUEUED
     tokens: List[int] = field(default_factory=list)   # generated ids
     blocks: List[int] = field(default_factory=list)   # physical kv slots
+    ring: List[int] = field(default_factory=list)     # window-layer ring
     cached: int = 0                   # kv entries currently stored
     # chunked-prefill progress (engine-managed): seed tokens ingested so
     # far vs the total to ingest.  Whole-prompt prefill sets both at

@@ -260,11 +260,36 @@ def test_options_that_need_paged_kv_refuse_this_model(params, option):
         _engine(params, **option)
 
 
-def test_a_described_softmax_model_is_refused_not_misrun(params):
-    """The paged programs still run the in-tree block: a description they
-    cannot honour is an error at construction, never the wrong model."""
-    with pytest.raises(MXNetError, match="in-tree transformer-lm"):
-        _engine(params, model=dict(MODEL, attention="softmax"))
+def test_a_described_softmax_model_is_served_not_misrun(params):
+    """The same block with softmax attention in place of retention
+    (grouped heads, RoPE, q/k norms) runs through the paged programs of a
+    described softmax model (ISSUE 37 lifted the refusal), never the
+    in-tree block: its greedy tokens are the maxima of
+    ``decoder_forward`` over the whole sequence with plain causal
+    attention, teacher-forced on them."""
+    from mxnet_tpu.models import decoder
+    model = dict(MODEL, attention="softmax")
+    eng = _engine(params, model=model, num_blocks=40, block_size=4)
+    assert eng.described_kv and not eng.recurrent
+    prompt = np.random.RandomState(5).randint(1, V, 13).tolist()
+    out = eng.result(eng.submit(prompt, max_new_tokens=6))
+    toks = jnp.asarray([prompt + out[:-1]])
+    n = toks.shape[1]
+    causal = jnp.arange(n)[None, :] <= jnp.arange(n)[:, None]
+
+    def attend(_i, _kind, q, k, v, _g):
+        q = q.reshape(1, n, KV, H // KV, HD)
+        s = jnp.einsum("blkgd,bmkd->bkglm", q, k) / np.sqrt(HD)
+        a = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bkglm,bmkd->blkgd", a, v).reshape(1, n, H, HD)
+
+    with jax.default_matmul_precision("highest"):
+        logits = decoder.decoder_forward(
+            ModelSpec.resolve(model, H), params, toks,
+            jnp.arange(n)[None, :], attend)
+    rows = np.asarray(logits[0, len(prompt) - 1:])
+    assert rows.std() > 1.0
+    assert (rows.max(-1) - rows[np.arange(6), out]).max() < 1e-4
 
 
 def test_a_narrower_state_is_refused(params):

@@ -172,6 +172,58 @@ def test_latent_program_makes_no_float64(kind, impl):
     _assert_no_float64(closed, f"{kind}@{bucket} (latent pool, {impl})")
 
 
+# window and global softmax layers with grouped heads and sigmoid-routed
+# experts (ISSUE 37): rotary positions on the window layer only, q/k
+# norms, the output gate, sandwich norms, a scaled embedding, a dense
+# layer then a routed one with a selection bias; its two programs, with
+# the XLA readers and with both kernels interpreted
+_WINDOW = dict(kv_heads=2, head_dim=8, norm="rmsnorm", norm_eps=1e-5,
+               qk_norm=True, bias=False, ffn="silu_gated",
+               position="rope_sliding", rope_theta=10000.0,
+               attention=["sliding", "softmax"], sliding_window=12,
+               attn_gate=True, sandwich_norm=True, embed_scale=5.657,
+               ffn_layers=["dense", "routed"], n_routed_experts=8,
+               experts_per_token=2, routed_scaling_factor=2.448,
+               norm_topk_prob=True, experts_held=[2, 4],
+               score_func="sigmoid", router_bias=True)
+
+
+def _window_params(seed=0):
+    shapes = _retention_params(seed)
+    for i in range(NL):
+        p = f"layer{i}_"
+        for gone in ("gate_weight", "gate_bias"):
+            del shapes[p + gone]
+        shapes[p + "attn_gate_weight"] = shapes[p + "q_weight"]
+        shapes[p + "post_attn_norm_gamma"] = shapes[p + "ln1_gamma"]
+        shapes[p + "post_ffn_norm_gamma"] = shapes[p + "ln2_gamma"]
+    latent = _latent_params(seed)
+    for k in list(shapes):
+        if k.startswith("layer1_ffn_"):
+            del shapes[k]
+    shapes.update({k: v for k, v in latent.items()
+                   if k.startswith(("layer1_shared", "layer1_experts",
+                                    "layer1_router"))})
+    shapes["layer1_router_bias"] = np.zeros((8,), np.float32)
+    return shapes
+
+
+@pytest.mark.parametrize("kind,impl", [("prefill_chunk", "dense"),
+                                       ("prefill_chunk", "flash_interpret"),
+                                       ("decode", "dense"),
+                                       ("decode", "flash_interpret")])
+def test_window_program_makes_no_float64(kind, impl):
+    eng = Engine(_window_params(), EngineConfig(
+        heads=H, model=_WINDOW, block_size=4, num_blocks=24, max_batch=4,
+        max_prompt_len=16, max_seq_len=48, prefill_chunk=8, attn_impl=impl))
+    assert eng.described_kv
+    bucket = 8 if kind == "prefill_chunk" else 4
+    fn = getattr(eng, _MAKERS[kind])(bucket)
+    closed = jax.make_jaxpr(fn)(*eng._avals(kind, bucket))
+    assert len(closed.jaxpr.eqns) > 20, "nothing was traced"
+    _assert_no_float64(closed, f"{kind}@{bucket} (window + global, {impl})")
+
+
 @pytest.mark.parametrize("kind,pool,impl", _CASES,
                          ids=["-".join(c) for c in _CASES])
 def test_serving_program_makes_no_float64(kind, pool, impl):

@@ -18,6 +18,10 @@ The same for a LATENT pool (ISSUE 35): ``decode``@64 and
 (5 layers x 5120, 128 heads, a row of 576 values on 640 lanes, 2,560
 blocks of 128, 40 of 160 experts held), and both of its kernels
 (``mxtpu_mla_decode``, ``mxtpu_moe_experts``) compiled by Mosaic.
+
+And for WINDOW and global K/V tables (ISSUE 37): both programs of
+``benchmark/configs/trinity-large-ep8-5of60.json`` (four pools: window
+and global, K and V) and the grouped decode kernel ``mxtpu_gqa_decode``.
 """
 import importlib.util
 import json
@@ -333,3 +337,128 @@ def test_latent_kernels_compile_for_a_v5e(topo, dtype):
             sds((tokens, 6), jnp.bool_), sds((held, d, f), dt),
             sds((held, d, f), dt), sds((held, f, d), dt))
         assert comp.as_text().count("mxtpu_moe_experts") >= 2
+
+
+# ---------------------------------------------------------------------------
+# window and global K/V tables under one allocator (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+WINDOW_CONFIG = os.path.join(REPO, "benchmark", "configs",
+                             "trinity-large-ep8-5of60.json")
+
+
+def _window_shape_engine():
+    """The engine of the window configuration, from SHAPES: the
+    parameters' shapes are the benchmark reference's own (loaded by path;
+    the selection bias float32), the pools' ``kvcache.make_pools``'s."""
+    import mxnet_tpu.serve.engine as eng_mod
+    from mxnet_tpu.serve import Engine, EngineConfig
+
+    with open(WINDOW_CONFIG) as f:
+        cfg = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "trinity_reference",
+        os.path.join(REPO, "benchmark", "reference", "trinity.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    sds = jax.ShapeDtypeStruct
+    real_asarray, real_pools = jnp.asarray, eng_mod.kvcache.make_pools
+    try:
+        eng_mod.jnp.asarray = lambda v, *a, **k: (
+            v if isinstance(v, sds) else real_asarray(v, *a, **k))
+        eng_mod.kvcache.make_pools = lambda *a, **k: jax.eval_shape(
+            lambda: real_pools(*a, **k))
+        engine = dict(cfg["serve"]["engine"], attn_impl="flash")
+        return Engine(
+            {k: sds(s, jnp.float32 if k.endswith("router_bias")
+                    else jnp.bfloat16)
+             for k, s in ref.param_shapes(cfg).items()},
+            EngineConfig(heads=int(cfg["num_attention_heads"]),
+                         dtype=jnp.bfloat16, **engine))
+    finally:
+        eng_mod.jnp.asarray = real_asarray
+        eng_mod.kvcache.make_pools = real_pools
+
+
+@pytest.mark.parametrize("kind,bucket", [("decode", 32),
+                                         ("prefill_chunk", 1024)])
+def test_window_program_keeps_its_pools_in_place(topo, kind, bucket):
+    """Both programs of ``trinity-large-ep8-5of60`` (4 window layers in a
+    ring of 41 blocks a row, 1 global layer in 3,000 blocks; 48 query
+    heads over 8 of 128; 32 of 256 experts) compile for a v5e, fit it,
+    alias all four pools, move none of them, and call the grouped decode
+    kernel once a layer in the decode program."""
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.serve import kvcache
+
+    eng = _window_shape_engine()
+    assert eng.described_kv and eng.alloc.ring == 41
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    make = {"decode": eng._make_decode_fn,
+            "prefill_chunk": eng._make_chunk_prefill_fn}[kind]
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._avals(kind, bucket))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        comp = jax.jit(make(bucket), donate_argnums=(0, 1, 2, 3)).trace(
+            *avals).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    pools = [eng._pool_aval(i) for i in range(4)]
+    assert [p.shape for p in pools] == [(4, 1313, 128, 1024)] * 2 + [
+        (1, 3001, 128, 1024)] * 2
+    m = comp.memory_analysis()
+    print(kind, bucket, "window GB: arguments %.2f aliased %.2f temporaries "
+          "%.3f" % (m.argument_size_in_bytes / 1e9, m.alias_size_in_bytes / 1e9,
+                    m.temp_size_in_bytes / 1e9))
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes) < CHIP_BYTES
+    assert m.alias_size_in_bytes >= kvcache.pool_nbytes(*pools)
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    sizes = {_counts(p.shape) for p in pools} | {
+        _counts(p.shape[1:]) for p in pools}
+    moved = []
+    for line in comp.as_text().splitlines():
+        hit = re.search(r"= \(?(\w+)\[([\d,]+)\]\S* (copy|slice|"
+                        r"dynamic-slice|transpose)\(", line)
+        if hit and _counts(int(d) for d in hit.group(2).split(",")) in sizes:
+            moved.append(line.strip()[:200])
+    assert not moved, moved[:3]
+    calls = [ln for ln in comp.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum("mxtpu_moe_experts" in ln for ln in calls) == 2 * 4
+    assert sum("mxtpu_gqa_decode" in ln for ln in calls) == (
+        5 if kind == "decode" else 0)
+
+
+@pytest.mark.parametrize("window,ring,columns", [(4096, 41, 41),
+                                                 (0, 0, 120)])
+def test_grouped_decode_kernel_compiles_for_a_v5e(topo, window, ring,
+                                                  columns):
+    """Mosaic COMPILES ``mxtpu_gqa_decode`` at the published widths (48
+    query heads over 8 of 128, blocks of [128, 1024] bf16) for a window
+    layer's ring and a global layer's table, with no pool-sized
+    temporary."""
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.serve.gqa_decode import gqa_decode
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        comp = jax.jit(lambda q, k, v, t, n: gqa_decode(
+            q, k, v, 1, t, n, scale=128 ** -0.5, window=window, ring=ring)
+        ).trace(sds((32, 48, 128), jnp.bfloat16),
+                sds((4, 1313, 128, 1024), jnp.bfloat16),
+                sds((4, 1313, 128, 1024), jnp.bfloat16),
+                sds((32, columns), jnp.int32),
+                sds((32,), jnp.int32)).lower(
+                    lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "mxtpu_gqa_decode" in comp.as_text()
+    assert comp.memory_analysis().temp_size_in_bytes < 10e6
