@@ -9,9 +9,14 @@ The front door of the serving tier (docs/serving.md): ``submit`` /
    (:class:`~mxnet_tpu.serve.scheduler.Scheduler` policy: FIFO with an
    SLO-aware jump),
 3. **prefills** each admitted prompt through a bucket-laddered AOT
-   program (one program per padded prompt length), and
-4. runs ONE **decode** step for the whole running batch through a
-   slot-bucketed AOT program.
+   program (one program per padded prompt length), alone on the device
+   and read in place, and
+4. queues ONE **decode** step for the whole running batch through a
+   slot-bucketed AOT program, and only then reads and emits the tokens
+   of the decode step the previous call queued: a one-deep pipeline,
+   so the device has the next program waiting while the host emits,
+   admits and builds (docs/serving.md §The step contract).  A row's
+   input token stays on the device from one step to the next.
 
 Both program families compile through
 :mod:`~mxnet_tpu.compile_cache` (:func:`Engine.warmup` resolves every
@@ -60,8 +65,8 @@ from ..models.transformer import (lm_config_from_params,
 from . import kvcache
 from . import retention_decode as retention_mod
 from . import speculate as speculate_mod
-from .scheduler import (CANCELLED, FAILED, FINISHED, Request, Scheduler,
-                        ServeError)
+from .scheduler import (ACTIVE, CANCELLED, FAILED, FINISHED, Request,
+                        Scheduler, ServeError)
 
 __all__ = ["EngineConfig", "Engine", "ServeError"]
 
@@ -421,6 +426,34 @@ def _moe_counts(stats):
                       sum(n for _, n in stats)]).astype(jnp.int32)
 
 
+def _feed(prev, src, tokens):
+    """A decode step's input tokens, without the host reading the last
+    ones: row ``i`` takes ``prev[src[i]]`` (the previous decode step's
+    output, still on the device) where ``src[i] >= 0``, and the host's
+    ``tokens[i]`` where ``src[i] < 0``."""
+    return jnp.where(src >= 0, jnp.take(prev, jnp.maximum(src, 0)), tokens)
+
+
+def _carry(toks, width: int):
+    """A decode step's tokens padded to ``width`` (the largest decode
+    bucket): the next step's ``prev``, whatever bucket either runs."""
+    return jnp.pad(toks, (0, width - toks.shape[0]))
+
+
+class _Flight:
+    """A decode step on the device whose tokens the host has not read:
+    its rows (``index``: request id -> row, for the rows whose result is
+    still wanted), the program's outputs, when it was dispatched and its
+    ``serve.decode`` span (annotated with what the read finds)."""
+    __slots__ = ("step", "rows", "index", "toks", "oks", "more", "t0",
+                 "span")
+
+    def __init__(self, step, rows, toks, oks, more, t0, span):
+        self.step, self.rows, self.t0, self.span = step, rows, t0, span
+        self.index = {r.id: i for i, r in enumerate(rows)}
+        self.toks, self.oks, self.more = toks, oks, more
+
+
 class Engine:
     """Continuous-batching autoregressive server for ``transformer_lm``
     parameter dicts.  See the module docstring for the step anatomy."""
@@ -629,6 +662,12 @@ class Engine:
             # prompt's length
             self.prompt_buckets = tuple(policy._ladder(config.max_seq_len))
         self.decode_buckets = config.resolved_decode_buckets()
+        # the decode step on the device whose tokens are not yet read (at
+        # most one); a decode program's tokens come back padded to the
+        # largest bucket, the next one's ``prev``
+        self._flight: Optional[_Flight] = None
+        self._carry_width = self.decode_buckets[-1]
+        self._no_toks = jnp.zeros((self._carry_width,), jnp.int32)
         self._base_key = jax.random.PRNGKey(config.seed)
         self._programs: Dict[Tuple[str, int], cc.AotProgram] = {}
         self.trace_counts = collections.Counter()
@@ -763,6 +802,9 @@ class Engine:
                 f"removed={report.removed[:4]} "
                 f"changed={[c['name'] for c in report.changed[:4]]}); "
                 "rebuild the engine (Router.rolling_swap does)")
+        # the step in flight ran under the old weights: its tokens are
+        # read before the new ones are installed
+        self._drain("swap_weights")
         self._params = new
         # the NaN-poison cache was derived from the OLD weights; a
         # later serve_poison_logits must poison the CURRENT ones
@@ -916,6 +958,7 @@ class Engine:
         kernel aliases the pool; rows that are not active carry the
         trash slot)."""
         spec, eps, impl = self.model, self.model.retention_eps, self.attn_impl
+        width = self._carry_width
 
         def update(pool, i, slots, q, k, v, gate):
             if impl in ("flash", "flash_interpret"):
@@ -925,10 +968,11 @@ class Engine:
             return retention_mod.retention_decode_xla(
                 pool, i, slots, q, k, v, gate, eps)
 
-        def fn_decode(state, params, tokens, lengths, slots, keys, temps,
-                      topks):
+        def fn_decode(state, params, prev, src, tokens, lengths, slots, keys,
+                      temps, topks):
             self.trace_counts[f"decode@{bb}"] += 1
             pool = [state]
+            tokens = _feed(prev, src, tokens)
 
             def attend(i, _kind, q, k, v, gate):
                 with jax.named_scope("retention"):
@@ -940,7 +984,7 @@ class Engine:
                 toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
                               axis=-1)
-            return pool[0], toks, oks
+            return pool[0], _carry(toks, width), oks
 
         return fn_decode
 
@@ -1075,13 +1119,15 @@ class Engine:
         them."""
         spec, impl = self.model, self.attn_impl
         n, z = len(self._caches), self._paged_sizes()
+        width = self._carry_width
 
         def fn_decode(*args):
             self.trace_counts[f"decode@{bb}"] += 1
             pools, stats = list(args[:n]), []
-            (params, tokens, tables, lengths, slots, offsets, active,
-             *ring_args, keys, temps, topks) = args[n:]
+            (params, prev, src, tokens, tables, lengths, slots, offsets,
+             active, *ring_args, keys, temps, topks) = args[n:]
             rings, ring_slots = ring_args or (None, None)
+            tokens = _feed(prev, src, tokens)
             # a row past ``active`` attends nothing: the kernel reads no
             # block for it
             attended = jnp.where(active, lengths + 1, 0)
@@ -1115,7 +1161,7 @@ class Engine:
                 toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
                               axis=-1)
-            return (*pools, toks, oks, _moe_counts(stats))
+            return (*pools, _carry(toks, width), oks, _moe_counts(stats))
 
         return fn_decode
 
@@ -1124,12 +1170,13 @@ class Engine:
             return self._make_state_decode_fn(bb)
         if self.latent or self.described_kv:
             return self._make_paged_decode_fn(bb)
-        heads, impl = self.heads, self.attn_impl
+        heads, impl, width = self.heads, self.attn_impl, self._carry_width
 
-        def fn_decode(kpool, vpool, params, tokens, tables, lengths, slots,
-                      offsets, active, keys, temps, topks):
+        def fn_decode(kpool, vpool, params, prev, src, tokens, tables,
+                      lengths, slots, offsets, active, keys, temps, topks):
             self.trace_counts[f"decode@{bb}"] += 1
             pools = [kpool, vpool]
+            tokens = _feed(prev, src, tokens)
 
             def attend(i, q, k, v):
                 pools[0] = kvcache.write_decode(pools[0], i, k, slots,
@@ -1148,7 +1195,7 @@ class Engine:
                 toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
                               axis=-1)
-            return pools[0], pools[1], toks, oks
+            return pools[0], pools[1], _carry(toks, width), oks
 
         return fn_decode
 
@@ -1251,6 +1298,9 @@ class Engine:
         pool = self._pool_aval()
         params = {k: sds(v.shape, v.dtype) for k, v in self._params.items()}
         key = sds((2,), jnp.uint32)
+        # a decode step's token operands (``_feed``): prev, src, tokens
+        feed = (sds((self._carry_width,), jnp.int32),
+                sds((bucket,), jnp.int32), sds((bucket,), jnp.int32))
         if self.recurrent:
             i32 = lambda *s: sds(s, jnp.int32)
             if kind == "prefill_chunk":
@@ -1258,7 +1308,7 @@ class Engine:
                         key, sds((), jnp.float32), i32())
             if kind == "decode":
                 b = bucket
-                return (pool, params, i32(b), i32(b), i32(b),
+                return (pool, params, *feed, i32(b), i32(b),
                         sds((b, 2), jnp.uint32), sds((b,), jnp.float32),
                         i32(b))
             raise MXNetError(f"a recurrent-state model has no {kind!r} "
@@ -1277,7 +1327,7 @@ class Engine:
             if kind == "decode":
                 b = bucket
                 rings = (i32(b, ring), i32(b)) if ring else ()
-                return pools + (params, i32(b), i32(b, self.max_blocks),
+                return pools + (params, *feed, i32(b, self.max_blocks),
                                 i32(b), i32(b), i32(b), sds((b,), jnp.bool_),
                                 *rings, sds((b, 2), jnp.uint32),
                                 sds((b,), jnp.float32), i32(b))
@@ -1303,7 +1353,7 @@ class Engine:
                     i32(b, self.max_blocks), i32(b), i32(b),
                     sds((b,), jnp.bool_), sds((b, 2), jnp.uint32),
                     sds((b,), jnp.float32), i32(b))
-        return (pool, pool, params, i32(b), i32(b, self.max_blocks),
+        return (pool, pool, params, *feed, i32(b, self.max_blocks),
                 i32(b), i32(b), i32(b), sds((b,), jnp.bool_),
                 sds((b, 2), jnp.uint32), sds((b,), jnp.float32), i32(b))
 
@@ -1492,8 +1542,11 @@ class Engine:
     # -- the step loop -----------------------------------------------------
 
     def step(self) -> None:
-        """One continuous-batching iteration: evict, admit+prefill, one
-        batched decode step.  Any exception dumps the flight recorder
+        """One continuous-batching iteration: evict, admit, prefill (a
+        chunk runs alone, after the decode step in flight is read),
+        dispatch one batched decode step, then read and emit the decode
+        step the previous call dispatched (docs/serving.md §The step
+        contract).  Any exception dumps the flight recorder
         (``serve-error``) before propagating."""
         try:
             self._step_inner()
@@ -1513,6 +1566,12 @@ class Engine:
         self.step_idx += 1
         self._poison_step = False
         if self.chaos is not None:
+            if any(self.chaos.at(kind, self.step_idx) for kind in
+                   ("serve_crash", "serve_hang", "serve_poison_logits")):
+                # a chaos step starts from every token read, and a
+                # poisoned one runs alone: its NaN must be scrubbed
+                # before a later program reads the trash block
+                self._drain("chaos")
             self._chaos_fire()
             if self._hung:
                 return
@@ -1541,7 +1600,7 @@ class Engine:
                 for req in admitted:
                     self._prefill(req)
                 chunk = bool(admitted)
-            rows = self._decode_step() if self.sched.running else 0
+            rows = self._decode_step()
             self.publish_load_gauges()
             telemetry.flight_recorder().record({
                 "kind": "serve", "step": self.step_idx,
@@ -1763,6 +1822,7 @@ class Engine:
         req.blocks = self.alloc.alloc(nblocks, req.id)
         lb = cc.bucket_for(plen, self.prompt_buckets)
         self._ensure_program("prefill", lb)
+        self._drain("prefill")
         t0 = time.monotonic()
         with telemetry.span("serve.prefill", req=req.id, bucket=lb,
                             prompt=plen):
@@ -1852,6 +1912,12 @@ class Engine:
         plen = req.prefill_target
         toks = req.seed_tokens[start:start + cb]
         self._ensure_program("prefill_chunk", cb)
+        # the chunk runs alone and is read in its own span: the decode
+        # step in flight is read first (docs/serving.md "The step
+        # contract"), so the span is the chunk's build, dispatch, time on
+        # the device and fetch, and the prompt's first token is on the
+        # host when the next decode step is built
+        self._drain("prefill")
         t0 = time.monotonic()
         with telemetry.span("serve.prefill", req=req.id, bucket=cb,
                             prompt=plen, chunk_start=start,
@@ -1958,6 +2024,10 @@ class Engine:
             telemetry.counter("serve.kv.window_blocks_reused").inc(n)
 
     def _preempt(self, victim: Request) -> None:
+        # a decode step in flight that holds the victim computes a row
+        # nobody wants: its re-prefill samples that position again
+        if self._flight is not None:
+            self._flight.index.pop(victim.id, None)
         telemetry.counter("serve.preemptions").inc()
         # drop references, don't force-free: a shared prefix block must
         # survive for its co-owners, and this victim's own published
@@ -1974,31 +2044,91 @@ class Engine:
         victim.published = 0
         self.sched.requeue(victim)
 
+    # -- the decode pipeline ------------------------------------------------
+    # ``_decode_step`` queues this call's decode program behind the one in
+    # flight, then reads that one's tokens (``_retire``).  A row's input
+    # token is the previous step's output on the device (``_feed``); the
+    # host knows everything else about the next step without it.  Where
+    # it does not (a prefill chunk's first token, a preemption,
+    # speculation, chaos) or something outside the step touches the
+    # pools, ``_drain`` reads the step in flight first.
+
+    def _decode_roster(self, flight: Optional[_Flight]) -> List[Request]:
+        """The rows of the next decode step: every running request whose
+        prompt is in (mid-prefill requests hold blocks for their whole
+        prompt already and have no last token to feed), but one whose
+        token budget its unread token in ``flight`` already spends."""
+        unread = flight.index if flight is not None else {}
+        return [r for r in self.sched.running
+                if r.prefilled >= r.prefill_target
+                and len(r.tokens) + (r.id in unread) < r.max_new_tokens]
+
+    def _growth_fits(self, rows: Sequence[Request]) -> bool:
+        """Whether the growth pass can give every row of ``rows`` its
+        next position without preempting anyone."""
+        al = self.alloc
+        need = sum(max(0, al.blocks_for_tokens(r.cached + 1) - len(r.blocks))
+                   for r in rows)
+        if need > al.num_available:
+            return False
+        if self.described_kv:
+            ring = sum(max(0, al.ring_blocks(r.cached + 1) - len(r.ring))
+                       for r in rows)
+            return ring <= al.window.num_available
+        return True
+
     def _decode_step(self) -> int:
-        """One batched decode step; returns the decode-ready rows it
-        carried (0: every running request is still mid-prefill)."""
+        """Dispatch one batched decode step, then read and emit the one
+        the previous call dispatched; returns the rows dispatched (0:
+        every running request is mid-prefill or ends with the step in
+        flight)."""
         if self.spec is not None:
-            return self._verify_step()
-        # growth pass first: a preemption inside _grow_blocks mutates
-        # sched.running, so the batch roster is only read afterwards
-        # (a preempted victim must not decode on freed blocks).
-        # Mid-prefill requests (chunked prefill still ingesting) hold
-        # blocks for their whole prompt already and have no last token
-        # to feed — they stay off the decode roster until the pump
-        # finishes them.
-        for req in list(self.sched.running):
-            if req in self.sched.running and req.prefilled >= req.prefill_target:
+            # speculation drafts from tokens on the host
+            self._drain("speculate")
+            return self._verify_step() if self.sched.running else 0
+        sync = self._poison_step
+        rows = self._decode_roster(self._flight)
+        if rows and not self._growth_fits(rows):
+            # a preemption: which row loses its blocks, and what it
+            # re-prefills, needs every token read first
+            self._drain("preempt")
+            sync = True
+            rows = self._decode_roster(None)
+        # growth pass first: a preemption inside _grow_blocks requeues
+        # its victim (and drops it from the step in flight), so the
+        # roster is read afterwards (a preempted victim must not decode
+        # on freed blocks)
+        for req in rows:
+            if req.state == ACTIVE:
                 self._grow_blocks(req)
-        active = [r for r in self.sched.running
-                  if r.prefilled >= r.prefill_target]
-        if not active:
-            return 0
+        rows = [r for r in rows if r.state == ACTIVE]
+        older = self._flight
+        new = self._dispatch_decode(rows, older) if rows else None
+        self._flight = None
+        self._retire(older)
+        if sync:
+            self._retire(new)
+        else:
+            self._flight = new
+        if self._flight is not None and self.sched.idle():
+            # every row of the step in flight ended with the read above
+            self._drain("idle")
+        return len(rows)
+
+    def _dispatch_decode(self, active: List[Request],
+                         flight: Optional[_Flight]) -> _Flight:
+        """Build and dispatch one decode step over ``active``; a row of
+        ``flight`` (the step in flight) takes its input token from that
+        step's output on the device."""
         bb = cc.bucket_for(len(active), self.decode_buckets)
         self._ensure_program("decode", bb)
+        src_of = flight.index if flight is not None else {}
         with telemetry.span("serve.decode", step=self.step_idx, bucket=bb,
-                            active=len(active)) as decode_span:
+                            active=len(active),
+                            overlapped=int(flight is not None)) as decode_span:
             with telemetry.span("serve.build"):
                 bsz = self.alloc.block_size
+                src = np.full((bb,), -1, np.int32)
                 tokens = np.zeros((bb,), np.int32)
                 tables = np.zeros((bb, self.max_blocks), np.int32)
                 lengths = np.zeros((bb,), np.int32)
@@ -2009,7 +2139,11 @@ class Engine:
                 temps = np.zeros((bb,), np.float32)
                 topks = np.zeros((bb,), np.int32)
                 for i, req in enumerate(active):
-                    tokens[i] = req.tokens[-1]
+                    j = src_of.get(req.id)
+                    if j is None:
+                        tokens[i] = req.tokens[-1]
+                    else:
+                        src[i] = j
                     tables[i, :len(req.blocks)] = req.blocks
                     lengths[i] = req.cached
                     slots[i] = req.blocks[req.cached // bsz]
@@ -2049,33 +2183,73 @@ class Engine:
                         global_rows=int(np.sum(seen)))
             t0 = time.monotonic()
             with telemetry.span("serve.dispatch", kind="decode", bucket=bb):
-                toks, oks, *more = self._run("decode", bb, tokens, *where,
-                                             keys, temps, topks)
-            with telemetry.span("serve.fetch"):
-                toks = np.asarray(toks)
-                oks = np.asarray(oks)
-                if self._routed_layers:
-                    # the routed layers' counts came with the tokens
-                    hit, here = (int(x) for x in np.asarray(more[0]))
-                    offered = (len(active) * self.model.experts_per_token
-                               * self._routed_layers)
-                    decode_span.annotate(experts_hit=hit, assigned_here=here)
-                    telemetry.counter("serve.moe.assignments").inc(offered)
-                    telemetry.counter("serve.moe.assignments_here").inc(here)
-            step_ms = (time.monotonic() - t0) * 1e3
-            hist = telemetry.histogram("serve.token_ms")
-            with telemetry.span("serve.emit"):
-                if self.described_kv:
-                    for req in active:
-                        self._count_ring_reuse(req.cached, req.cached + 1)
-                for i, req in enumerate(active):
-                    req.cached += 1
-                    if not bool(oks[i]):
-                        self._fail_nan(req)
-                        continue
-                    hist.observe(step_ms)
-                    self._append_token(req, int(toks[i]))
-        return len(active)
+                toks, oks, *more = self._run(
+                    "decode", bb,
+                    self._no_toks if flight is None else flight.toks, src,
+                    tokens, *where, keys, temps, topks)
+        # the position each row writes is in the cache once the program
+        # runs, whatever its token turns out to be
+        for req in active:
+            if self.described_kv:
+                self._count_ring_reuse(req.cached, req.cached + 1)
+            req.cached += 1
+        if flight is not None:
+            telemetry.counter("serve.pipeline.overlapped").inc()
+        return _Flight(self.step_idx, active, toks, oks, more, t0,
+                       decode_span)
+
+    def _retire(self, flight: Optional[_Flight]) -> None:
+        """Read a decode step's tokens in one fetch, then emit them.  A
+        row that ended since its step was dispatched (an EOS or NaN found
+        by the read before, a cancel, a timeout, a preemption) is thrown
+        away, as is a row whose logits are not finite."""
+        if flight is None:
+            return
+        with telemetry.span("serve.fetch", step=flight.step):
+            got = jax.device_get((flight.toks, flight.oks, *flight.more))
+        now = time.monotonic()
+        with telemetry.span("serve.emit"):
+            self._emit_decode(flight, got, now)
+
+    def _emit_decode(self, flight: _Flight, got, now: float) -> None:
+        toks, oks = got[0], got[1]
+        if self._routed_layers:
+            # the routed layers' counts came with the tokens
+            hit, here = (int(x) for x in got[2])
+            offered = (len(flight.rows) * self.model.experts_per_token
+                       * self._routed_layers)
+            flight.span.annotate(experts_hit=hit, assigned_here=here)
+            telemetry.counter("serve.moe.assignments").inc(offered)
+            telemetry.counter("serve.moe.assignments_here").inc(here)
+        step_ms = (now - flight.t0) * 1e3
+        hist = telemetry.histogram("serve.token_ms")
+        discarded = 0
+        for i, req in enumerate(flight.rows):
+            if req.state != ACTIVE or flight.index.get(req.id) != i:
+                discarded += 1
+                continue
+            if not bool(oks[i]):
+                discarded += 1
+                self._fail_nan(req)
+                continue
+            hist.observe(step_ms)
+            self._append_token(req, int(toks[i]))
+        flight.span.annotate(discarded=discarded)
+        if discarded:
+            telemetry.counter("serve.pipeline.discarded_rows").inc(discarded)
+
+    def _drain(self, reason: str) -> None:
+        """Read and emit the decode step in flight, if there is one,
+        before the caller does what needs every token on the host or
+        moves what an in-flight program addresses.
+        ``serve.pipeline.drains`` counts the drains that found one, by
+        ``reason``."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        telemetry.counter("serve.pipeline.drains").inc(reason=reason)
+        with telemetry.span("serve.drain", reason=reason):
+            self._retire(flight)
 
     def _verify_step(self) -> int:
         """The speculative replacement for :meth:`_decode_step`: draft
@@ -2143,7 +2317,7 @@ class Engine:
                 out, nem, oks = self._run(
                     "verify", bb, tokens, tables, lengths, live_v,
                     active_m, keys, temps, topks)
-            with telemetry.span("serve.fetch"):
+            with telemetry.span("serve.fetch", step=self.step_idx):
                 out = np.asarray(out)
                 nem = np.asarray(nem)
                 oks = np.asarray(oks)
@@ -2238,6 +2412,8 @@ class Engine:
         """Compact live KV blocks to the low end of the pool (both
         pools move in lockstep, tables are rewritten).  Returns the
         number of relocated blocks; outputs are bitwise unaffected."""
+        # the step in flight addresses the blocks by their old places
+        self._drain("defrag")
         mapping = self.alloc.defrag()
         if mapping:
             # blocks and state slots alike sit on the pools' axis 1; a
@@ -2254,7 +2430,10 @@ class Engine:
         return len(mapping)
 
     def check_tables(self) -> None:
-        """Allocator/table integrity audit (raises on any violation)."""
+        """Allocator/table integrity audit (raises on any violation).  A
+        decode step in flight changes nothing it audits: its rows' blocks
+        were grown before it was dispatched and stay theirs until its
+        tokens are read."""
         tables = {r.id: r.blocks for r in self.sched.running if r.blocks}
         if self.described_kv:
             self.alloc.check(tables, {r.id: r.ring for r in self.sched.running

@@ -147,6 +147,9 @@ class _Span:
         return False
 
     def annotate(self, **kv):
+        """Add args; once the span has closed this amends its recorded
+        event (the ring holds the same dict), for what is learnt after
+        the region ends."""
         self.args.update(kv)
 
 

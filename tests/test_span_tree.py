@@ -2,9 +2,11 @@
 (docs/observability.md "The serve step's span tree").
 
 * the tree: ``serve.step`` > ``serve.prefill`` / ``serve.decode`` >
-  ``serve.build`` / ``serve.dispatch`` / ``serve.fetch`` / ``serve.emit``,
-  for chunked, whole-prompt and speculative engines; a parent's ``dur``
-  covers its blocking fetch;
+  ``serve.build`` / ``serve.dispatch`` / ``serve.fetch``, for chunked,
+  whole-prompt and speculative engines; a prompt (or a chunk of one) and
+  a speculative step are read in their own span, whose ``dur`` covers
+  the blocking fetch; a pipelined decode step's tokens are read by one
+  ``serve.fetch`` beside it, under ``serve.step`` (or ``serve.drain``);
 * ``serve.prefill_ms`` (and the chunk EWMA the scheduler's SLO rule
   reads) is taken after the fetch;
 * a recording span is a ``jax.profiler.TraceAnnotation`` for its
@@ -102,26 +104,41 @@ def test_engine_step_is_one_span_tree(kind, tmp_path):
     def parent(ev):
         return spans.get(ev["args"].get("parent"))
 
+    # a program read where it is dispatched holds its fetch (and a
+    # decode step its emit); a pipelined engine reads beside its spans
+    in_place = {"chunked": ("serve.prefill",), "whole": ("serve.prefill",),
+                "speculative": ("serve.prefill", "serve.decode")}[kind]
+    for ev in spans.values():
+        up = parent(ev)
+        if ev["name"] in ("serve.build", "serve.dispatch"):
+            assert up["name"] in ("serve.prefill", "serve.decode"), (ev, up)
+            assert parent(up)["name"] == "serve.step", (ev, up)
+        elif ev["name"] in ("serve.fetch", "serve.emit"):
+            assert up["name"] in ("serve.step", "serve.drain") + in_place, (
+                ev, up)
+        elif ev["name"] in ("serve.admit", "serve.prefill", "serve.decode",
+                            "serve.drain"):
+            assert up["name"] == "serve.step", ev
+    # every decode step's tokens are read by exactly one fetch, after the
+    # step was dispatched
+    decodes = {ev["args"]["step"]: ev for ev in spans.values()
+               if ev["name"] == "serve.decode"}
+    reads = [ev for ev in spans.values()
+             if ev["name"] == "serve.fetch" and "step" in ev["args"]]
+    assert decodes and sorted(f["args"]["step"] for f in reads) == sorted(
+        decodes)
+    for f in reads:
+        assert f["ts"] >= decodes[f["args"]["step"]]["ts"]
+    # a prompt (or a chunk of one) is read inside its own span, so its
+    # time is the program's and not the dispatch's
     fetch_of = {}
     for ev in spans.values():
-        if ev["name"] in ("serve.build", "serve.dispatch", "serve.fetch",
-                          "serve.emit"):
-            mid = parent(ev)
-            assert mid is not None and mid["name"] in (
-                ("serve.decode",) if ev["name"] == "serve.emit"
-                else ("serve.prefill", "serve.decode")), (ev, mid)
-            assert parent(mid)["name"] == "serve.step", (ev, mid)
-            if ev["name"] == "serve.fetch":
-                assert mid["args"]["id"] not in fetch_of, "one fetch a program"
-                fetch_of[mid["args"]["id"]] = ev
-        elif ev["name"] in ("serve.admit", "serve.prefill", "serve.decode"):
-            assert parent(ev)["name"] == "serve.step", ev
-    # every program span holds its blocking fetch, so its time is the
-    # program's and not the dispatch's
-    programs = [ev for ev in spans.values()
-                if ev["name"] in ("serve.prefill", "serve.decode")]
-    assert programs and len(fetch_of) == len(programs)
-    for ev in programs:
+        if ev["name"] == "serve.fetch" and parent(ev)["name"] == "serve.prefill":
+            assert ev["args"]["parent"] not in fetch_of, "one fetch a program"
+            fetch_of[ev["args"]["parent"]] = ev
+    prefills = [ev for ev in spans.values() if ev["name"] == "serve.prefill"]
+    assert prefills and len(fetch_of) == len(prefills)
+    for ev in prefills:
         f = fetch_of[ev["args"]["id"]]
         assert ev["dur"] >= f["dur"]
         assert ev["ts"] <= f["ts"] and f["ts"] + f["dur"] <= ev["ts"] + ev["dur"]
@@ -245,9 +262,10 @@ def test_serve_spans_are_regions_of_the_profilers_host_plane(tmp_path):
         assert len(by[name]) == ring[name], name   # one region a span
     mids = by["serve.prefill"] + by["serve.decode"]
     assert all(_inside(ev, by["serve.step"]) for ev in mids)
-    for name in ("serve.fetch", "serve.build", "serve.dispatch"):
+    for name in ("serve.build", "serve.dispatch"):
         assert all(_inside(ev, mids) for ev in by[name]), name
-    assert all(_inside(ev, by["serve.decode"]) for ev in by["serve.emit"])
+    for name in ("serve.fetch", "serve.emit"):
+        assert all(_inside(ev, by["serve.step"]) for ev in by[name]), name
 
 
 def _prefetch_some(n=4):

@@ -27,15 +27,23 @@ anywhere.  It prints what ``PERF.md`` section 5 is written from:
   the steps by the branch their program's sampler took (``greedy``: an
   ``argmax`` alone; ``select``: a row samples) and, for a model with
   routed layers, ``[experts]``: ``experts_hit`` and ``assigned_here`` a
-  step.
+  step;
+* ``[pipeline]``: the ``serve.decode`` spans dispatched while another
+  decode step was in flight (``overlapped``), out of all; the drains by
+  ``reason``; the rows computed and thrown away (``discarded``); and the
+  device's idle ms a ``serve.step``, for the steps that drained (a
+  ``serve.drain`` region inside) and for those that dispatched a decode
+  step without draining.
 
 It measures nothing the benchmark reports and changes no number of it.
 """
 from __future__ import annotations
 
 import argparse
+import bisect
 import collections
 import gzip
+import itertools
 import json
 import os
 import shutil
@@ -124,11 +132,60 @@ def split_gaps(tr):
                        in trace.clip(tr.device_ops[chip], lo, hi)
                        if not trace.CONTAINER.match(name))
     edges = sorted({t for _, a, d in tr.host_regions for t in (a, a + d)})
+    # regions by start, and the longest one that counts: the regions that
+    # can cover an instant of a gap start within that much before it, so
+    # each gap looks at those alone (a scan of every edge and region for
+    # every gap takes many minutes on an open loop's trace)
+    regions = sorted((r for r in tr.host_regions if r[0] != "bench.window"),
+                     key=lambda r: r[1])
+    starts = [s for _, s, _ in regions]
+    longest = max((d for _, _, d in regions), default=0)
     out = collections.Counter()
     for a, b in trace.subtract([(lo, hi)], busy):
-        cuts = [a] + [t for t in edges if a < t < b] + [b]
+        near = regions[bisect.bisect_left(starts, a - longest):
+                       bisect.bisect_right(starts, b)]
+        inner = edges[bisect.bisect_right(edges, a):bisect.bisect_left(edges, b)]
+        cuts = [a] + inner + [b]
         for x, y in zip(cuts, cuts[1:]):
-            out[trace.region_at(tr.host_regions, (x + y) // 2)] += (y - x) / 1e9
+            out[trace.region_at(near, (x + y) // 2)] += (y - x) / 1e9
+    return out
+
+
+def idle_by_step(tr):
+    """``{"drained": [steps, idle s], "overlapped": [steps, idle s]}``:
+    the first chip's idle time inside each ``serve.step`` host region of
+    the window, by whether the step drained the pipeline (a
+    ``serve.drain`` region inside it) or dispatched a decode step
+    without draining (a ``serve.decode`` region and no drain)."""
+    from benchmark.harness import trace
+    lo, hi = trace.window_of(tr)
+    chip = sorted(tr.device_ops)[0]
+    busy = trace.union((a, b) for name, a, b
+                       in trace.clip(tr.device_ops[chip], lo, hi)
+                       if not trace.CONTAINER.match(name))
+    idle = trace.subtract([(lo, hi)], busy)
+    starts = [a for a, _ in idle]
+    spans = collections.defaultdict(list)
+    for name, start, dur in sorted(tr.host_regions, key=lambda r: r[1]):
+        if lo <= start and start + dur <= hi:
+            spans[name].append((start, start + dur))
+    out = {"drained": [0, 0.0], "overlapped": [0, 0.0]}
+    for a, b in spans["serve.step"]:
+        def holds(name):
+            inside = spans[name][bisect.bisect_left(spans[name], (a,)):]
+            return any(y <= b for x, y in
+                       itertools.takewhile(lambda r: r[0] <= b, inside))
+        kind = ("drained" if holds("serve.drain")
+                else "overlapped" if holds("serve.decode") else None)
+        if kind is None:
+            continue
+        gap = 0
+        for x, y in idle[max(bisect.bisect_right(starts, a) - 1, 0):]:
+            if x >= b:
+                break
+            gap += max(0, min(y, b) - max(x, a))
+        out[kind][0] += 1
+        out[kind][1] += gap / 1e9
     return out
 
 
@@ -230,6 +287,7 @@ def read(stem: str) -> None:
     print(f"[host plane] regions {({k: len(v) for k, v in sorted(by.items())})}; "
           f"{nested}/{len(steps)} serve.step inside a bench.engine_step")
 
+    per_step = idle_by_step(tr)
     space = load_xspace(raw)
     if space is None:
         print("[program] no xplane_pb2 found: device ms by scope not read")
@@ -282,6 +340,22 @@ def read(stem: str) -> None:
                   f"summed over the routed layers), assigned_here {here:.1f} "
                   f"a step = {here / max(hit, 1e-9):.2f} rows a hit expert, "
                   f"{here / max(rows, 1e-9):.2f} a decoded row")
+        # the decode pipeline: how often a step was queued behind another
+        decodes = [ev["args"] for ev in spans if ev["name"] == "serve.decode"
+                   and "overlapped" in ev["args"]]
+        if decodes:
+            over = sum(a["overlapped"] for a in decodes)
+            drains = collections.Counter(
+                ev["args"]["reason"] for ev in spans
+                if ev["name"] == "serve.drain")
+            print(f"[pipeline] {over} of {len(decodes)} serve.decode spans "
+                  f"overlapped ({100 * over / len(decodes):.2f} %); drains "
+                  + (", ".join(f"{k} {v}" for k, v in drains.most_common())
+                     or "none")
+                  + f"; rows discarded {sum(a.get('discarded', 0) for a in decodes)}"
+                  "; device idle ms a step (traced window): " + ", ".join(
+                      f"{k} {1e3 * s / max(n, 1):.3f} over {n}"
+                      for k, (n, s) in per_step.items()))
 
 
 def main() -> int:
