@@ -93,7 +93,13 @@ def eval_symbol(symbol, arg_vals: Dict[str, jax.Array],
     head_set = {(id(n), i) for (n, i) in symbol._heads}
 
     def eval_node(node, in_vals):
-        """One op node; returns (outs list, aux_updates dict)."""
+        """One op node; returns (outs list, aux_updates dict).  Its ops
+        carry the node's name in their HLO ``op_name`` (metadata only),
+        so a device trace can say which layer an instruction is for."""
+        with jax.named_scope(node.name):
+            return _eval_node(node, in_vals)
+
+    def _eval_node(node, in_vals):
         op = node.op
         params = node.parsed_params()
         aux_full = node.aux_full_names()

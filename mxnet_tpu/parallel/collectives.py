@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import re
 import threading
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -51,7 +52,8 @@ from .. import quant
 
 __all__ = ["allreduce_sum", "allreduce_mean", "distinct_devices",
            "psum_compressed", "count_collectives", "CollectiveStats",
-           "DEFAULT_BUCKET_BYTES", "COMPRESSIONS", "plan_buckets"]
+           "DEFAULT_BUCKET_BYTES", "COMPRESSIONS", "plan_buckets",
+           "collective_inventory", "collectives_by_axes", "replica_groups"]
 
 DEFAULT_BUCKET_BYTES = 4 << 20  # ~4 MiB, the classic DDP default
 COMPRESSIONS = (None, "int8", "bf16", "fp8")
@@ -465,3 +467,138 @@ def allreduce_mean(arrays, *, priorities=None,
                    compression: Optional[str] = None):
     return _allreduce(arrays, mean=True, priorities=priorities,
                       bucket_bytes=bucket_bytes, compression=compression)
+
+
+# ---------------------------------------------------------------------------
+# Collectives of a compiled program, by mesh axis
+# ---------------------------------------------------------------------------
+
+_COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
+                   "all-to-all", "collective-permute", "collective-broadcast")
+# ``%name = <shape> <opcode>(``: the opcode is the last word before the
+# operand list's parenthesis
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s"
+                        r"([a-z][a-z0-9\-]*)\(")
+_HLO_ARRAY = re.compile(r"\b([a-z]+[0-9]*(?:e[0-9]+m[0-9]+[a-z]*)?)"
+                        r"\[([0-9,]*)\]")
+_HLO_BITS = {"pred": 8, "s4": 4, "u4": 4, "s8": 8, "u8": 8, "s16": 16,
+             "u16": 16, "f16": 16, "bf16": 16, "s32": 32, "u32": 32,
+             "f32": 32, "s64": 64, "u64": 64, "f64": 64, "c64": 64,
+             "c128": 128}
+_IOTA_GROUPS = re.compile(r"^\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
+
+
+def _hlo_bytes(shape_text: str) -> int:
+    """Bytes of an HLO result type, a tuple's elements summed (the
+    float8 kinds ``f8e4m3fn`` ... are a byte an element)."""
+    total = 0
+    for dtype, dims in _HLO_ARRAY.findall(shape_text):
+        bits = _HLO_BITS.get(dtype, 8 if dtype.startswith("f8") else 0)
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        total += n * bits // 8
+    return total
+
+
+def replica_groups(attr: str, num_devices: int) -> List[List[int]]:
+    """The groups of an HLO ``replica_groups=`` value, in either form:
+    the list ``{{0,1},{2,3}}`` or XLA's iota form ``[G,S]<=[dims]`` /
+    ``[G,S]<=[dims]T(perm)`` (``arange(prod(dims))`` laid out as
+    ``dims``, transposed by ``perm``, read as G groups of S).  ``{}``
+    is one group of every device."""
+    attr = attr.strip()
+    m = _IOTA_GROUPS.match(attr)
+    if m:
+        g, size = int(m.group(1)), int(m.group(2))
+        dims = [int(d) for d in m.group(3).split(",")]
+        ids = np.arange(int(np.prod(dims))).reshape(dims)
+        if m.group(4):
+            ids = ids.transpose([int(p) for p in m.group(4).split(",")])
+        return ids.reshape(g, size).tolist()
+    groups = [[int(i) for i in grp.split(",") if i.strip()]
+              for grp in re.findall(r"\{([\d,\s]*)\}", attr)]
+    groups = [grp for grp in groups if grp]
+    return groups or [list(range(num_devices))]
+
+
+def _axes_spanned(groups, coords, axis_names) -> Tuple[str, ...]:
+    """The mesh axes along which the devices of some group differ."""
+    varied = set()
+    for grp in groups:
+        pos = [coords[i] for i in grp if i in coords]
+        for a in range(len(axis_names)):
+            if len({p[a] for p in pos}) > 1:
+                varied.add(a)
+    return tuple(axis_names[a] for a in sorted(varied))
+
+
+def collective_inventory(hlo_text: str, mesh: Mesh,
+                         device_order: Optional[Sequence[int]] = None):
+    """Every collective instruction of a compiled program's HLO text:
+    ``{instruction: {"kind", "bytes", "axes", "op_name"}}``.
+
+    ``axes`` are the mesh axes its replica groups (a permute's source ->
+    target pairs) span.  The groups' ids are the program's logical
+    device ids, mapped to devices through ``device_order`` (the
+    executable's device assignment; default the mesh's own order) and
+    to mesh coordinates through ``mesh``.  Both halves of an
+    asynchronous form (``all-reduce-start`` / ``-done``) are listed, the
+    ``-done`` with its ``-start``'s axes; ``bytes`` is the collective's
+    result (a ``-start`` the bytes of its ``-done``), a tuple's
+    elements summed.  A device trace names each executed instruction
+    the same way, so a collective's device time can be read by axis.
+    """
+    devs = list(mesh.devices.flat)
+    order = (list(device_order) if device_order is not None
+             else [d.id for d in devs])
+    at = {d.id: np.unravel_index(i, mesh.devices.shape)
+          for i, d in enumerate(devs)}
+    coords = {logical: at[dev] for logical, dev in enumerate(order)
+              if dev in at}
+    axis_names = tuple(mesh.axis_names)
+    out = {}
+    starts = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, shape_text, opcode = m.groups()
+        base = re.sub(r"-(start|done)$", "", opcode)
+        if base not in _COLLECTIVE_OPS:
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        rec = {"kind": opcode, "bytes": _hlo_bytes(shape_text), "axes": (),
+               "op_name": op_name.group(1) if op_name else ""}
+        if opcode.endswith("-done"):
+            src = re.search(r"%([\w.\-]+)", line[m.end():])
+            start = starts.get(src.group(1)) if src else None
+            if start is not None:
+                rec["axes"] = start["axes"]
+                start["bytes"] = rec["bytes"]
+            out[name] = rec
+            continue
+        groups = re.search(r"replica_groups=(\[[^=]*?\]<=\[[\d,]+\]"
+                           r"(?:T\([\d,]+\))?|\{[\d,{}\s]*\})", line)
+        pairs = re.search(r"source_target_pairs=(\{[\d,{}\s]*\})", line)
+        if groups:
+            spanned = replica_groups(groups.group(1), len(order))
+        elif pairs:
+            spanned = replica_groups(pairs.group(1), len(order))
+        else:
+            spanned = [list(range(len(order)))]
+        rec["axes"] = _axes_spanned(spanned, coords, axis_names)
+        out[name] = rec
+        if opcode.endswith("-start"):
+            starts[name] = rec
+    return out
+
+
+def collectives_by_axes(inventory) -> dict:
+    """``{"model": {instruction: bytes}, "data": {...}}`` from a
+    :func:`collective_inventory`: each instruction under the axes it
+    spans, joined by ``+`` where it spans several (``""``: none)."""
+    out: dict = {}
+    for name, rec in inventory.items():
+        out.setdefault("+".join(rec["axes"]), {})[name] = rec["bytes"]
+    return out

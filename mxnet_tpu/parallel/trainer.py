@@ -328,6 +328,14 @@ class ShardedTrainer:
         self.aot_stats: Dict[str, int] = {"hits": 0, "fallbacks": 0,
                                           "compile_errors": 0}
         self.compile_info: List[Dict[str, Any]] = []
+        # program kind -> the collectives of its AOT program, read once
+        # from the compiled HLO (``collectives.collective_inventory``:
+        # instruction -> kind, bytes, mesh axes, op_name); and the same
+        # by axis (axis -> {instruction: bytes}), which a recording
+        # ``step.dispatch`` span carries as ``collectives`` so a device
+        # trace's collective time can be split by mesh axis
+        self.collective_inventory: Dict[str, Dict[str, Dict[str, Any]]] = {}
+        self._collective_axes: Dict[str, Dict[str, Dict[str, int]]] = {}
 
     def _multiproc(self) -> bool:
         if not hasattr(self, "_multiproc_cached"):
@@ -776,8 +784,10 @@ class ShardedTrainer:
             def body(params, aux, batch, rng, scale, *ef_res):
                 rng = jax.random.fold_in(rng, jax.lax.axis_index(daxis))
                 grads, heads, auxu = base(params, aux, batch, rng, scale)
-                red = reduce_grads(grads, *ef_res)
-                auxu = {k: jax.lax.pmean(v, daxis) for k, v in auxu.items()}
+                with jax.named_scope("grad_reduce"):
+                    red = reduce_grads(grads, *ef_res)
+                    auxu = {k: jax.lax.pmean(v, daxis)
+                            for k, v in auxu.items()}
                 return (red[0], heads, auxu, red[1]) + tuple(red[2:])
 
             kwargs = dict(mesh=self.mesh,
@@ -791,8 +801,10 @@ class ShardedTrainer:
                 # same effect from per-example positions in the global batch
                 rng = jax.random.fold_in(rng, jax.lax.axis_index(daxis))
                 grads, heads, auxu = base(params, aux, batch, rng)
-                red = reduce_grads(grads, *ef_res)
-                auxu = {k: jax.lax.pmean(v, daxis) for k, v in auxu.items()}
+                with jax.named_scope("grad_reduce"):
+                    red = reduce_grads(grads, *ef_res)
+                    auxu = {k: jax.lax.pmean(v, daxis)
+                            for k, v in auxu.items()}
                 return (red[0], heads, auxu) + tuple(red[2:])
 
             kwargs = dict(mesh=self.mesh,
@@ -870,10 +882,16 @@ class ShardedTrainer:
 
         def _grads_and_heads(params, aux, batch, rng, *scale_arg):
             def fwd(p):
-                args = cast_params(p)
-                args.update(batch)
-                heads, auxu = eval_symbol(sym, args, aux, rng, True,
-                                          topo=topo)
+                # the step's phases are named in the HLO ``op_name``
+                # (metadata only): ``forward``, its transpose
+                # ``transpose(jvp(forward))`` (the backward, a remat's
+                # recomputed forward inside it), ``update``, ``guard``,
+                # ``grad_reduce``; a device trace reads a step by them
+                with jax.named_scope("forward"):
+                    args = cast_params(p)
+                    args.update(batch)
+                    heads, auxu = eval_symbol(sym, args, aux, rng, True,
+                                              topo=topo)
                 return heads, auxu
             heads, vjp_fn, auxu = jax.vjp(fwd, params, has_aux=True)
             if scaling:
@@ -1057,60 +1075,65 @@ class ShardedTrainer:
             ok = None
             mult = None
             if resil is not None:
-                if sq is None:
-                    sq = resilience.tree_sq_sum(grads)
-                # overflow of the f32 square-sum reads as non-finite —
-                # exactly right: a gradient too large to measure is a step
-                # we must not take (and dynamic scaling backs off)
-                ok = jnp.isfinite(sq)
-                eff_norm = jnp.sqrt(sq) * jnp.float32(
-                    abs(self._rescale_grad) or 1.0)
-                if scaling:
-                    inv_scale = jnp.float32(1.0) / gstate["scale"]
-                    eff_norm = eff_norm * inv_scale
-                    mult = inv_scale
-                if resil.clip_global_norm is not None:
-                    coef = jnp.minimum(
-                        jnp.float32(1.0),
-                        jnp.float32(resil.clip_global_norm)
-                        / jnp.maximum(eff_norm, jnp.float32(1e-12)))
-                    mult = coef if mult is None else mult * coef
-                if mult is not None and not fused:
-                    # ONE combined multiplier (unscale x clip) applied
-                    # once; with neither feature on, no multiply at all —
-                    # a guard-on clean run stays bitwise identical to
-                    # guard-off (pinned by tests/test_resilience.py).
-                    # On the fused path mult rides INTO the kernel.
-                    grads = {n: g * mult.astype(g.dtype)
-                             for n, g in grads.items()}
-            if fused:
-                # single streaming pass per bucket: combined multiplier,
-                # guard verdict and the whole optimizer update ride ONE
-                # primitive (ops/fused_update.py); the where-gating lives
-                # inside it, so a bad step stays a bitwise no-op
-                new_params, new_opt = _fused_apply(
-                    params, grads, opt_state, lr, t, mult, ok)
-            else:
-                new_params, new_opt = _unfused_apply(
-                    params, grads, opt_state, lr, t, rng, ok)
-            if ef:
-                # a bad step keeps the OLD residual: the new one was
-                # computed from non-finite grads and would poison every
-                # following step's feedback
-                for k, nres in zip(ef_keys, new_ef):
-                    new_opt[k] = (jnp.where(ok, nres, opt_state[k])
-                                  if ok is not None else nres)
-            for k in opt_state:
-                # static opt-state riders (wd segment vectors) pass
-                # through unchanged — identity keeps donation aliasing
-                if k not in new_opt:
-                    new_opt[k] = opt_state[k]
+                with jax.named_scope("guard"):
+                    if sq is None:
+                        sq = resilience.tree_sq_sum(grads)
+                    # overflow of the f32 square-sum reads as non-finite —
+                    # exactly right: a gradient too large to measure is a
+                    # step we must not take (and dynamic scaling backs off)
+                    ok = jnp.isfinite(sq)
+                    eff_norm = jnp.sqrt(sq) * jnp.float32(
+                        abs(self._rescale_grad) or 1.0)
+                    if scaling:
+                        inv_scale = jnp.float32(1.0) / gstate["scale"]
+                        eff_norm = eff_norm * inv_scale
+                        mult = inv_scale
+                    if resil.clip_global_norm is not None:
+                        coef = jnp.minimum(
+                            jnp.float32(1.0),
+                            jnp.float32(resil.clip_global_norm)
+                            / jnp.maximum(eff_norm, jnp.float32(1e-12)))
+                        mult = coef if mult is None else mult * coef
+                    if mult is not None and not fused:
+                        # ONE combined multiplier (unscale x clip) applied
+                        # once; with neither feature on, no multiply at
+                        # all — a guard-on clean run stays bitwise
+                        # identical to guard-off (pinned by
+                        # tests/test_resilience.py).  On the fused path
+                        # mult rides INTO the kernel.
+                        grads = {n: g * mult.astype(g.dtype)
+                                 for n, g in grads.items()}
+            with jax.named_scope("update"):
+                if fused:
+                    # single streaming pass per bucket: combined
+                    # multiplier, guard verdict and the whole optimizer
+                    # update ride ONE primitive (ops/fused_update.py); the
+                    # where-gating lives inside it, so a bad step stays a
+                    # bitwise no-op
+                    new_params, new_opt = _fused_apply(
+                        params, grads, opt_state, lr, t, mult, ok)
+                else:
+                    new_params, new_opt = _unfused_apply(
+                        params, grads, opt_state, lr, t, rng, ok)
+                if ef:
+                    # a bad step keeps the OLD residual: the new one was
+                    # computed from non-finite grads and would poison
+                    # every following step's feedback
+                    for k, nres in zip(ef_keys, new_ef):
+                        new_opt[k] = (jnp.where(ok, nres, opt_state[k])
+                                      if ok is not None else nres)
+                for k in opt_state:
+                    # static opt-state riders (wd segment vectors) pass
+                    # through unchanged — identity keeps donation aliasing
+                    if k not in new_opt:
+                        new_opt[k] = opt_state[k]
             new_aux = dict(aux)
             if resil is not None:
-                for k, v in auxu.items():
-                    new_aux[k] = jnp.where(ok, v, aux[k])
-                new_gstate = resilience.state_update(gstate, ok, eff_norm,
-                                                     resil)
+                with jax.named_scope("guard"):
+                    for k, v in auxu.items():
+                        new_aux[k] = jnp.where(ok, v, aux[k])
+                    new_gstate = resilience.state_update(gstate, ok,
+                                                         eff_norm, resil)
                 return new_params, new_aux, new_opt, heads, new_gstate
             new_aux.update(auxu)
             return new_params, new_aux, new_opt, heads
@@ -1215,6 +1238,8 @@ class ShardedTrainer:
             out_shardings=acc_out_sh,
             donate_argnums=(0, 1, 2))
         self._aot.clear()
+        self.collective_inventory.clear()
+        self._collective_axes.clear()
 
     # ------------------------------------------------------------------
     # AOT warmup (compile_cache integration)
@@ -1407,6 +1432,7 @@ class ShardedTrainer:
             compiled, info = cc.get_cache().get_or_compile(
                 key, build, label=f"trainer.{kind}")
             self._aot[kind] = compiled
+            self._read_collectives(kind, compiled)
             info = dict(info)
             info["kind"] = kind
             self.compile_info.append(info)
@@ -1430,6 +1456,24 @@ class ShardedTrainer:
             th.start()
             return th
         return [compile_one(kind, b_avals) for kind, b_avals in work]
+
+    def _read_collectives(self, kind: str, compiled) -> None:
+        """Fill ``collective_inventory[kind]`` from the compiled program
+        (once a compile; nothing on one device, where no collective
+        exists)."""
+        from .collectives import collective_inventory, collectives_by_axes
+        self.collective_inventory.pop(kind, None)
+        self._collective_axes.pop(kind, None)
+        if self.mesh.devices.size < 2:
+            return
+        # the executable's device assignment names a replica group's
+        # ids; a pod process sees only its own devices: the mesh's order
+        order = [d.id for d in compiled.runtime_executable().local_devices()]
+        if len(order) != self.mesh.devices.size:
+            order = None
+        inv = collective_inventory(compiled.as_text(), self.mesh, order)
+        self.collective_inventory[kind] = inv
+        self._collective_axes[kind] = collectives_by_axes(inv)
 
     # ------------------------------------------------------------------
     # Stepping
@@ -1524,7 +1568,8 @@ class ShardedTrainer:
             "(donate_argnums: params, aux, opt_state)")
         # scope the mesh so mesh-aware ops (RingAttention) pick up the seq
         # axis when this step traces
-        with telemetry.span("step.dispatch", step=self._num_update), \
+        with telemetry.span("step.dispatch", step=self._num_update,
+                            collectives=self._collective_axes.get("train")), \
                 default_mesh(self.mesh), self._precision_scope():
             fn = self._aot_or_jit("train", self._train_step)
             if self._resil is not None:
@@ -1561,7 +1606,6 @@ class ShardedTrainer:
                     "falling back to jit", kind, e)
                 return jit_fn(*args)
             self.aot_stats["hits"] += 1
-            telemetry.counter("trainer.aot_hits").inc()
             return out
         return dispatch
 
@@ -1584,7 +1628,9 @@ class ShardedTrainer:
         nd_mod.note_donation(
             f"ShardedTrainer.step #{self._num_update} "
             "(donate_argnums: params, aux, opt_state)")
-        with telemetry.span("step.dispatch", step=self._num_update), \
+        with telemetry.span("step.dispatch", step=self._num_update,
+                            collectives=self._collective_axes.get(
+                                "train_acc")), \
                 default_mesh(self.mesh), self._precision_scope():
             fn = self._aot_or_jit("train_acc", self._train_step_acc)
             if self._resil is not None:

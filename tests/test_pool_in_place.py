@@ -462,3 +462,97 @@ def test_grouped_decode_kernel_compiles_for_a_v5e(topo, window, ring,
         jax.config.update("jax_enable_compilation_cache", True)
     assert "mxtpu_gqa_decode" in comp.as_text()
     assert comp.memory_analysis().temp_size_in_bytes < 10e6
+
+
+# -- the training step's names ----------------------------------------------
+
+def _shape_trainer(topo, which):
+    """A trainer bound to described devices: parameters and optimizer
+    state are shapes (``_global_put`` and the optimizer's zeros hand back
+    ``ShapeDtypeStruct``s), so nothing is placed."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import models
+    from mxnet_tpu import symbol as S
+    from mxnet_tpu.parallel import ShardedTrainer, make_mesh, megatron_rules
+
+    class ShapeTrainer(ShardedTrainer):
+        def _global_put(self, val, sh):
+            if not hasattr(val, "dtype"):
+                val = np.asarray(val)
+            return jax.ShapeDtypeStruct(tuple(val.shape), val.dtype,
+                                        sharding=sh)
+
+    mx.random.seed(7)
+    if which == "conv":
+        d = S.Variable("data")
+        net = S.Convolution(d, num_filter=128, kernel=(3, 3), pad=(1, 1),
+                            name="conv1")
+        net = S.BatchNorm(net, name="bn1")
+        net = S.Activation(net, act_type="relu", name="relu1")
+        net = S.Pooling(net, global_pool=True, kernel=(1, 1),
+                        pool_type="avg", name="pool")
+        net = S.FullyConnected(net, num_hidden=128, name="fc")
+        tr = ShapeTrainer(S.SoftmaxOutput(net, name="softmax"),
+                          mesh=make_mesh({"data": 1}, topo.devices[:1]),
+                          optimizer="sgd",
+                          optimizer_params={"learning_rate": 0.1,
+                                            "momentum": 0.9},
+                          fused_update=True, compute_dtype="bfloat16")
+        tr.bind(data_shapes={"data": (16, 8, 32, 32)},
+                label_shapes={"softmax_label": (16,)})
+        return tr
+    sym = models.get_symbol("transformer-lm", vocab_size=512, num_layers=2,
+                            d_model=256, heads=4, batch_size=4, seq_len=256,
+                            loss_head=True, remat=True)
+    tr = ShapeTrainer(sym, mesh=make_mesh({"data": 2, "model": 2},
+                                          topo.devices[:4]),
+                      rules=megatron_rules(), optimizer="adam",
+                      optimizer_params={"learning_rate": 1e-4},
+                      compute_dtype="bfloat16", matmul_precision="bfloat16")
+    zeros = tr.optimizer.state_zeros_like
+    tr.optimizer.state_zeros_like = lambda w: jax.eval_shape(
+        zeros, jax.ShapeDtypeStruct(w.shape, w.dtype))
+    tr.bind(data_shapes={"data": (4, 256)},
+            label_shapes={"softmax_label": (4, 256)})
+    return tr
+
+
+@pytest.mark.parametrize("which", ["conv", "lm"])
+def test_train_step_names_are_metadata_only_on_a_v5e(topo, which,
+                                                     monkeypatch):
+    """Compiled for a described v5e, the training step with its phase and
+    node scopes is, metadata and the location table stripped, the same
+    program as without them (the fused sgd-momentum conv step on one
+    chip; the tensor-parallel LM with remat and adam on data 2 x model
+    2, where each collective's mesh axis is read from the program)."""
+    import contextlib
+
+    from mxnet_tpu.parallel.collectives import collective_inventory
+
+    def compiled_text():
+        tr = _shape_trainer(topo, which)
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            comp = tr.trace_program("train")[0].lower(
+                lowering_platforms=("tpu",)).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+        return tr, comp.as_text()
+
+    def stripped(text):
+        return re.sub(r",? metadata=\{[^}]*\}", "",
+                      text.split("\nFileNames")[0])
+
+    tr, text = compiled_text()
+    assert "/transpose(jvp(forward))/" in text and "/update/" in text
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        _, bare = compiled_text()
+    assert "/update/" not in bare
+    assert stripped(text) == stripped(bare)
+    if which == "lm":
+        inv = collective_inventory(text, tr.mesh)
+        axes = {r["axes"] for r in inv.values()}
+        assert ("model",) in axes and ("data",) in axes, axes

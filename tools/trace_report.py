@@ -33,7 +33,19 @@ anywhere.  It prints what ``PERF.md`` section 5 is written from:
   ``reason``; the rows computed and thrown away (``discarded``); and the
   device's idle ms a ``serve.step``, for the steps that drained (a
   ``serve.drain`` region inside) and for those that dispatched a decode
-  step without draining.
+  step without draining;
+* ``[train]``, for a training cell: the step program's device ms a step
+  by the trainer's phases (``forward``, ``backward`` = ops under
+  ``transpose(``, a remat's recomputed forward included, ``update``,
+  ``guard``, ``grad_reduce``, and ``unowned``: no scope of the
+  trainer's), the top symbol nodes of each phase and the top
+  instructions of ``unowned``; ``[collectives]``: count, bytes and
+  device ms a step of the step's collectives by mesh axis and phase,
+  the axes from the ``step.dispatch`` spans' ``collectives`` argument
+  (``ShardedTrainer.collective_inventory``).
+
+Every ``op_name`` comes from the harness's one reader of them,
+``benchmark/harness/trace_ops.py``.
 
 It measures nothing the benchmark reports and changes no number of it.
 """
@@ -72,6 +84,10 @@ def run_cell(args) -> str:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     device = dev.require(int(cell["chips"]), False)
     cc.enable_persistent_cache(os.path.join(REPO, ".jax_cache"))
+    # a cached executable keyed without its metadata would bring another
+    # build's op_names (the scopes this tool reads) into the trace
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     scratch = tempfile.mkdtemp(prefix="mxtpu-trace-")
     run = Run(cell=cell, config=spec.load_config(bench, cell["config"]),
               traffic=mix, seed=args.seed,
@@ -99,26 +115,6 @@ def scope_of(op_name: str) -> str:
     component under the jit wrappers, if a primitive follows it."""
     parts = [p for p in op_name.split("/") if p and not p.startswith("jit(")]
     return parts[0] if len(parts) > 1 else ""
-
-
-def load_xspace(raw: bytes):
-    """The trace as an ``XSpace`` message.  ``jax.profiler.ProfileData``
-    shows an event's own stats only; the HLO ``op_name`` (stat
-    ``tf_op``) sits on the event's METADATA, so the proto is read with
-    the ``xplane_pb2`` that ships inside the installed tensorflow,
-    loaded by path (importing tensorflow itself takes seconds)."""
-    import importlib.util
-    for root in sys.path:
-        path = os.path.join(root, "tensorflow", "tsl", "profiler", "protobuf",
-                            "xplane_pb2.py")
-        if os.path.isfile(path):
-            spec = importlib.util.spec_from_file_location("xplane_pb2", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            space = mod.XSpace()
-            space.ParseFromString(raw)
-            return space
-    return None
 
 
 def split_gaps(tr):
@@ -189,32 +185,17 @@ def idle_by_step(tr):
     return out
 
 
-def by_scope(space, lo: int, hi: int) -> None:
+def by_scope(ot, lo: int, hi: int) -> None:
     """Device ms by named scope for the median run of each program."""
     from benchmark.harness import trace
-    plane = next((p for p in space.planes
-                  if trace.DEVICE_PLANE.match(p.name)), None)
-    if plane is None:
+    chip = min(ot.ops, default=None)
+    if chip is None:
         return
-    names = {k: v.name for k, v in plane.stat_metadata.items()}
-    lines = {line.name: line for line in plane.lines}
-
-    def events(line):
-        for ev in line.events:
-            yield (line.timestamp_ns + ev.offset_ps // 1000,
-                   ev.duration_ps / 1000.0, plane.event_metadata[ev.metadata_id])
-
-    def op_name(meta) -> str:
-        for st in meta.stats:
-            if names.get(st.metadata_id) == "tf_op":
-                return st.str_value or names.get(st.ref_value, "")
-        return ""
-
     runs = collections.defaultdict(list)
-    for start, dur, meta in events(lines[trace.MODULES_LINE]):
+    for name, start, dur in ot.trace.device_modules.get(chip, ()):
         if start >= lo and start + dur <= hi:
-            runs[trace.clean_name(meta.name).split("(")[0]].append((dur, start))
-    ops = list(events(lines[trace.OPS_LINE]))
+            runs[name.split("(")[0]].append((dur, start))
+    ops = ot.ops[chip]
     for prog, rs in sorted(runs.items(), key=lambda kv: -sum(d for d, _ in kv[1])):
         dur, start = sorted(rs)[len(rs) // 2]
         print(f"[program] {prog}: {len(rs)} runs in the window, median "
@@ -222,11 +203,11 @@ def by_scope(space, lo: int, hi: int) -> None:
         if dur < 1e6:
             continue
         scopes = collections.defaultdict(collections.Counter)
-        for t0, d, meta in ops:
-            name = trace.base_name(meta.name)
+        for full, t0, d, op in ops:
+            name = trace.base_name(full)
             if t0 < start or t0 + d > start + dur or trace.CONTAINER.match(name):
                 continue
-            op = op_name(meta).split(";")[0]    # a merged op: its first
+            op = op.split(";")[0]    # a merged op: its first
             # inside a scope: by the rest of the op_name; outside: by what
             # the instruction is and what it is for.  A ``copy`` named
             # ``kpool:`` / ``vpool:`` here is a whole K/V pool re-laid-out
@@ -248,18 +229,63 @@ def by_scope(space, lo: int, hi: int) -> None:
                   + ", ".join(f"{n} {x / 1e6:.2f}" for n, x in c.most_common(4)))
 
 
+def train_step(ot, spans) -> None:
+    """``[train]`` and ``[collectives]``: the training step program read
+    by the trainer's phases, and its collectives by mesh axis."""
+    from benchmark.harness import trace_ops
+    dispatch = [ev["args"] for ev in spans if ev["name"] == "step.dispatch"]
+    axes = next((a["collectives"] for a in reversed(dispatch)
+                 if a.get("collectives")), None)
+    split = trace_ops.step_split(ot, collectives=axes)
+    if split is None or not any(split["phase_ms"][p]
+                                for p in trace_ops.PHASES if p):
+        return
+    phases = split["phase_ms"]
+    total = sum(phases.values())
+    med = split["run_ms_median"]
+    print(f"[train] {split['program']}: {split['runs']} runs in the window, "
+          f"median {med:.3f} ms; device ms a step by phase (mean over "
+          f"{split['chips']} chip(s); sum {total:.3f} = "
+          f"{100 * total / med:.2f} % of the median run):")
+    for p in trace_ops.PHASES:
+        v = phases[p]
+        if not v and p in ("grad_reduce", "guard"):
+            continue
+        top = split["nodes"].get(p, {}) if p else split["unowned"]
+        print(f"  {p or 'unowned':12s} {v:9.3f} ms "
+              f"{100 * v / max(total, 1e-9):5.1f} %  "
+              + ", ".join(f"{n} {x:.2f}" for n, x in
+                          collections.Counter(top).most_common(6)))
+    print("[train] the step's per-layer numbers: " + ", ".join(
+        f"{k} {v:.3f}" for k, v in trace_ops.metric_values(split).items()))
+    if axes is None and not split["collective_all_ms"]:
+        return
+    held = collections.defaultdict(lambda: [0, 0])
+    for ax, names in (axes or {}).items():
+        for n, b in names.items():
+            if not trace_ops.trace.base_name(n).endswith("-done"):
+                held[ax][0] += 1
+                held[ax][1] += b
+    by_ax = trace_ops.axis_totals(split)
+    print(f"[collectives] {sum(c for c, _ in held.values())} in the step "
+          f"program; {split['collective_all_ms']:.3f} device ms a step, by "
+          "axis ('?': no axis known) and phase:")
+    for ax in sorted(set(held) | set(by_ax)):
+        print(f"  {ax or '(none)':12s} {held[ax][0]:4d} x "
+              f"{held[ax][1] / 1e6:10.2f} MB {by_ax.get(ax, 0.0):9.3f} ms  "
+              + ", ".join(f"{ph or 'unowned'} {v:.3f}" for (a, ph), v
+                          in sorted(split["collective_ms"].items())
+                          if a == ax))
+
+
 def read(stem: str) -> None:
-    from benchmark.harness import trace
+    from benchmark.harness import trace, trace_ops
 
     with gzip.open(stem + ".xplane.pb.gz", "rb") as f:
-        raw = f.read()
-    path = os.path.join(tempfile.mkdtemp(prefix="mxtpu-xplane-"), "t.xplane.pb")
-    with open(path, "wb") as f:
-        f.write(raw)
-    try:
-        tr = trace.read_xplane(path, region_prefix=REGIONS)
-    finally:
-        shutil.rmtree(os.path.dirname(path), ignore_errors=True)
+        ot = trace_ops.read(f.read(), region_prefix=REGIONS)
+    if ot is None:
+        sys.exit("no xplane_pb2 is installed: the trace cannot be read")
+    tr = ot.trace
     s = trace.summarize(tr)
     idle = s.window_s - s.busy_s
     print(f"[device] window {s.window_s:.4f} s, busy {s.busy_s:.4f} s, idle "
@@ -288,16 +314,15 @@ def read(stem: str) -> None:
           f"{nested}/{len(steps)} serve.step inside a bench.engine_step")
 
     per_step = idle_by_step(tr)
-    space = load_xspace(raw)
-    if space is None:
-        print("[program] no xplane_pb2 found: device ms by scope not read")
-    else:
-        by_scope(space, *trace.window_of(tr))
-
-    # the ring's medians, to lay beside the device's
+    by_scope(ot, *trace.window_of(tr))
+    spans = []
     if os.path.exists(stem + ".spans.json"):
         with open(stem + ".spans.json") as f:
             spans = json.load(f)
+    train_step(ot, spans)
+
+    # the ring's medians, to lay beside the device's
+    if spans:
         durs = collections.defaultdict(list)
         for ev in spans:
             key = ev["name"]
