@@ -584,14 +584,12 @@ def bench_audit(args):
     FC trainer (sgd+momentum), the transformer-LM trainer (adam), and
     the LM with the full guardrail stack — through
     ``mxnet_tpu.analysis.audit_trainer`` and records the per-flat-grad-
-    bucket HBM pass count, once on the fused single-pass update
-    (the default since r8: exactly 1 read / 1 write per bucket) and
-    once with ``fused_update=False`` (the unfused chain this PR
-    retired: 5/5 for sgd+momentum up to 18/17 for adam with the full
-    guardrail stack — every extra count is one more full sweep of the
-    gradient bytes through HBM per step).  The audit must also be
-    CLEAN (zero unsuppressed findings) — a finding here is a real
-    hazard in a shipped step program, and the row goes red.
+    bucket pass count of the update chain in the jaxpr (5/5 for
+    sgd+momentum up to 18/17 for adam with the full guardrail stack;
+    XLA fuses each parameter's chain, so these count jaxpr sweeps, not
+    HBM passes of the compiled step).  The audit must also be CLEAN
+    (zero unsuppressed findings) — a finding here is a real hazard in
+    a shipped step program, and the row goes red.
 
     r9 adds the wire-bytes rows: each config re-traced with
     ``grad_compression`` int8/fp8 (error feedback on, the default) and
@@ -636,39 +634,33 @@ def bench_audit(args):
     rows = []
     for name, make_sym, dshapes, lshapes, kw in configs:
         from mxnet_tpu.parallel import ShardedTrainer, make_mesh
-        for fused in (True, False):
-            mx.random.seed(7)
-            tr = ShardedTrainer(make_sym(),
-                                mesh=make_mesh({"data": len(jax.devices())}),
-                                fused_update=fused, **kw)
-            tr.bind(data_shapes=dshapes, label_shapes=lshapes)
-            t0 = time.perf_counter()
-            report = analysis.audit_trainer(tr, programs=("train",))
-            elapsed = time.perf_counter() - t0
-            hbm = report.metrics.get("trainer.train", {}).get("hbm_passes", {})
-            buckets = hbm.get("buckets", [])
-            label = "fused" if fused else "unfused"
-            passed = bool(report.clean) and (
-                not fused or (hbm.get("max_reads") == 1
-                              and hbm.get("max_writes") == 1))
-            rows.append({
-                "metric": f"grad-bucket HBM passes ({name}, {label}, "
-                          "audited train step)",
-                "value": hbm.get("max_reads"),
-                "unit": "reads/bucket/step",
-                "vs_baseline": None,
-                "writes_per_bucket": hbm.get("max_writes"),
-                "buckets": len(buckets),
-                "bucket_bytes": [b["bytes"] for b in buckets],
-                "fused": fused,
-                "clean": report.clean,
-                "findings": len(report.unsuppressed()),
-                "target": "CLEAN; fused update = 1 read/1 write",
-                "pass": passed,
-                "audit_s": round(elapsed, 2),
-                "n_devices": len(jax.devices()),
-            })
-            _emit_row(rows[-1])
+        mx.random.seed(7)
+        tr = ShardedTrainer(make_sym(),
+                            mesh=make_mesh({"data": len(jax.devices())}),
+                            **kw)
+        tr.bind(data_shapes=dshapes, label_shapes=lshapes)
+        t0 = time.perf_counter()
+        report = analysis.audit_trainer(tr, programs=("train",))
+        elapsed = time.perf_counter() - t0
+        hbm = report.metrics.get("trainer.train", {}).get("hbm_passes", {})
+        buckets = hbm.get("buckets", [])
+        rows.append({
+            "metric": f"grad-bucket jaxpr passes ({name}, audited train "
+                      "step)",
+            "value": hbm.get("max_reads"),
+            "unit": "reads/bucket/step",
+            "vs_baseline": None,
+            "writes_per_bucket": hbm.get("max_writes"),
+            "buckets": len(buckets),
+            "bucket_bytes": [b["bytes"] for b in buckets],
+            "clean": report.clean,
+            "findings": len(report.unsuppressed()),
+            "target": "CLEAN",
+            "pass": bool(report.clean),
+            "audit_s": round(elapsed, 2),
+            "n_devices": len(jax.devices()),
+        })
+        _emit_row(rows[-1])
 
     for name, make_sym, dshapes, lshapes, kw in configs:
         from mxnet_tpu.parallel import ShardedTrainer, make_mesh
@@ -710,18 +702,15 @@ def bench_audit(args):
 
 
 def bench_twin_gap(args):
-    """--twin-gap: the framework-tax referee, post-fused-update.
+    """--twin-gap: the framework-tax referee.
 
     Loads ``tools/resnet_probe.py`` (the committed raw-JAX ResNet-50
     twin from r5) and times it with the SAME N/3N median-slope protocol
     ``measure`` uses, then times the framework ResNet-50 trainer on an
     identical config — batch, image edge, bf16 activation flow with f32
     master params, SGD momentum 0.9, weight decay OFF on both sides
-    (so the twin's plain update matches the framework's math exactly;
-    per-param wd fuses too since r9, via the per-bucket wd segment
-    vector).  The delta between the two slopes IS the
-    framework tax.  r4 measured it at ~14 ms/step with the unfused
-    18-pass update chain; with the fused single-pass kernel the target
+    (so the twin's plain update matches the framework's math exactly).
+    The delta between the two slopes IS the framework tax; the target
     is <2 ms/step on the TPU headline config (``--twin-batch 256
     --twin-image 224 --twin-steps 6``).  The CPU-mesh defaults are tiny
     — there the row demonstrates protocol parity, not headline numbers.
@@ -781,9 +770,6 @@ def bench_twin_gap(args):
                                          "momentum": 0.9, "wd": 0.0})
     tr.bind(data_shapes={"data": (B, 3, E, E)},
             label_shapes={"softmax_label": (B,)})
-    if not tr._fused:
-        raise RuntimeError("twin-gap must measure the FUSED framework "
-                           "path, but this config fell back")
     feeds = [{"data": rng.random((B, 3, E, E)).astype(np.float32),
               "softmax_label":
               rng.integers(0, 1000, (B,)).astype(np.float32)}
@@ -793,16 +779,15 @@ def bench_twin_gap(args):
     gap_ms = (fw_per - twin_per) * 1e3
     row = {
         "metric": f"framework tax vs raw-JAX ResNet-50 twin (batch {B}, "
-                  f"{E}x{E}, fused update, same slope protocol)",
+                  f"{E}x{E}, same slope protocol)",
         "value": round(gap_ms, 2),
         "unit": "ms/step delta",
-        "vs_baseline": "r4: ~14 ms/step with the unfused 18-pass chain",
+        "vs_baseline": "r4: ~14 ms/step",
         "framework_ms_per_step": round(fw_per * 1e3, 2),
         "twin_ms_per_step": round(twin_per * 1e3, 2),
         "dispatch_ms": round(dispatch * 1e3, 2),
         "compile_s": {"framework": round(fw_compile, 1),
                       "twin": round(twin_compile, 1)},
-        "fused": bool(tr._fused),
         "target": "<2 ms/step on the TPU headline config "
                   "(--twin-batch 256 --twin-image 224)",
         "n_devices": len(jax.devices()),
@@ -1974,16 +1959,16 @@ def main():
                     "mesh; target <2%% (docs/resilience.md)")
     ap.add_argument("--audit", action="store_true",
                     help="statically audit the acceptance step programs "
-                    "(mxnet_tpu.analysis), fused AND unfused, plus the "
-                    "quantized-wire configs, and record grad-bucket HBM "
-                    "pass counts + collective wire bytes -> "
+                    "(mxnet_tpu.analysis), plus the quantized-wire "
+                    "configs, and record grad-bucket jaxpr pass counts "
+                    "+ collective wire bytes -> "
                     "BENCH_r09.json (docs/static_analysis.md)")
     ap.add_argument("--twin-gap", action="store_true",
                     help="framework ResNet-50 step vs the raw-JAX "
                     "tools/resnet_probe.py twin under one slope "
-                    "protocol; the delta is the framework tax the "
-                    "fused update closes (target <2 ms/step on the "
-                    "TPU r4 config; see docs/perf.md r8)")
+                    "protocol; the delta is the framework tax (target "
+                    "<2 ms/step on the TPU r4 config; see docs/perf.md "
+                    "r8)")
     ap.add_argument("--twin-batch", type=int, default=8,
                     help="--twin-gap batch size (TPU headline: 256)")
     ap.add_argument("--twin-steps", type=_positive, default=2,

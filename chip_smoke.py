@@ -9,13 +9,12 @@ points a user calls, at the full width of the default suite's LM row
    jax found, the versions, and where the compile cache lives;
 2. **kernels** — each Pallas kernel against the repo's own reference, on
    the chip, at bench shapes: flash attention fwd + grads (seq 2048, d 64,
-   bf16), the fused optimizer update (adam, sgd_momentum; a length that is
-   not a tile multiple), flash-decode over a filled paged pool (f32, fp8);
+   bf16), flash-decode over a filled paged pool (f32, fp8);
 3. **train** — ``models.get_symbol`` -> ``ShardedTrainer(adam, bf16 AMP)``
    -> ``bind`` (seq 2048, batch 8) -> ``compile`` -> ``place_batch`` -> a
    few ``step()``s on one repeated batch: loss finite, starts near ln(V),
-   ends lower; one trace, no AOT fallback, fused update on, and the
-   compiled program holds the flash fwd/dq/dkdv and fused-update kernels;
+   ends lower; one trace, no AOT fallback, and the compiled program
+   holds the flash fwd/dq/dkdv kernels;
 4. **serve** — ``trainer.get_params()`` -> ``serve.Engine`` (``attn_impl``
    left at ``auto`` -> flash) -> ``warmup`` -> a dozen mixed
    greedy/sampled requests through ``run()``, then each one again alone
@@ -57,14 +56,14 @@ FULL = dict(
     steps=4, lr=1e-3,
     block_size=16, num_blocks=2048, max_batch=16, max_seq=2048, chunk=256,
     requests=12, prompt=(64, 512), new_tokens=(32, 64),
-    attn=dict(b=2, h=8, l=2048, d=64), fused_n=1_000_003,
+    attn=dict(b=2, h=8, l=2048, d=64),
     paged=dict(b=16, nblk=128))
 TINY = dict(
     vocab=96, layers=2, d_model=32, heads=4, seq=32, batch=4,
     steps=4, lr=1e-2,
     block_size=4, num_blocks=64, max_batch=4, max_seq=48, chunk=8,
     requests=4, prompt=(3, 16), new_tokens=(4, 8),
-    attn=dict(b=1, h=2, l=64, d=32), fused_n=1231,
+    attn=dict(b=1, h=2, l=64, d=32),
     paged=dict(b=3, nblk=6))
 
 # |first loss - ln(vocab)|: Uniform(0.07) init puts ~N(0, 0.9) logits on
@@ -77,9 +76,6 @@ TOL_FLASH_FWD = 2e-2
 TOL_FLASH_GRAD = 4e-2
 # flash-decode is f32 VPU math end to end; only summation order differs
 TOL_DECODE = 1e-4
-# fused update vs the XLA-compiled jnp reference: same f32 formula, two
-# compilers (Mosaic / XLA) free to contract multiply-adds differently
-TOL_FUSED_ULP = 16
 # multi-chip loss vs the one-chip trajectory, per step (bf16 compute, a
 # different reduction order, adam amplifying it over a few steps)
 TOL_MESH_LOSS = 0.05
@@ -133,16 +129,6 @@ def nerr(x, ref) -> float:
     ref = np.asarray(ref, np.float32)
     check(np.isfinite(x).all(), "non-finite values in a kernel output")
     return float(np.max(np.abs(x - ref)) / (np.max(np.abs(ref)) + 1e-30))
-
-
-def ulp_diff(a, b) -> int:
-    """Largest units-in-the-last-place distance between two f32 arrays."""
-    import numpy as np
-
-    def key(x):
-        i = np.asarray(x, np.float32).view(np.int32).astype(np.int64)
-        return np.where(i < 0, np.int64(-2**31) - i - 1, i)
-    return int(np.abs(key(a) - key(b)).max())
 
 
 # ---------------------------------------------------------------------------
@@ -223,7 +209,6 @@ def phase_kernels(cfg, interpret: bool) -> None:
     import numpy as np
 
     from mxnet_tpu import quant
-    from mxnet_tpu.ops import fused_update as fu
     from mxnet_tpu.parallel.flash_attention import flash_attention
     from mxnet_tpu.parallel.ring_attention import (blockwise_attention,
                                                    local_attention)
@@ -260,30 +245,6 @@ def phase_kernels(cfg, interpret: bool) -> None:
         say(f"[kernels] flash_attention {name}: kernel err {e_k:.2e}, "
             f"jnp blockwise path err {e_s:.2e} (vs f32 dense, tol {tol})")
         check(e_k <= tol, f"flash_attention {name} err {e_k} > {tol}")
-
-    # -- fused optimizer update -----------------------------------------
-    n = cfg["fused_n"]                  # not a multiple of 8 * 128
-    g, wt = (jnp.asarray(rng.randn(n), jnp.float32) for _ in range(2))
-    s1 = jnp.asarray(rng.randn(n) * 1e-2, jnp.float32)
-    s2 = jnp.asarray(np.abs(rng.randn(n)) * 1e-3, jnp.float32)
-    cases = [
-        ("adam", (s1, s2), dict(beta1=0.9, beta2=0.999, epsilon=1e-8,
-                                rescale_grad=0.125)),
-        ("sgd_momentum", (s1,), dict(momentum=0.9, wd=1e-4,
-                                     rescale_grad=0.125)),
-    ]
-    for kind, state, hyper in cases:
-        scalars = (np.float32(1e-3),)
-        ref = jax.jit(lambda g, w, s: fu.reference_update(
-            g, w, s, scalars, kind=kind, **hyper))(g, wt, state)
-        pal = jax.jit(lambda g, w, s: fu.pallas_update(
-            g, w, s, scalars, kind=kind, interpret=interpret,
-            **hyper))(g, wt, state)
-        ulps = [ulp_diff(r, p) for r, p in zip(ref, pal)]
-        say(f"[kernels] fused_update {kind} n={n}: max ulp distance to "
-            f"reference_update {ulps} (w, *state; tol {TOL_FUSED_ULP})")
-        check(max(ulps) <= TOL_FUSED_ULP,
-              f"fused_update {kind}: {ulps} ulp > {TOL_FUSED_ULP}")
 
     # -- flash-decode over a filled paged pool ---------------------------
     p = cfg["paged"]
@@ -379,9 +340,6 @@ def train_lm(cfg, mesh, rules=None, on_chip=True):
           tr.aot_stats["hits"] == cfg["steps"],
           f"steps did not all run the AOT program: {tr.aot_stats}")
 
-    tp = rules is not None
-    check(tr._fused is not tp,
-          f"fused update is {tr._fused} with tensor parallelism {tp}")
     if on_chip:
         got = pallas_kernels(tr._aot["train"])
         say(f"[train] mesh {dict(mesh.shape)}: Mosaic kernels in the "
@@ -389,8 +347,6 @@ def train_lm(cfg, mesh, rules=None, on_chip=True):
         want = {"mxtpu_flash_fwd": cfg["layers"],
                 "mxtpu_flash_dq": cfg["layers"],
                 "mxtpu_flash_dkdv": cfg["layers"]}
-        if tr._fused:
-            want["mxtpu_fused_update"] = 1
         for name, least in want.items():
             check(got[name] >= least,
                   f"compiled train step holds {got[name]} x {name}, "

@@ -46,10 +46,6 @@ RULES: Dict[str, Tuple[str, str]] = {
         "error", "a carried value changes sharding across the step, or a "
         "scalar carry is not fully replicated — every call regathers or "
         "re-traces"),
-    "program.fused-update": (
-        "error", "a fused-update program breaks the single-pass HBM "
-        "contract: a grad bucket is traversed more than once "
-        "(reads/writes > 1) or the fused primitive/tags are missing"),
     "program.hbm-bytes": (
         "error", "a quantized-collective program breaks the wire-bytes "
         "contract: a bucket-scale floating reduce collective puts a "

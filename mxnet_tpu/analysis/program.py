@@ -19,8 +19,8 @@ Rules (catalogue + worked examples in docs/static_analysis.md):
 plus the **HBM-pass metric**: gradients are tagged in the trainer's step
 with the identity primitive ``mxtpu_tag`` (zero HLO footprint), and the
 auditor counts how many program eqns traverse each gradient buffer on the
-update path, aggregated onto the flat comm buckets — the measuring stick
-for ROADMAP item 4's single-pass fused update (target: 1 read / 1 write).
+update path, aggregated onto the flat comm buckets (how many times the
+update chain sweeps each gradient before XLA fuses it).
 
 And the **HBM-bytes metric** (``program.hbm-bytes``): every reduce
 collective (``psum``/``psum_invariant``) gets a dtype-width-weighted wire-bytes
@@ -111,14 +111,14 @@ FREE_PASS_PRIMS = frozenset({
     "mxtpu_tag",
 })
 
-#: opaque fused-kernel calls that stream each operand through VMEM once:
-#: counted as exactly 1 read + 1 write for every tagged operand they
-#: consume, with NO propagation to their outputs (the outputs are the
-#: updated weight/state buffers, not more traffic over the gradient).
-#: This is how the counter sees through ``pallas_call`` and the fused
-#: update primitive instead of miscounting them as ordinary eqns.
+#: opaque fused calls that read each operand from HBM once (a Pallas
+#: kernel): counted as exactly 1 read + 1 write for every tagged operand
+#: they consume, with NO propagation to their outputs (the outputs are
+#: new buffers, not more traffic over the gradient).  This is how the
+#: counter sees through ``pallas_call`` instead of miscounting it as an
+#: ordinary eqn.
 STREAM_ONCE_PRIMS = frozenset({
-    "pallas_call", "mxtpu_fused_update",
+    "pallas_call",
 })
 
 #: reduce collectives whose operands cross the interconnect (``psum`` at
@@ -461,8 +461,9 @@ def update_passes(closed, config: Optional[AuditConfig] = None
     ``reads`` counts non-layout eqns consuming the gradient or a
     same-shape value derived from it (the clip multiply, the optimizer
     step, the non-finite gate...); ``writes`` counts the same-shape
-    buffers those eqns produce.  A single-pass fused update reads 1 /
-    writes 1; every extra count is one more full bucket through HBM.
+    buffers those eqns produce.  One streaming pass reads 1 / writes 1;
+    every extra count is one more sweep of the jaxpr over the gradient
+    (XLA may fuse them into one pass).
     """
     config = config or AuditConfig()
     free = config.free_pass_prims
@@ -546,51 +547,6 @@ def bucket_passes(per_param: Dict[str, Dict[str, int]],
                 "writes": max(wrs) if wrs else 0,
             })
     return out
-
-
-def _fused_bucket_passes(per_label: Dict[str, Dict[str, int]],
-                         plan) -> List[Dict[str, Any]]:
-    """Bucket rows for a fused-update program: the trainer tags each flat
-    bucket ``gradbucket:<i>`` directly, so counts map 1:1 onto the
-    :class:`~mxnet_tpu.ops.fused_update.FusedPlan` buckets — no
-    per-param aggregation needed."""
-    out: List[Dict[str, Any]] = []
-    for i, segs in enumerate(plan.buckets):
-        c = per_label.get(f"gradbucket:{i}", {"reads": 0, "writes": 0})
-        out.append({
-            "index": i,
-            "dtype": "float32",
-            "bytes": sum(s1 - s0 for _, s0, s1 in segs) * 4,
-            "params": sorted({n for n, _, _ in segs}),
-            "reads": c["reads"],
-            "writes": c["writes"],
-        })
-    return out
-
-
-def _check_fused_update(per: Dict[str, Dict[str, int]], program: str,
-                        report: Report) -> None:
-    """The ``program.fused-update`` rule: a program audited with
-    ``expect_fused`` must tag its buckets and traverse each exactly
-    once (1 read / 1 write — the single-pass HBM contract)."""
-    labels = [l for l in per if l.startswith("gradbucket:")]
-    if not labels:
-        report.add(Finding(
-            "program.fused-update",
-            "expect_fused was set but no `gradbucket:<i>` tags exist in "
-            "the program — the fused update path is not in the trace",
-            program=program))
-        return
-    for l in sorted(labels):
-        c = per[l]
-        if c["reads"] > 1 or c["writes"] > 1:
-            report.add(Finding(
-                "program.fused-update",
-                f"fused bucket `{l}` is traversed {c['reads']} reads / "
-                f"{c['writes']} writes — the single-pass contract is "
-                "1R/1W, so an op outside the fused primitive is touching "
-                "the bucket",
-                program=program, details={"label": l, **c}))
 
 
 # ----------------------------------------------------------------------
@@ -788,7 +744,6 @@ def audit_traced(traced, program: str,
                  never_donate: Optional[Dict[int, str]] = None,
                  carry_pairs: Optional[Sequence[Tuple[int, int, str]]] = None,
                  replicated_out: Optional[Sequence[Tuple[int, str]]] = None,
-                 expect_fused: bool = False,
                  expect_wire_itemsize: Optional[int] = None,
                  expect_kv_itemsize: Optional[int] = None,
                  config: Optional[AuditConfig] = None,
@@ -801,9 +756,6 @@ def audit_traced(traced, program: str,
     ``carry_pairs``: ``(in_flat_idx, out_flat_idx, name)`` carried state.
     ``replicated_out``: ``(out_flat_idx, name)`` scalar carries that must
     be fully replicated.
-    ``expect_fused``: assert the single-pass fused-update contract — the
-    program must contain ``gradbucket:<i>`` tags and traverse each
-    exactly once (``program.fused-update`` findings otherwise).
     ``expect_wire_itemsize``: assert the quantized-collective contract —
     every bucket-scale floating reduce collective must put at most this
     many bytes/elem on the wire (``program.hbm-bytes`` findings
@@ -849,8 +801,6 @@ def audit_traced(traced, program: str,
         per = update_passes(closed, config)
         if per:
             metrics["hbm_passes"] = {"per_grad": per}
-        if expect_fused:
-            _check_fused_update(per, program, report)
         rows = collective_wire_rows(closed, config)
         if rows:
             frows = [r for r in rows if r["float_payload"]]
@@ -940,8 +890,6 @@ def audit_trainer(trainer, programs: Sequence[str] = ("train", "train_acc"),
                     carry_pairs.append(
                         (gs_in + j, out_after_heads + j, gnames[j]))
                     replicated_out.append((out_after_heads + j, gnames[j]))
-        fused_plan = (trainer._fused_plan
-                      if getattr(trainer, "_fused", False) else None)
         expect_wire = None
         if kind in ("train", "train_acc") and \
                 getattr(trainer, "grad_compression", None) is not None:
@@ -950,20 +898,15 @@ def audit_trainer(trainer, programs: Sequence[str] = ("train", "train_acc"),
         audit_traced(
             traced, label, donate_flat=donate_flat,
             carry_pairs=carry_pairs, replicated_out=replicated_out,
-            expect_fused=(fused_plan is not None
-                          and kind in ("train", "train_acc")),
             expect_wire_itemsize=expect_wire,
             config=config, report=report)
         if config.count_hbm and kind in ("train", "train_acc"):
             per = report.metrics[label].get(
                 "hbm_passes", {}).get("per_grad")
             if per:
-                if fused_plan is not None:
-                    buckets = _fused_bucket_passes(per, fused_plan)
-                else:
-                    buckets = bucket_passes(
-                        per, trainer._params, trainer._param_names,
-                        trainer.grad_bucket_bytes)
+                buckets = bucket_passes(
+                    per, trainer._params, trainer._param_names,
+                    trainer.grad_bucket_bytes)
                 hbm = report.metrics[label]["hbm_passes"]
                 hbm["buckets"] = buckets
                 hbm["max_reads"] = max(

@@ -153,7 +153,6 @@ class ShardedTrainer:
                  grad_compression: Optional[str] = None,
                  grad_bucket_bytes: Optional[int] = None,
                  error_feedback: Optional[bool] = None,
-                 fused_update: Optional[bool] = None,
                  guard: Optional[bool] = None,
                  clip_global_norm: Optional[float] = None,
                  loss_scale=None,
@@ -254,18 +253,11 @@ class ShardedTrainer:
                 error_feedback = False
             self.error_feedback = bool(error_feedback)
         self._ef_keys: List[str] = []
-        # single-pass fused optimizer update (ops/fused_update.py): one
-        # primitive per flat grad bucket replaces the unfused jnp chain
-        # (loss-scale unscale x clip x guard gating x optimizer step),
-        # with optimizer state laid out bucket-aligned so grads, weights
-        # and moments stream through VMEM in lockstep.  None = auto (on
-        # for eligible configs; MXNET_TPU_FUSED_UPDATE=0 opts out);
-        # True raises at bind() if the config cannot fuse; False forces
-        # the unfused path.
-        self._fused_req = fused_update
+        # the optimizer update has one path: each parameter's jnp chain in
+        # its own shape, which XLA fuses in the layout the parameter lives
+        # in.  ``_fused`` stays False for readers that report it
+        # (benchmark/runners/train_sharded.py)
         self._fused = False
-        self._fused_kind: Optional[str] = None
-        self._fused_plan = None
         # step-level anomaly defense (resilience.py): a fused non-finite
         # guard gates the whole param/opt-state update with jnp.where (a
         # bad step leaves state bitwise-unchanged), dynamic loss scaling
@@ -480,45 +472,15 @@ class ShardedTrainer:
                 self._wd_mult[n] = 0.0
             else:
                 self._wd_mult[n] = 1.0
-        self._setup_fused(shape_of, params)
         opt_state = {}
-        if self._fused:
-            # bucket-aligned optimizer state: moments live as replicated
-            # flat f32 buffers in the SAME streaming order as the reduced
-            # grad buckets, keyed "fused:<i>" (checkpoints namespace them
-            # opt:fused:<i>:<leaf> like any other opt-state entry)
-            rep = replicated(self.mesh)
-            for i, blen in enumerate(self._fused_plan.bucket_sizes):
-                opt_state[f"fused:{i}"] = jax.tree.map(
-                    lambda z: self._global_put(z, rep),
-                    opt.state_zeros_like(jnp.zeros((blen,), jnp.float32)))
-        else:
-            for n in self._param_names:
-                flat_len = self._zero_flat[n]
-                template = (jnp.zeros((flat_len,), params[n].dtype)
-                            if flat_len is not None else params[n])
-                opt_state[n] = jax.tree.map(
-                    lambda z, _n=n: self._global_put(
-                        z, NamedSharding(self.mesh, self._zero_specs[_n])),
-                    opt.state_zeros_like(template))
-        if self._fused and not self._fused_wd_uniform:
-            # per-bucket wd segment vectors (satellite of ROADMAP item
-            # 4): each element holds its param's effective wd, laid out
-            # in bucket order, so the kernel's wd multiply stays one
-            # elementwise op.  Static config, not training state — they
-            # ride opt_state for donation/placement but are excluded
-            # from checkpoints (_state_arrays) so a restore never
-            # resurrects a stale wd schedule.
-            rep = replicated(self.mesh)
-            for i, bucket in enumerate(self._fused_plan.buckets):
-                vec = np.empty(sum(s1 - s0 for _, s0, s1 in bucket),
-                               np.float32)
-                off = 0
-                for n, s0, s1 in bucket:
-                    vec[off:off + (s1 - s0)] = np.float32(
-                        opt.wd * self._wd_mult[n])
-                    off += s1 - s0
-                opt_state[f"fusedwd:{i}"] = self._global_put(vec, rep)
+        for n in self._param_names:
+            flat_len = self._zero_flat[n]
+            template = (jnp.zeros((flat_len,), params[n].dtype)
+                        if flat_len is not None else params[n])
+            opt_state[n] = jax.tree.map(
+                lambda z, _n=n: self._global_put(
+                    z, NamedSharding(self.mesh, self._zero_specs[_n])),
+                opt.state_zeros_like(template))
         self._ef_keys = []
         if self.error_feedback:
             # one persistent f32 residual per grad bucket, sharded over
@@ -585,69 +547,6 @@ class ShardedTrainer:
         padded = -(-numel // n) * n  # ceil to a multiple of the data axis
         return P(self.data_axis), padded
 
-    def _setup_fused(self, shape_of, params) -> None:
-        """Decide whether this bind runs the single-pass fused update
-        (ops/fused_update.py) and build the bucket plan if so.  The gate
-        is conservative: any configuration the kernel cannot express
-        bitwise (per-param multipliers, sharded state, non-f32 masters)
-        silently falls back to the unfused path — unless the user forced
-        ``fused_update=True``, which makes ineligibility an error."""
-        from ..ops import fused_update as fu
-        self._fused = False
-        self._fused_kind = None
-        self._fused_plan = None
-        req = self._fused_req
-        if req is False or (req is None and not fu.fused_enabled()):
-            return
-        kind = fu.fused_kind(self.optimizer)
-        why = []
-        if not self._param_names:
-            why.append("no parameters")
-        if kind is None:
-            why.append(f"optimizer {type(self.optimizer).__name__} has "
-                       "no fused twin")
-        if self.shard_optimizer:
-            why.append("shard_optimizer (ZeRO state layout)")
-        if any(ax is not None for n in self._param_names
-               for ax in self.rules.spec_for(n)):
-            why.append("tensor-parallel param sharding")
-        if any(params[n].dtype != jnp.float32 for n in self._param_names):
-            why.append("non-f32 master params")
-        if any(int(np.prod(shape_of[n], dtype=np.int64)) == 0
-               for n in self._param_names):
-            why.append("zero-size params")
-        if len({float(v) for v in self._lr_mult.values()}) > 1:
-            why.append("per-param lr_mult")
-        # per-param effective wd (gamma/beta/bias exclusion) is fused-
-        # eligible: a non-uniform layout rides a per-bucket wd segment
-        # vector operand into the kernel (opt_state "fusedwd:<i>")
-        self._fused_wd_uniform = len(
-            {float(self.optimizer.wd * v)
-             for v in self._wd_mult.values()}) <= 1
-        if kind == "adam" and any(
-                float(self.optimizer.wd * v) != 0.0
-                for v in self._wd_mult.values()):
-            # adam FOLDS wd into the gradient (g + wd*w) and that fold
-            # feeds both moments; LLVM's FMA contraction of it is
-            # context-dependent, so the fused twin is 1 ulp off the
-            # inline unfused step — no bitwise twin exists.  (adamw's
-            # DECOUPLED wd never touches the grad and stays bitwise;
-            # sgd's fold has a single consumer and contracts the same
-            # way in both contexts.)
-            why.append("adam with weight decay (folded wd has no "
-                       "bitwise fused twin; use adamw)")
-        if why:
-            if req:
-                raise MXNetError("fused_update=True but this "
-                                 "configuration cannot fuse: "
-                                 + "; ".join(why))
-            self.logger.debug("fused update off: %s", "; ".join(why))
-            return
-        self._fused_kind = kind
-        self._fused_plan = fu.build_plan(self._param_names, shape_of,
-                                         self.grad_bucket_bytes)
-        self._fused = True
-
     def _zero_spec(self, name: str, shape: Tuple[int, ...]) -> P:
         return self._zero_plan(name, shape)[0]
 
@@ -684,7 +583,7 @@ class ShardedTrainer:
         return lens
 
     def _explicit_comm_grads(self, base, resil: bool = False,
-                             bucket_out: bool = False, ef: bool = False):
+                             ef: bool = False):
         """Wrap the grad computation in a manual shard_map region over the
         data axis: per-shard backward, then explicit bucketed (and
         optionally quantized) psums of the gradients — the comm path this
@@ -706,12 +605,6 @@ class ShardedTrainer:
         reduction per bucket and NO extra pass over the per-tensor grads.
         The body then returns it as a fourth (replicated) output.
 
-        With ``bucket_out`` (the fused-update path) the reduced flat
-        buckets are returned AS-IS — a list in plan order — instead of
-        being scattered back to per-tensor grads: the fused kernel
-        consumes them directly, so the scatter pass (one extra
-        read+write of every bucket) disappears entirely.
-
         With ``ef`` the body additionally takes the list of per-shard
         error-feedback residuals (one flat f32 per bucket, in dispatch
         order) and returns the updated residuals as its last output:
@@ -731,7 +624,6 @@ class ShardedTrainer:
             for n in order:
                 by_dtype.setdefault(jnp.dtype(grads[n].dtype), []).append(n)
             out = dict(grads)
-            flat_buckets: List[jax.Array] = []
             new_ef: List[jax.Array] = []
             bidx = 0
             sq = jnp.float32(0.0)
@@ -759,22 +651,15 @@ class ShardedTrainer:
                         # fused guard stat on the reduced flat bucket
                         sq = sq + jnp.sum(jnp.square(
                             red.astype(jnp.float32)))
-                    if bucket_out:
-                        # fused update consumes the flat bucket directly
-                        flat_buckets.append(red)
-                        continue
                     off = 0
                     for pi, s0, s1 in bucket:
                         pieces[names[pi]].append(red[off:off + (s1 - s0)])
                         off += s1 - s0
-                if bucket_out:
-                    continue
                 for n in names:
                     ps = pieces[n]
                     flat = ps[0] if len(ps) == 1 else jnp.concatenate(ps)
                     out[n] = flat.reshape(grads[n].shape)
-            res = (flat_buckets, sq) if bucket_out else (out, sq)
-            return res + ((new_ef,) if ef_res is not None else ())
+            return (out, sq) + ((new_ef,) if ef_res is not None else ())
 
         # the residual lists ride in/out as pytrees; P(data_axis) as a
         # pytree-prefix spec shards every flat residual over data — each
@@ -825,26 +710,6 @@ class ShardedTrainer:
         lr_mult, wd_mult = dict(self._lr_mult), dict(self._wd_mult)
         base_wd = opt.wd
         needs_rng = type(opt)._needs_rng
-
-        fused = self._fused
-        if fused:
-            from ..analysis.program import tag as _tag_val
-            from ..ops import fused_update as _fu
-            fused_plan = self._fused_plan
-            fused_kind = self._fused_kind
-            n_buckets = len(fused_plan.buckets)
-            # the gate proved lr_mult uniform across params; wd is either
-            # uniform (scalar into the kernel) or rides the per-bucket
-            # "fusedwd:<i>" segment vectors built at bind
-            lr_common = float(next(iter(lr_mult.values())))
-            wd_uniform = self._fused_wd_uniform
-            wd_common = (float(base_wd * next(iter(wd_mult.values())))
-                         if wd_uniform else 0.0)
-            f_momentum = float(getattr(opt, "momentum", 0.0) or 0.0)
-            f_b1 = float(getattr(opt, "beta1", 0.0) or 0.0)
-            f_b2 = float(getattr(opt, "beta2", 0.0) or 0.0)
-            f_eps = float(getattr(opt, "epsilon", 0.0) or 0.0)
-            f_clip = hyper.get("clip_gradient")
 
         # per-step RNG keys fold from the update counter INSIDE the
         # program (no per-step host->device key transfer to wait on
@@ -910,68 +775,13 @@ class ShardedTrainer:
 
         explicit = (self.grad_compression is not None
                     and self.data_axis is not None)
-        # zero-copy handoff: on the explicit-comm path (accum == 1) the
-        # reduced flat buckets skip the scatter-back entirely and feed
-        # the fused kernel as-is; under accum > 1 grads must still sum
-        # per-tensor across the scan, so the fused path gathers them
-        explicit_fused = explicit and fused and accum == 1
         ef = bool(self.error_feedback and explicit and accum == 1)
         ef_keys = list(self._ef_keys) if ef else []
         if explicit:
             _grads_and_heads = self._explicit_comm_grads(
-                _grads_and_heads, resil=resil is not None,
-                bucket_out=explicit_fused, ef=ef)
+                _grads_and_heads, resil=resil is not None, ef=ef)
 
-        if fused:
-            def _fused_apply(params, grads, opt_state, lr, t, mult, ok):
-                """One fused primitive per bucket.  ``grads`` is either
-                the per-param dict (gathered into plan order here) or,
-                on the explicit-comm path, the already-reduced flat
-                buckets.  The scalar chain below mirrors the unfused
-                ``_functional_step`` op-for-op so parity is bitwise."""
-                lr_eff = lr * lr_common
-                if fused_kind in ("sgd", "sgd_momentum"):
-                    scalars = (lr_eff,)
-                else:
-                    # Adam/AdamW bias correction, exactly as in
-                    # optimizer.py (t cast to the f32 weight dtype)
-                    tf = jnp.asarray(t, dtype=jnp.float32)
-                    lr_t = (lr_eff * jnp.sqrt(1.0 - f_b2 ** tf)
-                            / (1.0 - f_b1 ** tf))
-                    # with a wd segment vector the kernel forms lrwd =
-                    # lr_eff * wdvec elementwise; the scalar stays lr_eff
-                    scalars = ((lr_t,) if fused_kind == "adam"
-                               else (lr_t, lr_eff * wd_common)
-                               if wd_uniform else (lr_t, lr_eff))
-                if isinstance(grads, dict):
-                    buckets = [fused_plan.gather(grads, i)
-                               for i in range(n_buckets)]
-                else:
-                    buckets = grads
-                new_w_buckets = []
-                new_opt = {}
-                for i, g in enumerate(buckets):
-                    w = fused_plan.gather(params, i)
-                    # auditor anchor: everything after this tag must be
-                    # the ONE fused eqn (program.fused-update rule)
-                    g = _tag_val(g, label=f"gradbucket:{i}")
-                    leaves, treedef = jax.tree_util.tree_flatten(
-                        opt_state[f"fused:{i}"])
-                    res = _fu.fused_update(
-                        g, w, tuple(leaves), scalars, kind=fused_kind,
-                        mult=mult, ok=ok, momentum=f_momentum,
-                        beta1=f_b1, beta2=f_b2, epsilon=f_eps,
-                        wd=wd_common, rescale_grad=self._rescale_grad,
-                        clip_gradient=f_clip,
-                        wd_vec=(None if wd_uniform
-                                else opt_state[f"fusedwd:{i}"]),
-                        mesh=self.mesh)
-                    new_w_buckets.append(res[0])
-                    new_opt[f"fused:{i}"] = jax.tree_util.tree_unflatten(
-                        treedef, list(res[1:]))
-                return fused_plan.scatter(new_w_buckets), new_opt
-
-        def _unfused_apply(params, grads, opt_state, lr, t, rng, ok):
+        def _apply_update(params, grads, opt_state, lr, t, rng, ok):
             new_params, new_opt = {}, {}
             for i, n in enumerate(param_names):
                 prng = jax.random.fold_in(rng, i) if needs_rng else None
@@ -1066,11 +876,8 @@ class ShardedTrainer:
 
             # identity-tag the grads for the static auditor's HBM-pass
             # counter: mxtpu_tag lowers to nothing, so HLO, executables
-            # and compile-cache keys are unchanged (analysis/program.py).
-            # The fused path tags its flat buckets (gradbucket:<i>)
-            # inside _fused_apply instead.
-            if not fused:
-                grads = _mark_grads(grads)
+            # and compile-cache keys are unchanged (analysis/program.py)
+            grads = _mark_grads(grads)
 
             ok = None
             mult = None
@@ -1094,27 +901,20 @@ class ShardedTrainer:
                             jnp.float32(resil.clip_global_norm)
                             / jnp.maximum(eff_norm, jnp.float32(1e-12)))
                         mult = coef if mult is None else mult * coef
-                    if mult is not None and not fused:
+                    if mult is not None:
                         # ONE combined multiplier (unscale x clip) applied
                         # once; with neither feature on, no multiply at
                         # all — a guard-on clean run stays bitwise
                         # identical to guard-off (pinned by
-                        # tests/test_resilience.py).  On the fused path
-                        # mult rides INTO the kernel.
+                        # tests/test_resilience.py)
                         grads = {n: g * mult.astype(g.dtype)
                                  for n, g in grads.items()}
             with jax.named_scope("update"):
-                if fused:
-                    # single streaming pass per bucket: combined
-                    # multiplier, guard verdict and the whole optimizer
-                    # update ride ONE primitive (ops/fused_update.py); the
-                    # where-gating lives inside it, so a bad step stays a
-                    # bitwise no-op
-                    new_params, new_opt = _fused_apply(
-                        params, grads, opt_state, lr, t, mult, ok)
-                else:
-                    new_params, new_opt = _unfused_apply(
-                        params, grads, opt_state, lr, t, rng, ok)
+                # each parameter in its own shape: XLA fuses the chain
+                # into the backward's fusions or one loop fusion of its
+                # own, in the layout the parameter lives in (no flatten)
+                new_params, new_opt = _apply_update(
+                    params, grads, opt_state, lr, t, rng, ok)
                 if ef:
                     # a bad step keeps the OLD residual: the new one was
                     # computed from non-finite grads and would poison
@@ -1122,11 +922,6 @@ class ShardedTrainer:
                     for k, nres in zip(ef_keys, new_ef):
                         new_opt[k] = (jnp.where(ok, nres, opt_state[k])
                                       if ok is not None else nres)
-                for k in opt_state:
-                    # static opt-state riders (wd segment vectors) pass
-                    # through unchanged — identity keeps donation aliasing
-                    if k not in new_opt:
-                        new_opt[k] = opt_state[k]
             new_aux = dict(aux)
             if resil is not None:
                 with jax.named_scope("guard"):
@@ -1165,9 +960,8 @@ class ShardedTrainer:
         p_shard = {n: NamedSharding(self.mesh, self.rules.spec_for(n))
                    for n in param_names}
         a_shard = {n: replicated(self.mesh) for n in self._aux_names}
-        # opt state keys are param names on the unfused path, "fused:<i>"
-        # bucket keys on the fused path (always replicated there);
-        # error-feedback residuals are per-shard, pinned to P(data)
+        # opt state keys are param names; error-feedback residuals are
+        # per-shard, pinned to P(data)
         def _opt_spec(k):
             if k.startswith("efres:"):
                 return P(self.data_axis)
@@ -1269,9 +1063,6 @@ class ShardedTrainer:
             "grad_bucket_bytes": self.grad_bucket_bytes,
             "error_feedback": self.error_feedback,
             "quant_block": _quant_block_key(self.grad_compression),
-            "fused": self._fused_kind if self._fused else None,
-            "fused_wd_vec": bool(self._fused
-                                 and not self._fused_wd_uniform),
             "data_axis": self.data_axis,
             "rules": sorted((n, str(self.rules.spec_for(n)))
                             for n in self._param_names),
@@ -1682,23 +1473,60 @@ class ShardedTrainer:
     def _state_arrays(self) -> Dict[str, jax.Array]:
         """Flat ``{name: array}`` view of the full trainer state.  Names
         are namespaced (``param:``/``aux:``/``opt:<key>:<leaf>`` where
-        ``<key>`` is a param name or a fused bucket ``fused:<i>``) so one
-        checkpoint dict round-trips through CheckpointManager and the
-        optimizer pytree re-assembles leaf-by-leaf on restore."""
+        ``<key>`` is a param name or an error-feedback residual
+        ``efres:<i>``) so one checkpoint dict round-trips through
+        CheckpointManager and the optimizer pytree re-assembles
+        leaf-by-leaf on restore."""
         if not self._bound:
             raise MXNetError("call bind() before save_state/restore_state")
         arrays = {f"param:{n}": self._params[n] for n in self._param_names}
         arrays.update({f"aux:{n}": self._aux[n] for n in self._aux_names})
         for key in self._opt_state:
-            if key.startswith("fusedwd:"):
-                # wd segment vectors are bind-time config, not training
-                # state: a restore must use THIS run's wd schedule, not
-                # resurrect the saving run's
-                continue
             for i, leaf in enumerate(
                     jax.tree_util.tree_leaves(self._opt_state[key])):
                 arrays[f"opt:{key}:{i}"] = leaf
         return arrays
+
+    def _unbucket_opt_state(self, arrays) -> Dict[str, jax.Array]:
+        """Per-parameter optimizer state from a checkpoint written while
+        the fused update kept it in flat f32 buckets
+        (``opt:fused:<i>:<leaf>``).  Those buckets were the parameters in
+        reversed order, cut greedily at ``grad_bucket_bytes``
+        (``collectives.plan_buckets``), so the layout is rebuilt from the
+        names and shapes and each leaf sliced back into its parameters'
+        state, placed as this trainer holds it."""
+        from .collectives import plan_buckets
+        order = [n for n in reversed(self._param_names)
+                 if self._params[n].size > 0]
+        buckets = plan_buckets([self._params[n].size for n in order], 4,
+                               self.grad_bucket_bytes)
+        n_leaves = jax.tree_util.tree_structure(
+            self._opt_state[order[0]]).num_leaves
+        pieces: Dict[str, List[List[np.ndarray]]] = {
+            n: [[] for _ in range(n_leaves)] for n in order}
+        for i, bucket in enumerate(buckets):
+            for li in range(n_leaves):
+                flat = np.asarray(arrays[f"opt:fused:{i}:{li}"]).reshape(-1)
+                if flat.size != sum(s1 - s0 for _, s0, s1 in bucket):
+                    raise MXNetError(
+                        f"restore: bucket {i} of the checkpoint's fused "
+                        f"optimizer state holds {flat.size} values, not "
+                        "this trainer's layout (parameters or "
+                        "grad_bucket_bytes differ)")
+                off = 0
+                for idx, s0, s1 in bucket:
+                    pieces[order[idx]][li].append(flat[off:off + s1 - s0])
+                    off += s1 - s0
+        out = {}
+        for n in order:
+            leaves = jax.tree_util.tree_leaves(self._opt_state[n])
+            for li, leaf in enumerate(leaves):
+                val = np.concatenate(pieces[n][li])
+                # a ZeRO flatten-and-pad leaf is longer: its tail is zero
+                val = np.pad(val, (0, leaf.size - val.size))
+                out[f"opt:{n}:{li}"] = self._global_put(
+                    val.reshape(leaf.shape), leaf.sharding)
+        return out
 
     def _state_meta(self, extra_meta=None) -> Dict[str, Any]:
         meta = {"state": "sharded_trainer",
@@ -1764,25 +1592,29 @@ class ShardedTrainer:
                 step=step, shardings=shardings, target_shapes=target_shapes,
                 names=names)
         except MXNetError as e:
-            if "efres" not in str(e):
+            if "has no array" not in str(e):
                 raise
-            # checkpoint predates error feedback: restore everything
-            # else and keep the bind-time zero residuals (worst case one
-            # step's sub-quantum correction is lost)
-            names = [n for n in names if not n.startswith("opt:efres:")]
+            # an older checkpoint: read all it holds (what this trainer
+            # names comes back placed) and make up what it lacks
             arrays, meta, step = manager.restore(
-                step=step, shardings=shardings, target_shapes=target_shapes,
-                names=names)
-            self.logger.warning(
-                "restore_state: checkpoint has no error-feedback "
-                "residuals; starting them at zero")
+                step=step, shardings=shardings, target_shapes=target_shapes)
+            if any(k.startswith("opt:fused:") for k in arrays):
+                arrays.update(self._unbucket_opt_state(arrays))
+            missing = [n for n in names if n not in arrays]
+            if any(not n.startswith("opt:efres:") for n in missing):
+                raise
+            if missing:
+                # checkpoint predates error feedback: keep the bind-time
+                # zero residuals (worst case one step's sub-quantum
+                # correction is lost)
+                self.logger.warning(
+                    "restore_state: checkpoint has no error-feedback "
+                    "residuals; starting them at zero")
         for n in self._param_names:
             self._params[n] = arrays[f"param:{n}"]
         for n in self._aux_names:
             self._aux[n] = arrays[f"aux:{n}"]
         for key in list(self._opt_state):
-            if key.startswith("fusedwd:"):
-                continue  # bind-time config, never checkpointed
             treedef = jax.tree_util.tree_structure(self._opt_state[key])
             if any(f"opt:{key}:{i}" not in arrays
                    for i in range(treedef.num_leaves)):

@@ -23,7 +23,7 @@ Because scales ride the *non-contracted* coordinates of each partial
 dot they factor out exactly; no scale ever multiplies inside the fp8
 contraction.  Master weights stay f32 in the (already sharded)
 optimizer state — quantization happens in-graph on the forward/backward
-edges, and the fused optimizer update consumes f32 masters unchanged.
+edges, and the optimizer update consumes f32 masters unchanged.
 
 Backends without an fp8 dot lowering (older CPU jaxlibs) fall back to
 running the contraction on the fp8 values upcast to f32 — numerically

@@ -4,9 +4,8 @@ Decode attention is HBM-bound — each step streams every cached K/V
 position of every running request once, does ~4 flops per byte, and
 throws the bytes away.  The generic ``kvcache._attend_blocks`` scan
 expresses that stream as one gather + softmax-update op chain per block
-column, which XLA schedules as independent HLOs; this kernel is the
-serving twin of the r8 fused-update kernel: the whole per-request scan
-becomes **one fused Pallas program** that
+column, which XLA schedules as independent HLOs; in this kernel the
+whole per-request scan becomes **one fused Pallas program** that
 
 * prefetches the block *tables*, the rows' *lengths* and the layer as
   scalars and takes the WHOLE pools as they are stored, ``[num_layers,
@@ -59,8 +58,8 @@ split finishes.
 
 Numerics match the reference scan: f32 scores, max, sum and accumulator,
 ``NEG_INF`` masking, the same ``exp(m - m_new)`` rescale — pinned against
-``kvcache.dense_attention`` by ``tests/test_flash_decode.py``.  Like
-``ops/fused_update.py``, the kernel runs under ``interpret=True`` on CPU
+``kvcache.dense_attention`` by ``tests/test_flash_decode.py``.  The
+kernel runs under ``interpret=True`` on CPU
 (same program, emulated grid) so every test exercises the true kernel
 body; ``paged_attention(impl="flash_interpret")`` selects that twin.
 """

@@ -58,43 +58,6 @@ def clean():
                                _SDS((4, 4), jnp.float32)), {}
 
 
-def fused_regress():
-    """The PR 7 regression class: a trainer that claims the single-pass
-    fused update (tags its flat bucket) but still runs the legacy
-    multi-pass chain — the bucket is traversed once for the rescale and
-    again for the momentum update, so the 1R/1W contract is broken."""
-    from mxnet_tpu.analysis.program import tag
-
-    def step(g, w, m):
-        g = tag(g, label="gradbucket:0")
-        g = g * 0.0625                  # pass 1: rescale sweep
-        m2 = 0.9 * m - 0.1 * g          # pass 2: momentum sweep
-        return w + m2, m2
-    tr = jax.jit(step).trace(_SDS((64,), jnp.float32),
-                             _SDS((64,), jnp.float32),
-                             _SDS((64,), jnp.float32))
-    return tr, {"expect_fused": True}
-
-
-def fused_clean():
-    """Negative control for ``expect_fused``: the tagged bucket feeds
-    ONE opaque fused-update eqn, so the audit must report exactly
-    1R/1W and stay silent."""
-    from mxnet_tpu.analysis.program import tag
-    from mxnet_tpu.ops.fused_update import fused_update
-
-    def step(g, w, m):
-        g = tag(g, label="gradbucket:0")
-        new_w, new_m = fused_update(g, w, (m,), (0.1,),
-                                    kind="sgd_momentum", momentum=0.9,
-                                    rescale_grad=0.0625)
-        return new_w, new_m
-    tr = jax.jit(step).trace(_SDS((64,), jnp.float32),
-                             _SDS((64,), jnp.float32),
-                             _SDS((64,), jnp.float32))
-    return tr, {"expect_fused": True}
-
-
 def _data_mesh():
     from jax.sharding import Mesh
     devs = np.array(jax.devices())
@@ -181,8 +144,6 @@ PROGRAMS = {
     "captured_const": (captured_const, ["program.captured-const"]),
     "donation_miss": (donation_miss, ["program.donation-miss"]),
     "clean": (clean, []),
-    "fused_regress": (fused_regress, ["program.fused-update"]),
-    "fused_clean": (fused_clean, []),
     "hbm_bytes_widened": (hbm_bytes_widened, ["program.hbm-bytes"]),
     "hbm_bytes_quantized": (hbm_bytes_quantized, []),
     "decode_kv_widened": (decode_kv_widened, ["program.hbm-bytes"]),

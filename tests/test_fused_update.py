@@ -1,14 +1,16 @@
-"""mxnet_tpu.ops.fused_update: the single-pass fused optimizer kernel.
+"""The trainer's optimizer update, and checkpoints written while its
+state lived in flat buckets.
 
-The contract under test is BITWISE identity with the unfused per-param
-path — not allclose.  The fused trainer must be a drop-in numerical
-twin: same params, same optimizer state (reconstructed from the flat
-buckets through ``FusedPlan.scatter``), same heads, over multiple steps,
-for every supported optimizer kind, with the bad-step guard on and off,
-including a chaos step whose update must be a bitwise no-op on both
-paths.  On top of the numerics the fused path must keep the framework
-contracts: one trace, donated buffers aliased, and a 1R/1W grad-bucket
-audit (the unfused baseline stays at its multi-pass count).
+The update runs each parameter's optimizer step in the parameter's own
+shape, so XLA fuses it in the layout the parameter lives in.  Under test:
+it matches the imperative optimizer that ``Module`` drives, for every
+optimizer kind, with per-parameter weight-decay and learning-rate
+multipliers; a guarded poisoned step leaves the whole update a bitwise
+no-op; on a ``data`` mesh the state stays replicated; and a checkpoint
+whose optimizer state was kept in flat f32 buckets
+(``opt:fused:<i>:<leaf>``, what the earlier fused update wrote) restores
+and continues.  ``tests/golden/bucketed_ckpt/`` holds such checkpoints,
+written by that update's own code (``make.py`` there says how).
 """
 import os
 
@@ -16,16 +18,15 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import analysis
+from mxnet_tpu import nd
 from mxnet_tpu import symbol as S
-from mxnet_tpu.base import MXNetError
-from mxnet_tpu.ops import fused_update as fu
+from mxnet_tpu.io import DataBatch
 from mxnet_tpu.parallel import ShardedTrainer, make_mesh
 
 N_STEPS = 3
+GOLDEN = os.path.join(os.path.dirname(__file__), "golden", "bucketed_ckpt")
 
 
 def _mlp(no_bias=False):
@@ -36,13 +37,15 @@ def _mlp(no_bias=False):
     return S.SoftmaxOutput(net, name="softmax")
 
 
-def _trainer(fused, optimizer="sgd", opt_params=None, no_bias=False, **kw):
+def _trainer(optimizer="sgd", opt_params=None, no_bias=False, ndev=None,
+             **kw):
     mx.random.seed(7)
-    tr = ShardedTrainer(_mlp(no_bias), mesh=make_mesh({"data": len(jax.devices())}),
+    devs = jax.devices()[:ndev] if ndev else jax.devices()
+    tr = ShardedTrainer(_mlp(no_bias), mesh=make_mesh({"data": len(devs)},
+                                                      devs),
                         optimizer=optimizer,
                         optimizer_params=opt_params or
-                        {"learning_rate": 0.1, "momentum": 0.9},
-                        fused_update=fused, **kw)
+                        {"learning_rate": 0.1, "momentum": 0.9}, **kw)
     tr.bind(data_shapes={"data": (16, 8)},
             label_shapes={"softmax_label": (16,)})
     return tr
@@ -59,38 +62,12 @@ def _params_bytes(tr):
     return {n: np.asarray(tr._params[n]).tobytes() for n in tr._param_names}
 
 
-def _fused_state_bytes(tr):
-    """Per-param optimizer state of a FUSED trainer, reconstructed from
-    the flat buckets through the plan (the layout contract)."""
-    plan = tr._fused_plan
-    leaves = [jax.tree_util.tree_leaves(tr._opt_state[f"fused:{i}"])
-              for i in range(len(plan.buckets))]
-    out = {n: [] for n in tr._param_names}
-    for li in range(len(leaves[0])):
-        per = plan.scatter([leaves[i][li] for i in range(len(plan.buckets))])
-        for n, v in per.items():
-            out.setdefault(n, []).append(np.asarray(v).tobytes())
-    return out
-
-
-def _unfused_state_bytes(tr):
+def _state_bytes(tr):
     out = {}
     for n in tr._param_names:
         out[n] = [np.asarray(x).tobytes()
                   for x in jax.tree_util.tree_leaves(tr._opt_state[n])]
     return out
-
-
-def _assert_twins(a, b, steps, what=""):
-    for si, f in enumerate(steps):
-        ha, hb = a.step(f), b.step(f)
-        assert np.asarray(ha[0]).tobytes() == np.asarray(hb[0]).tobytes(), \
-            f"{what}: heads diverged at step {si}"
-        assert _params_bytes(a) == _params_bytes(b), \
-            f"{what}: params diverged at step {si}"
-        assert _fused_state_bytes(a) == _unfused_state_bytes(b), \
-            f"{what}: optimizer state diverged at step {si}"
-    assert a.trace_counts["train"] == 1 and b.trace_counts["train"] == 1
 
 
 KINDS = [
@@ -99,249 +76,249 @@ KINDS = [
     ("sgd", {"learning_rate": 0.1, "momentum": 0.9,
              "wd": 0.01, "clip_gradient": 0.5}, True),
     ("adam", {"learning_rate": 1e-3}, False),
-    # bias-free net: wd_mult uniform -> scalar wd into the kernel
+    # bias-free net: every param decays
     ("adamw", {"learning_rate": 1e-3, "wd": 0.01}, True),
-    # WITH biases wd_mult is 0 on *_bias params -> non-uniform wd rides
-    # the per-bucket wd segment vector ("fusedwd:<i>") into the kernel
-    # (adam is absent: folded wd has no bitwise fused twin — see the
-    # eligibility-gate test)
+    # WITH biases: wd_mult is 0 on *_bias params, so each parameter's
+    # step takes its own wd (adam folds it into the gradient)
     ("sgd", {"learning_rate": 0.1, "momentum": 0.9,
              "wd": 0.01, "clip_gradient": 0.5}, False),
     ("adamw", {"learning_rate": 1e-3, "wd": 0.01}, False),
+    ("adam", {"learning_rate": 1e-3, "wd": 0.01}, False),
 ]
+KIND_IDS = ["sgd", "sgd_momentum", "sgd_wd_clip", "adam", "adamw",
+            "sgd_wd_bias", "adamw_wd_bias", "adam_wd_bias"]
 
 
-@pytest.mark.parametrize("opt,op,no_bias", KINDS,
-                         ids=["sgd", "sgd_momentum", "sgd_wd_clip",
-                              "adam", "adamw", "sgd_wdvec",
-                              "adamw_wdvec"])
-def test_fused_is_bitwise_twin_of_unfused(opt, op, no_bias):
-    a = _trainer(True, opt, op, no_bias=no_bias)
-    b = _trainer(False, opt, op, no_bias=no_bias)
-    assert a._fused and not b._fused
-    if op.get("wd") and not no_bias:
-        # per-param wd -> the segment vectors must exist, one per bucket
-        assert any(k.startswith("fusedwd:") for k in a._opt_state)
-    _assert_twins(a, b, _feeds(), what=f"{opt}:{op}")
+def _assert_tracks_module(tr, sym, feeds, opt, op, lr_mult=None):
+    """Step ``tr`` and a ``Module`` (executor + the imperative optimizer,
+    one gradient and one ``update`` a parameter) from the same
+    parameters on the same batches; the parameters agree after every
+    step."""
+    arg, aux = tr.get_params()
+    shape = feeds[0]["data"].shape
+    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod.bind(data_shapes=[("data", shape)],
+             label_shapes=[("softmax_label", (shape[0],))])
+    mod.init_params(arg_params=arg, aux_params=aux)
+    mod.init_optimizer(optimizer=opt, optimizer_params=dict(op))
+    if lr_mult:
+        mod._optimizer.set_lr_mult(lr_mult)
+    for si, f in enumerate(feeds):
+        tr.step(f)
+        mod.forward_backward(DataBatch(data=[nd.array(f["data"])],
+                                       label=[nd.array(f["softmax_label"])]))
+        mod.update()
+        want = mod.get_params()[0]
+        for n in tr._param_names:
+            np.testing.assert_allclose(
+                np.asarray(tr._params[n]), want[n].asnumpy(), rtol=2e-5,
+                atol=2e-6, err_msg=f"{opt} {n} step {si}")
+    assert tr.trace_counts["train"] == 1
+
+
+@pytest.mark.parametrize("opt,op,no_bias", KINDS, ids=KIND_IDS)
+def test_update_tracks_the_imperative_optimizer(opt, op, no_bias):
+    tr = _trainer(opt, op, no_bias=no_bias, ndev=1)
+    # one state entry a parameter, in its shape
+    assert list(tr._opt_state) == tr._param_names
+    for n in tr._param_names:
+        for leaf in jax.tree_util.tree_leaves(tr._opt_state[n]):
+            assert leaf.shape == tr._params[n].shape
+    _assert_tracks_module(tr, _mlp(no_bias), _feeds(), opt, op)
+
+
+def _conv_net():
+    """Conv weights of the tiled kinds ([O, I, 3, 3], [O, I, 1, 1],
+    [O, 3, 7, 7]), BatchNorm gamma/beta, biases and an FC weight."""
+    d = S.Variable("data")
+    net = S.Convolution(d, num_filter=8, kernel=(7, 7), stride=(2, 2),
+                        pad=(3, 3), name="conv0")
+    net = S.BatchNorm(net, name="bn0")
+    net = S.Activation(net, act_type="relu")
+    net = S.Convolution(net, num_filter=8, kernel=(3, 3), pad=(1, 1),
+                        name="conv1")
+    net = S.BatchNorm(net, name="bn1")
+    net = S.Activation(net, act_type="relu")
+    net = S.Convolution(net, num_filter=16, kernel=(1, 1), name="conv2",
+                        no_bias=True)
+    net = S.Pooling(net, global_pool=True, kernel=(1, 1), pool_type="avg")
+    net = S.FullyConnected(net, num_hidden=10, name="fc")
+    return S.SoftmaxOutput(net, name="softmax")
+
+
+def test_conv_net_with_per_parameter_multipliers_tracks_the_optimizer():
+    """BatchNorm's gamma/beta and the biases take wd_mult 0, one weight
+    its own lr_mult: each parameter's step takes its own scalars."""
+    op = {"learning_rate": 0.1, "momentum": 0.9, "wd": 0.01}
+    mx.random.seed(7)
+    tr = ShardedTrainer(_conv_net(), mesh=make_mesh({"data": 1},
+                                                    jax.devices()[:1]),
+                        optimizer="sgd", optimizer_params=op)
+    tr.optimizer.lr_mult = {"conv1_weight": 2.0}
+    tr.bind(data_shapes={"data": (4, 3, 16, 16)},
+            label_shapes={"softmax_label": (4,)})
+    assert {tr._wd_mult[n] for n in tr._param_names} == {0.0, 1.0}
+    assert tr._lr_mult["conv1_weight"] == 2.0
+    rng = np.random.RandomState(1)
+    feeds = [{"data": rng.rand(4, 3, 16, 16).astype(np.float32),
+              "softmax_label": rng.randint(0, 10, (4,)).astype(np.float32)}
+             for _ in range(N_STEPS)]
+    _assert_tracks_module(tr, _conv_net(), feeds, "sgd", op,
+                          lr_mult={"conv1_weight": 2.0})
 
 
 @pytest.mark.parametrize("opt,op", [
     ("sgd", {"learning_rate": 0.1, "momentum": 0.9}),
     ("adam", {"learning_rate": 1e-3}),
 ], ids=["sgd_momentum", "adam"])
-def test_fused_guard_twin_and_chaos_step_is_bitwise_noop(opt, op):
-    a = _trainer(True, opt, op, guard=True)
-    b = _trainer(False, opt, op, guard=True)
+def test_guarded_poisoned_step_is_a_bitwise_noop(opt, op):
+    """With the guard on, clean steps match an unguarded run bit for
+    bit, a step whose gradients are not finite leaves every parameter
+    and every state leaf as it was, and the steps after it go on."""
+    a = _trainer(opt, op, guard=True)
+    b = _trainer(opt, op)
     feeds = _feeds(4)
-    feeds[2] = {k: v.copy() for k, v in feeds[2].items()}
-    feeds[2]["data"][0, 0] = np.nan          # chaos: one poisoned sample
-    for si, f in enumerate(feeds):
-        pre_w, pre_s = _params_bytes(a), _fused_state_bytes(a)
+    for f in feeds[:2]:
         a.step(f), b.step(f)
-        if si == 2:
-            # the guard must turn the whole update into a bitwise no-op
-            assert _params_bytes(a) == pre_w
-            assert _fused_state_bytes(a) == pre_s
-        assert _params_bytes(a) == _params_bytes(b), f"step {si}"
-        assert _fused_state_bytes(a) == _unfused_state_bytes(b), f"step {si}"
+        assert _params_bytes(a) == _params_bytes(b)
+        assert _state_bytes(a) == _state_bytes(b)
+    bad = {k: v.copy() for k, v in feeds[2].items()}
+    bad["data"][0, 0] = np.nan          # chaos: one poisoned sample
+    pre_w, pre_s = _params_bytes(a), _state_bytes(a)
+    a.step(bad)
+    assert _params_bytes(a) == pre_w
+    assert _state_bytes(a) == pre_s
+    a.step(feeds[3])
+    assert _params_bytes(a) != pre_w
+    for n in a._param_names:
+        assert np.isfinite(np.asarray(a._params[n])).all(), n
 
 
-def test_fused_multi_bucket_and_split_params_stay_bitwise():
-    """A small byte budget forces several buckets and makes params
-    straddle bucket boundaries — gather/scatter must stay exact."""
+def test_update_stays_replicated_on_a_data_mesh():
+    """On a ``data`` 2 mesh each parameter's update runs on the
+    replicated parameter in its own shape, and the new weights and
+    state come back replicated, matching a one-device run."""
+    def run(ndev):
+        tr = _trainer(ndev=ndev)
+        for f in _feeds():
+            tr.step(f)
+        return tr
+    two = run(2)
+    for n in two._param_names:
+        for arr in (two._params[n], two._opt_state[n]):
+            assert arr.sharding.is_fully_replicated, n
+            assert arr.shape == two._params[n].shape
+    one = run(1)
+    for n in two._param_names:
+        np.testing.assert_allclose(np.asarray(two._params[n]),
+                                   np.asarray(one._params[n]),
+                                   rtol=1e-5, atol=1e-6)
+
+
+def _bucketed_checkpoint(tr, mgr, step):
+    """Save ``tr`` the way the fused update did before its state was
+    kept per parameter: every optimizer leaf as flat f32 buckets
+    ``opt:fused:<i>:<leaf>``, the parameters in reversed order cut
+    greedily at ``grad_bucket_bytes``."""
+    from mxnet_tpu.parallel.collectives import plan_buckets
+    arrays = dict(tr._state_arrays())
+    order = list(reversed(tr._param_names))
+    buckets = plan_buckets([tr._params[n].size for n in order], 4,
+                           tr.grad_bucket_bytes)
+    n_leaves = len(jax.tree_util.tree_leaves(tr._opt_state[order[0]]))
+    for li in range(n_leaves):
+        flat = {n: np.asarray(arrays.pop(f"opt:{n}:{li}")).reshape(-1)
+                for n in order}
+        for i, bucket in enumerate(buckets):
+            arrays[f"opt:fused:{i}:{li}"] = np.concatenate(
+                [flat[order[idx]][s0:s1] for idx, s0, s1 in bucket])
+    return mgr.save(step, arrays, meta=tr._state_meta(), blocking=True)
+
+
+@pytest.mark.parametrize("opt,op", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9}),
+    ("adamw", {"learning_rate": 1e-3, "wd": 0.01}),
+], ids=["sgd_momentum", "adamw"])
+def test_bucketed_checkpoint_restores_and_continues_bitwise(tmp_path, opt,
+                                                            op):
+    from mxnet_tpu.checkpoint import CheckpointManager
+    feeds = _feeds(5)
+    # several buckets, parameters straddling their boundaries
     kw = dict(grad_bucket_bytes=1024)
-    a = _trainer(True, **kw)
-    b = _trainer(False, **kw)
-    assert len(a._fused_plan.buckets) > 1
-    # at least one param is split across buckets
-    per_bucket = [{n for n, _, _ in b_} for b_ in a._fused_plan.buckets]
-    assert any(per_bucket[i] & per_bucket[i + 1]
-               for i in range(len(per_bucket) - 1))
-    _assert_twins(a, b, _feeds(), what="multi-bucket")
+    ref = _trainer(opt, op, **kw)
+    for f in feeds:
+        ref.step(f)
+
+    tr = _trainer(opt, op, **kw)
+    for f in feeds[:2]:
+        tr.step(f)
+    mgr = CheckpointManager(str(tmp_path / "ckpt"))
+    _bucketed_checkpoint(tr, mgr, 2)
+    mgr.close()
+
+    back = _trainer(opt, op, **kw)
+    mgr = CheckpointManager(str(tmp_path / "ckpt"))
+    _, step = back.restore_state(mgr)
+    mgr.close()
+    assert step == 2
+    assert _state_bytes(back) == _state_bytes(tr)
+    for f in feeds[2:]:
+        back.step(f)
+    assert _params_bytes(back) == _params_bytes(ref)
+    assert _state_bytes(back) == _state_bytes(ref)
 
 
-def test_fused_explicit_comm_hands_buckets_to_kernel_bitwise():
-    a = _trainer(True, grad_compression="bf16")
-    b = _trainer(False, grad_compression="bf16")
-    _assert_twins(a, b, _feeds(), what="explicit-comm")
-    rep = analysis.audit_trainer(a, programs=("train",))
-    hbm = rep.metrics["trainer.train"]["hbm_passes"]
-    assert hbm["max_reads"] == 1 and hbm["max_writes"] == 1
+#: the runs of ``tests/golden/bucketed_ckpt/make.py``: optimizer,
+#: its parameters, whether the net has biases
+GOLDEN_RUNS = {
+    "sgd": ("sgd", {"learning_rate": 0.1, "wd": 0.01}),
+    "sgd_momentum": ("sgd", {"learning_rate": 0.1, "momentum": 0.9,
+                             "wd": 0.01}),
+    "adam": ("adam", {"learning_rate": 1e-3}),
+    "adamw": ("adamw", {"learning_rate": 1e-3, "wd": 0.01}),
+}
 
 
-def test_fused_audit_one_read_one_write_and_unfused_baseline():
-    rep = analysis.audit_trainer(_trainer(True), programs=("train",))
-    assert rep.clean, rep.format_text()
-    hbm = rep.metrics["trainer.train"]["hbm_passes"]
-    assert len(hbm["buckets"]) == 1
-    assert hbm["max_reads"] == 1 and hbm["max_writes"] == 1
-    don = rep.metrics["trainer.train"]["donation"]
-    assert don["donated_leaves"] == don["aliased_outputs"] > 0
+@pytest.mark.parametrize("run", sorted(GOLDEN_RUNS))
+def test_checkpoint_written_by_the_bucketed_update_restores(tmp_path, run):
+    """A checkpoint the fused update's own code wrote (two steps on a
+    ``data`` 1 mesh, ``grad_bucket_bytes`` 1024: several buckets,
+    parameters straddling them, and per-bucket wd vectors where biases
+    take no decay) restores into per-parameter state equal to two
+    steps of this trainer, and the next three steps follow a run that
+    never saved."""
+    import shutil
+    from mxnet_tpu.checkpoint import CheckpointManager
+    opt, op = GOLDEN_RUNS[run]
+    kw = dict(grad_bucket_bytes=1024, ndev=1)
+    feeds = _feeds(5)
+    ref = _trainer(opt, op, **kw)
+    for f in feeds[:2]:
+        ref.step(f)
+    at2 = {n: [np.asarray(x).copy()
+               for x in jax.tree_util.tree_leaves(ref._opt_state[n])]
+           for n in ref._param_names}
+    for f in feeds[2:]:
+        ref.step(f)
 
-    rep = analysis.audit_trainer(_trainer(False), programs=("train",))
-    hbm = rep.metrics["trainer.train"]["hbm_passes"]
-    assert hbm["max_reads"] == 5 and hbm["max_writes"] == 5
-
-
-def test_fused_eligibility_gate():
-    # per-param effective wd (bias wd_mult=0) is fused-ELIGIBLE since the
-    # wd segment-vector operand landed: the old silent fallback is gone
-    op = {"learning_rate": 1e-3, "wd": 0.01}
-    tr = _trainer(None, "adamw", op)
-    assert tr._fused and not tr._fused_wd_uniform
-    assert any(k.startswith("fusedwd:") for k in tr._opt_state)
-    # ...and the segment vectors hold exactly wd * wd_mult per element
-    vec = np.asarray(tr._opt_state["fusedwd:0"])
-    assert set(np.unique(vec)) <= {np.float32(0.0), np.float32(0.01)}
-
-    # per-param lr_mult still cannot fuse
-    mx.random.seed(7)
-    tr = ShardedTrainer(_mlp(), mesh=make_mesh({"data": len(jax.devices())}),
-                        optimizer="adamw", optimizer_params=op,
-                        fused_update=True)
-    tr.optimizer.lr_mult = {"fc1_weight": 2.0}
-    with pytest.raises(MXNetError, match="cannot fuse"):
-        tr.bind(data_shapes={"data": (16, 8)},
-                label_shapes={"softmax_label": (16,)})
-
-    # adam's FOLDED wd (g + wd*w feeds both moments) has no bitwise
-    # fused twin — LLVM's FMA contraction of the fold is context-
-    # dependent.  Silent fallback on default, error when forced.  This
-    # also closes a latent hole: the old gate let uniform-wd adam fuse.
-    assert not _trainer(None, "adam", op)._fused
-    with pytest.raises(MXNetError, match="use adamw"):
-        _trainer(True, "adam", op)
-
-    # env opt-out wins over the default
-    os.environ["MXNET_TPU_FUSED_UPDATE"] = "0"
-    try:
-        assert not _trainer(None)._fused
-    finally:
-        del os.environ["MXNET_TPU_FUSED_UPDATE"]
-    assert _trainer(None)._fused
-
-
-def test_fused_kind_detection():
-    from mxnet_tpu.optimizer import SGD, Adam, AdamW
-    assert fu.fused_kind(SGD(learning_rate=0.1)) == "sgd"
-    assert fu.fused_kind(SGD(learning_rate=0.1, momentum=0.9)) == "sgd_momentum"
-    assert fu.fused_kind(Adam()) == "adam"
-    assert fu.fused_kind(AdamW()) == "adamw"
-
-    class NotSGD(SGD):
-        def _functional_step(self, *a, **k):  # pragma: no cover
-            raise NotImplementedError
-    # overridden update rule → no fused twin, silent fallback
-    assert fu.fused_kind(NotSGD(learning_rate=0.1)) is None
-
-
-def test_pallas_kernel_matches_reference():
-    """interpret-mode Pallas vs the jnp reference, every kind, with the
-    guard/mult operands exercised in both accept and reject states.
-
-    This guards the kernel's FORMULA (rtol 1e-5, atol 5e-6 on O(1)
-    operands — a wrong term or constant is off by 1e-3 or more) — not
-    its rounding: interpret mode wraps the kernel ops in block slicing, so
-    its CPU fusion shape differs from the plain jitted reference and
-    LLVM's backend FMA contraction picks different multiplies to fuse
-    (the hazard the trainer's while-loop lowering removes — see
-    ``_materialized_reference``; the trainer-level fused-vs-unfused pins
-    above ARE bitwise).  How far that drifts is a property of the
-    installed XLA:CPU (1 ulp once, 4 ulp for adam on jaxlib 0.9.0), so a
-    tighter pin here arbitrates the wrong compiler.  The arbiter of the
-    compiled kernel's rounding is the chip: ``chip_smoke.py`` compares
-    the Mosaic kernel to ``reference_update`` there (0 ulp on a v5e for
-    adam and sgd_momentum, PR 21).  The ``ok=False`` reject path must
-    still be a bitwise no-op."""
-    rng = np.random.RandomState(3)
-    n = 618                      # deliberately not a multiple of 8*128
-    g = jnp.asarray(rng.randn(n).astype(np.float32))
-    w = jnp.asarray(rng.randn(n).astype(np.float32))
-    s1 = jnp.asarray(rng.randn(n).astype(np.float32) * 1e-2)
-    s2 = jnp.asarray(np.abs(rng.randn(n).astype(np.float32)) * 1e-3)
-    cases = [
-        ("sgd", (), dict(wd=0.01, rescale_grad=0.25)),
-        ("sgd_momentum", (s1,), dict(momentum=0.9, wd=0.01,
-                                     clip_gradient=0.5, rescale_grad=0.25)),
-        ("adam", (s1, s2), dict(beta1=0.9, beta2=0.999, epsilon=1e-8,
-                                wd=0.01, rescale_grad=0.25)),
-        ("adamw", (s1, s2), dict(beta1=0.9, beta2=0.999, epsilon=1e-8,
-                                 rescale_grad=0.25)),
-    ]
-    # the wd segment-vector operand (per-element effective wd)
-    wdv = jnp.asarray((rng.rand(n) < 0.5).astype(np.float32) * 0.01)
-    cases += [
-        ("sgd", (), dict(rescale_grad=0.25, wd_vec=wdv)),
-        ("sgd_momentum", (s1,), dict(momentum=0.9, clip_gradient=0.5,
-                                     rescale_grad=0.25, wd_vec=wdv)),
-        ("adam", (s1, s2), dict(beta1=0.9, beta2=0.999, epsilon=1e-8,
-                                rescale_grad=0.25, wd_vec=wdv)),
-        ("adamw", (s1, s2), dict(beta1=0.9, beta2=0.999, epsilon=1e-8,
-                                 rescale_grad=0.25, wd_vec=wdv)),
-    ]
-    for kind, state, hyper in cases:
-        scalars = (np.float32(0.05),) if kind != "adamw" \
-            else (np.float32(0.05), np.float32(1e-4))
-        for mult in (None, np.float32(0.5)):
-            for oks in ((None,), (True, False)):
-                # jit, not eager: eager runs every op as its own XLA
-                # program where the backend never FMA-contracts — the
-                # spec is the jitted form.  ``ok`` is an operand, so
-                # accept and reject share one program.
-                def both(g, w, s, ok):
-                    kw = dict(kind=kind, mult=mult, ok=ok, **hyper)
-                    return (fu.reference_update(g, w, s, scalars, **kw),
-                            fu.pallas_update(g, w, s, scalars, **kw))
-                run = jax.jit(both)
-                for ok in oks:
-                    ref, pal = run(g, w, state,
-                                   None if ok is None else jnp.asarray(ok))
-                    for r, p in zip(ref, pal):
-                        np.testing.assert_allclose(
-                            np.asarray(p), np.asarray(r), rtol=1e-5,
-                            atol=5e-6, err_msg=str((kind, mult, ok)))
-                    if ok is False:  # reject: bitwise no-op on BOTH paths
-                        assert np.asarray(ref[0]).tobytes() == \
-                            np.asarray(w).tobytes()
-                        assert np.asarray(pal[0]).tobytes() == \
-                            np.asarray(w).tobytes()
-
-
-def test_tpu_lowering_under_a_mesh():
-    """GSPMD cannot partition a Mosaic kernel, so under a multi-device
-    mesh the TPU lowering must open its own ``shard_map`` (cross-lowered
-    here, no chip needed).  Found when the data=4 LM step was first
-    compiled for a v5e host: every CPU-mesh test passed because the
-    default lowering is plain jnp."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = Mesh(np.array(jax.devices()), ("data",))
-    aval = jax.ShapeDtypeStruct((5000,), jnp.float32,
-                                sharding=NamedSharding(mesh, P()))
-
-    def step(mesh_):
-        return jax.jit(lambda g, w, m, v: fu.fused_update(
-            g, w, (m, v), (np.float32(0.01),), kind="adam", beta1=0.9,
-            beta2=0.999, epsilon=1e-8, mesh=mesh_)).trace(*[aval] * 4)
-
-    assert "tpu_custom_call" in step(mesh).lower(
-        lowering_platforms=("tpu",)).as_text()
-    # the refusal this guards against, so the test cannot rot silently
-    with pytest.raises(NotImplementedError, match="shard_map"):
-        step(None).lower(lowering_platforms=("tpu",))
-    # one opaque eqn either way: the auditor's fusion boundary holds
-    assert [e.primitive.name for e in step(mesh).jaxpr.eqns] == \
-        ["mxtpu_fused_update"]
-
-
-def test_plan_round_trip_and_reduce_grads_mirror():
-    shapes = {"a": (10, 32), "b": (32,), "c": (32, 8), "d": (10,)}
-    plan = fu.build_plan(["a", "b", "c", "d"], shapes, bucket_bytes=1024)
-    rng = np.random.RandomState(0)
-    tree = {n: jnp.asarray(rng.randn(*s).astype(np.float32))
-            for n, s in shapes.items()}
-    buckets = [plan.gather(tree, i) for i in range(len(plan.buckets))]
-    assert sum(plan.bucket_sizes) == sum(int(np.prod(s))
-                                         for s in shapes.values())
-    back = plan.scatter(buckets)
-    for n in shapes:
-        assert np.asarray(back[n]).tobytes() == np.asarray(tree[n]).tobytes()
+    src = os.path.join(GOLDEN, run)
+    assert os.path.isdir(src), src
+    shutil.copytree(src, tmp_path / "ckpt")
+    back = _trainer(opt, op, **kw)
+    mgr = CheckpointManager(str(tmp_path / "ckpt"))
+    _, step = back.restore_state(mgr)
+    mgr.close()
+    assert step == 2 and back._num_update == 2
+    for n in back._param_names:
+        leaves = jax.tree_util.tree_leaves(back._opt_state[n])
+        assert len(leaves) == len(at2[n])
+        for got, want in zip(leaves, at2[n]):
+            assert got.shape == back._params[n].shape
+            np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                                       atol=1e-7, err_msg=n)
+    for f in feeds[2:]:
+        back.step(f)
+    for n in back._param_names:
+        np.testing.assert_allclose(np.asarray(back._params[n]),
+                                   np.asarray(ref._params[n]), rtol=1e-6,
+                                   atol=1e-7, err_msg=n)

@@ -55,8 +55,7 @@ def _conv_trainer(**kw):
                                                     jax.devices()[:1]),
                         optimizer="sgd",
                         optimizer_params={"learning_rate": 0.1,
-                                          "momentum": 0.9},
-                        fused_update=True, **kw)
+                                          "momentum": 0.9}, **kw)
     tr.bind(data_shapes={"data": (4, 3, 8, 8)},
             label_shapes={"softmax_label": (4,)})
     return tr
@@ -71,8 +70,7 @@ def _lm_trainer(mesh_shape=None, **kw):
     n = int(np.prod(list(mesh_shape.values())))
     tr = ShardedTrainer(sym, mesh=make_mesh(mesh_shape, jax.devices()[:n]),
                         optimizer="adam",
-                        optimizer_params={"learning_rate": 1e-3},
-                        fused_update=False, **kw)
+                        optimizer_params={"learning_rate": 1e-3}, **kw)
     tr.bind(data_shapes={"data": (4, 8)},
             label_shapes={"softmax_label": (4, 8)})
     return tr
@@ -103,13 +101,12 @@ def _unscoped_text(build, monkeypatch):
 def test_fused_conv_step_names_forward_backward_update_and_nodes(
         monkeypatch):
     tr = _conv_trainer()
-    assert tr._fused
     text = _compiled_text(tr)
     names = _op_names(text)
     assert any("/jvp(forward)/conv1/" in n for n in names)
     assert any("/transpose(jvp(forward))/conv2/" in n for n in names)
     assert any("/jvp(forward)/bn1/" in n for n in names)
-    # the fused path's gather, kernel and scatter are all update's
+    # each parameter's optimizer step is update's
     assert any("/update/" in n for n in names)
     assert not any("/guard/" in n for n in names)
     assert _without_metadata(text) == _without_metadata(

@@ -9,8 +9,8 @@ Five subcommands:
   default FullyConnected trainer and the transformer-LM trainer) and
   run the jaxpr/HLO rules: dtype widening, carried-state fixed points,
   host transfers, donation misses, captured constants.  Also reports
-  the HBM-pass count per flat grad bucket — the measuring stick for
-  the fused-update ROADMAP item.
+  how many passes the update chain makes over each flat grad bucket
+  in the jaxpr.
 - ``gate``  — CI entry: lint + audit must be clean AND every seeded
   violation in ``tests/golden/staticcheck/`` must still be detected
   (rule-regression coverage), with the corpus' negative control
