@@ -42,6 +42,20 @@ output before each residual add).  ``embed_scale`` multiplies the
 embedding.  A hybrid model adds a kind here and a cache kind in
 ``serve.kvcache``; it does not add a file of twins.
 
+The residual path is ``h + F(h)`` unless ``hc_mult`` says ``n``: then
+the hidden state is ``n`` streams ``X`` [..., n, d] (manifold-constrained
+hyper-connections), each a copy of the embedding at the start and summed
+before the final norm, and every sublayer F (the attention, then the
+FFN) has a connection of its own.  From ``vec(X)`` (n d values) a
+projection ``h = (Phi vec(X)) (mean(vec(X)^2) + norm_eps)^-1/2`` gives
+``H_pre = sigmoid(a_pre h[:n] + b)``, ``H_post = 2 sigmoid(a_post
+h[n:2n] + b)`` and ``H_res`` = ``hc_sinkhorn_iters`` alternations of
+row then column normalisation (``x / (sum + hc_eps)``) of
+``exp(clamp(a_res mat(h[2n:]) + b, +-hc_clamp))``; the sublayer reads
+``u = sum_j H_pre[j] X[j]`` and the streams become ``X[i] = sum_j
+H_res[i, j] X[j] + H_post[i] F(u)``.  The maps are float32, the streams
+keep the activations' type (:func:`hc_maps`).
+
 The FFN of a layer is dense (``ffn``: ReLU or gated SiLU) or, where
 ``ffn_layers`` says ``"routed"``, an expert layer
 (``models/experts.py``): a router over ``n_routed_experts`` with
@@ -67,7 +81,11 @@ key rows before its value rows); ``router_weight`` ``[experts, d]``
 ``shared_gate/up/down_weight``, ``experts_gate/up_weight`` ``[held, d,
 width]`` and ``experts_down_weight`` ``[held, width, d]`` (routed
 layers: a held expert's matrices are ``[in, out]``, as the grouped
-product reads them); ``embed_weight``, ``final_ln_*``, ``lm_head_*``.
+product reads them); ``hc_attn_weight`` ``[2n + n^2, n d]`` (Phi),
+``hc_attn_bias`` ``[2n + n^2]`` and ``hc_attn_scale`` ``[3]`` (a_pre,
+a_post, a_res), all float32, and the same three ``hc_ffn_*`` (the two
+connections of a layer under ``hc_mult``); ``embed_weight``,
+``final_ln_*``, ``lm_head_*``.
 """
 from __future__ import annotations
 
@@ -138,6 +156,11 @@ class ModelSpec:
     attn_gate: bool = False             # o = W_o(sigmoid(W_g u) * attention)
     sandwich_norm: bool = False         # norm attention's, FFN's outputs
     embed_scale: float = 1.0            # h_0 = embed_scale * E[token]
+    # -- hyper-connections: None is the plain residual h + F(h) --
+    hc_mult: Optional[int] = None       # residual streams n
+    hc_sinkhorn_iters: int = 20         # row, column normalisations of H_res
+    hc_eps: float = 1e-6                # x / (sum + hc_eps) in each of them
+    hc_clamp: float = 30.0              # H_res's logits clamped to +-this
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -205,6 +228,13 @@ class ModelSpec:
             if first < 0 or count < 1 or first + count > n:
                 raise MXNetError(f"experts_held {self.experts_held}: not "
                                  f"a range of the {n} routed experts")
+        if self.hc_mult is not None and (
+                int(self.hc_mult) < 1 or int(self.hc_sinkhorn_iters) < 1
+                or not self.hc_eps > 0 or not self.hc_clamp > 0):
+            raise MXNetError(
+                f"hyper-connections need hc_mult ({self.hc_mult}) >= 1, "
+                f"hc_sinkhorn_iters ({self.hc_sinkhorn_iters}) >= 1 and "
+                f"hc_eps ({self.hc_eps}), hc_clamp ({self.hc_clamp}) > 0")
         kv = self.heads if self.kv_heads is None else int(self.kv_heads)
         if kv < 1 or self.heads % kv:
             raise MXNetError(f"heads {self.heads} not a multiple of "
@@ -476,20 +506,10 @@ def _latent_states(spec: ModelSpec, params, name, hn, positions):
         return q, jnp.concatenate([c, k_r], axis=-1)
 
 
-def block(spec: ModelSpec, params, i: int, kind: str, h, positions,
-          attend: Callable, ffn_kind: str = DENSE,
-          routed: Optional[Callable] = None):
-    """One decoder block on hidden states ``h`` ([..., d]).
-
-    ``attend(q, k, v, gate)`` receives the per-head states (q
-    [..., H, hd]; k, v [..., KV, hd]; ``gate`` the log forget gate
-    [..., KV] in float32 for a retention layer, else None), owns the
-    cache, and returns the attention output [..., H, hd].  A latent
-    layer hands it ``(q, row, None, None)``: the queries [..., H, nope +
-    rope] and the row it caches ([..., kv_lora_rank + rope]), and gets
-    [..., H, v_head_dim] back.  ``routed(x)`` is the expert layer of a
-    ``"routed"`` block on the normed states (shared experts included).
-    The parts are ``jax.named_scope``s, so a device trace names them."""
+def _attention(spec: ModelSpec, params, i: int, kind: str, h, positions,
+               attend: Callable):
+    """The attention sublayer's output ``W_o(...)`` on hidden states
+    ``h`` [..., d] (its pre-norm inside), before any residual."""
     heads, kv, hd = spec.dims(h.shape[-1])
     lead = h.shape[:-1]
 
@@ -534,30 +554,118 @@ def block(spec: ModelSpec, params, i: int, kind: str, h, positions,
                     "attn_gate_weight"))).astype(jnp.float32))
                 att = (att.astype(jnp.float32) * g).astype(att.dtype)
     with jax.named_scope("proj"):
-        h = _residual(spec, params, name("post_attn_norm"), h,
-                      _linear(spec, params, name("proj"), att))
+        return _linear(spec, params, name("proj"), att)
+
+
+def _ffn(spec: ModelSpec, params, i: int, h, ffn_kind: str,
+         routed: Optional[Callable]):
+    """The FFN sublayer's output on hidden states ``h`` (its pre-norm
+    inside), before any residual: a routed layer's experts (shared ones
+    included) or the dense FFN."""
+    def name(suffix):
+        return f"layer{i}_{suffix}"
+
     if ffn_kind == ROUTED:
         with jax.named_scope("router"):
             hn = _norm(spec, params, name("ln2"), h)
-        return _residual(spec, params, name("post_ffn_norm"), h,
-                         routed(hn).astype(h.dtype))
+        return routed(hn).astype(h.dtype)
     with jax.named_scope("ffn"):
         hn = _norm(spec, params, name("ln2"), h)
         if spec.ffn == "relu":
             f = _linear(spec, params, name("ffn1"), hn)
-            f = jnp.maximum(f, 0)
-            return h + _linear(spec, params, name("ffn2"), f)
+            return _linear(spec, params, name("ffn2"), jnp.maximum(f, 0))
         f = (jax.nn.silu(_linear(spec, params, name("ffn_gate"), hn))
              * _linear(spec, params, name("ffn_up"), hn))
-        return _residual(spec, params, name("post_ffn_norm"), h,
-                         _linear(spec, params, name("ffn_down"), f))
+        return _linear(spec, params, name("ffn_down"), f)
 
 
-def _residual(spec: ModelSpec, params, norm: str, h, out):
-    """``h + out``, the output normed first under ``sandwich_norm``."""
+def block(spec: ModelSpec, params, i: int, kind: str, h, positions,
+          attend: Callable, ffn_kind: str = DENSE,
+          routed: Optional[Callable] = None, mix: Optional[Callable] = None):
+    """One decoder block on hidden states ``h`` ([..., d]; under
+    ``hc_mult`` the streams [..., n, d]).
+
+    ``attend(q, k, v, gate)`` receives the per-head states (q
+    [..., H, hd]; k, v [..., KV, hd]; ``gate`` the log forget gate
+    [..., KV] in float32 for a retention layer, else None), owns the
+    cache, and returns the attention output [..., H, hd].  A latent
+    layer hands it ``(q, row, None, None)``: the queries [..., H, nope +
+    rope] and the row it caches ([..., kv_lora_rank + rope]), and gets
+    [..., H, v_head_dim] back.  ``routed(x)`` is the expert layer of a
+    ``"routed"`` block on the normed states (shared experts included).
+    ``mix`` computes a connection's maps (:func:`hc_maps`'s signature).
+    The parts are ``jax.named_scope``s, so a device trace names them."""
+    def attention(u):
+        return _attention(spec, params, i, kind, u, positions, attend)
+
+    def ffn(u):
+        return _ffn(spec, params, i, u, ffn_kind, routed)
+
+    if spec.hc_mult:
+        for which, sublayer in (("attn", attention), ("ffn", ffn)):
+            pre = f"layer{i}_hc_{which}_"
+            with jax.named_scope("hc_pre"):
+                u, _, post, res = mix(
+                    spec, h, _param(params, pre + "weight"),
+                    _param(params, pre + "bias"),
+                    _param(params, pre + "scale"))
+            y = _post_norm(spec, params, f"layer{i}_post_{which}_norm",
+                           sublayer(u))
+            with jax.named_scope("hc_post"):
+                h = hc_depth(h, post, res, y)
+        return h
+    out = attention(h)
+    with jax.named_scope("proj"):
+        h = h + _post_norm(spec, params, f"layer{i}_post_attn_norm", out)
+    out = ffn(h)
+    if ffn_kind == ROUTED:
+        return h + _post_norm(spec, params, f"layer{i}_post_ffn_norm", out)
+    with jax.named_scope("ffn"):
+        return h + _post_norm(spec, params, f"layer{i}_post_ffn_norm", out)
+
+
+def _post_norm(spec: ModelSpec, params, norm: str, out):
+    """A sublayer's output, normed under ``sandwich_norm``."""
     if spec.sandwich_norm:
-        out = _rmsm(out, _param(params, norm + "_gamma"), spec.norm_eps)
-    return h + out
+        return _rmsm(out, _param(params, norm + "_gamma"), spec.norm_eps)
+    return out
+
+
+def hc_maps(spec: ModelSpec, x, weight, bias, scale):
+    """A connection's maps from the streams ``x`` [..., n, d]: ``(u
+    [..., d] in x's type, H_pre [..., n], H_post [..., n], H_res [..., n,
+    n])``, the maps float32 (the module docstring's equations).  The
+    plain form; ``serve/hc_mix.py`` is the kernel."""
+    n, f32 = int(spec.hc_mult), jnp.float32
+    lead, d = x.shape[:-2], x.shape[-1]
+    flat = x.reshape(lead + (n * d,)).astype(f32)
+    ms = jnp.mean(jnp.square(flat), axis=-1, keepdims=True)
+    h = jax.lax.dot_general(
+        flat, weight.astype(f32), (((flat.ndim - 1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST) * jax.lax.rsqrt(
+            ms + np.float32(spec.norm_eps))
+    a, b = scale.astype(f32), bias.astype(f32)
+    pre = jax.nn.sigmoid(a[0] * h[..., :n] + b[:n])
+    post = np.float32(2.0) * jax.nn.sigmoid(a[1] * h[..., n:2 * n]
+                                            + b[n:2 * n])
+    clamp, eps = np.float32(spec.hc_clamp), np.float32(spec.hc_eps)
+    m = jnp.exp(jnp.clip(a[2] * h[..., 2 * n:] + b[2 * n:], -clamp,
+                         clamp)).reshape(lead + (n, n))
+    for _ in range(int(spec.hc_sinkhorn_iters)):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    u = jnp.sum(pre[..., None] * x.astype(f32), axis=-2)
+    return u.astype(x.dtype), pre, post, m
+
+
+def hc_depth(x, post, res, y):
+    """The streams after a sublayer: ``X[i] = sum_j H_res[i, j] X[j] +
+    H_post[i] y``, in float32, kept in ``x``'s type."""
+    f32 = jnp.float32
+    mixed = jnp.sum(res[..., :, :, None] * x.astype(f32)[..., None, :, :],
+                    axis=-2)
+    return (mixed + post[..., None] * y.astype(f32)[..., None, :]).astype(
+        x.dtype)
 
 
 def lm_head(spec: ModelSpec, params, h):
@@ -569,7 +677,8 @@ def lm_head(spec: ModelSpec, params, h):
 def decoder_forward(spec: ModelSpec, params: Dict[str, Any], tokens,
                     positions, attend: Callable, *,
                     routed: Optional[Callable] = None,
-                    select: Optional[Callable] = None):
+                    select: Optional[Callable] = None,
+                    mix: Optional[Callable] = None):
     """Logits [..., V] for ``tokens`` [...] (one position per decode row
     ``[B]``, or a chunk / verify window ``[B, C]``) over a caller-owned
     cache.  ``positions`` has the tokens' shape (absolute positions; read
@@ -581,7 +690,8 @@ def decoder_forward(spec: ModelSpec, params: Dict[str, Any], tokens,
     ``routed(layer, x)`` runs a routed layer's experts on the normed
     states (None: the plain form of ``models/experts.py``, every held
     expert over every token).  ``select(h)`` picks the hidden states the
-    head is computed for (None: all)."""
+    head is computed for (None: all).  ``mix`` computes a connection's
+    maps under ``hc_mult`` (None: :func:`hc_maps`)."""
     _, num_layers, _ = lm_config_from_params(params)
     kinds = spec.layer_kinds(num_layers)
     ffns = spec.ffn_kinds(num_layers)
@@ -594,11 +704,18 @@ def decoder_forward(spec: ModelSpec, params: Dict[str, Any], tokens,
     if spec.embed_scale != 1.0:
         h = (h.astype(jnp.float32) * np.float32(spec.embed_scale)).astype(
             h.dtype)
+    if spec.hc_mult:
+        # every stream starts as the embedding
+        h = jnp.broadcast_to(h[..., None, :], h.shape[:-1] + (
+            int(spec.hc_mult), h.shape[-1]))
     for i, (kind, ffn_kind) in enumerate(zip(kinds, ffns)):
         h = block(spec, params, i, kind, h, positions,
                   lambda q, k, v, g, i=i, kind=kind: attend(i, kind, q, k,
                                                            v, g),
-                  ffn_kind, lambda x, i=i: routed(i, x))
+                  ffn_kind, lambda x, i=i: routed(i, x), mix or hc_maps)
     if select is not None:
         h = select(h)
+    if spec.hc_mult:
+        with jax.named_scope("hc_post"):
+            h = jnp.sum(h.astype(jnp.float32), axis=-2).astype(h.dtype)
     return lm_head(spec, params, h)

@@ -45,6 +45,7 @@ import collections
 import os
 import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -493,6 +494,12 @@ class Engine:
                              and self.model != ModelSpec(heads=self.heads))
         self._routed_layers = self.model.ffn_kinds(self.num_layers).count(
             ROUTED)
+        # under hyper-connections the prefill and decode spans say how
+        # many connections a program run makes (``mxtpu_hc_mix`` calls
+        # where the kernels run) over how many streams
+        self._hc_args = (dict(hc_calls=2 * self.num_layers,
+                              hc_streams=int(self.model.hc_mult))
+                         if self.model.hc_mult else {})
         if self._routed_layers and "experts_held" in self._params:
             # weights that say which experts they are: the description's
             # share has to be the same one
@@ -1017,6 +1024,16 @@ class Engine:
 
         return routed
 
+    def _mix(self):
+        """``decoder_forward``'s ``mix`` for this engine: the kernel
+        ``mxtpu_hc_mix`` where the kernels run (on the chip, or
+        interpreted), the plain form elsewhere."""
+        if not self.model.hc_mult or self.attn_impl not in (
+                "flash", "flash_interpret"):
+            return None
+        from .hc_mix import hc_mix
+        return partial(hc_mix, interpret=self.attn_impl == "flash_interpret")
+
     def _paged_attend(self, pools, write, read):
         """``decoder_forward``'s ``attend`` over the paged cache.  Layer
         ``i`` lives at ``self._kv_slot[i] = (at, j)``: layer ``j`` of the
@@ -1094,7 +1111,8 @@ class Engine:
                 spec, params, tokens, positions,
                 self._paged_attend(pools, write, read),
                 routed=self._routed(params, (positions < length)[0], []),
-                select=lambda h: jax.lax.dynamic_slice_in_dim(h, last, 1, 1))
+                select=lambda h: jax.lax.dynamic_slice_in_dim(h, last, 1, 1),
+                mix=self._mix())
             with jax.named_scope("sample"):
                 last_logits = logits[0, 0]
                 tok = _sample(last_logits, key, temp, topk, length)
@@ -1156,7 +1174,7 @@ class Engine:
             logits = decoder_forward(
                 spec, params, tokens, lengths,
                 self._paged_attend(pools, write, read),
-                routed=self._routed(params, active, stats))
+                routed=self._routed(params, active, stats), mix=self._mix())
             with jax.named_scope("sample"):
                 toks = _sample(logits, keys, temps, topks, lengths + 1)
                 oks = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
@@ -1921,7 +1939,7 @@ class Engine:
         t0 = time.monotonic()
         with telemetry.span("serve.prefill", req=req.id, bucket=cb,
                             prompt=plen, chunk_start=start,
-                            chunk_budget=cb):
+                            chunk_budget=cb, **self._hc_args):
             with telemetry.span("serve.build"):
                 padded = np.zeros((1, cb), np.int32)
                 padded[0, :len(toks)] = toks
@@ -2125,7 +2143,8 @@ class Engine:
         src_of = flight.index if flight is not None else {}
         with telemetry.span("serve.decode", step=self.step_idx, bucket=bb,
                             active=len(active),
-                            overlapped=int(flight is not None)) as decode_span:
+                            overlapped=int(flight is not None),
+                            **self._hc_args) as decode_span:
             with telemetry.span("serve.build"):
                 bsz = self.alloc.block_size
                 src = np.full((bb,), -1, np.int32)

@@ -172,6 +172,34 @@ def test_latent_program_makes_no_float64(kind, impl):
     _assert_no_float64(closed, f"{kind}@{bucket} (latent pool, {impl})")
 
 
+# the same latent model on four residual streams joined by
+# hyper-connections: two connections a layer, their maps by
+# ``hc_maps`` (dense) or the kernel ``mxtpu_hc_mix`` (interpreted)
+@pytest.mark.parametrize("kind,impl", [("prefill_chunk", "dense"),
+                                       ("prefill_chunk", "flash_interpret"),
+                                       ("decode", "dense"),
+                                       ("decode", "flash_interpret")])
+def test_hyper_connection_program_makes_no_float64(kind, impl):
+    params = _latent_params()
+    rng = np.random.RandomState(1)
+    for i in range(NL):
+        for which in ("attn", "ffn"):
+            p = f"layer{i}_hc_{which}_"
+            params[p + "weight"] = (rng.randn(24, 4 * D) * 0.05).astype(
+                np.float32)
+            params[p + "bias"] = np.zeros((24,), np.float32)
+            params[p + "scale"] = np.full((3,), 0.4, np.float32)
+    eng = Engine(params, EngineConfig(
+        heads=H, model=dict(_LATENT, hc_mult=4), block_size=4,
+        num_blocks=24, max_batch=4, max_prompt_len=16, max_seq_len=48,
+        prefill_chunk=8, attn_impl=impl))
+    bucket = 8 if kind == "prefill_chunk" else 4
+    fn = getattr(eng, _MAKERS[kind])(bucket)
+    closed = jax.make_jaxpr(fn)(*eng._avals(kind, bucket))
+    assert len(closed.jaxpr.eqns) > 20, "nothing was traced"
+    _assert_no_float64(closed, f"{kind}@{bucket} (streams, {impl})")
+
+
 # window and global softmax layers with grouped heads and sigmoid-routed
 # experts (ISSUE 37): rotary positions on the window layer only, q/k
 # norms, the output gate, sandwich norms, a scaled embedding, a dense

@@ -644,3 +644,41 @@ def test_step_reshapes_no_parameter_and_its_update_relays_none_on_a_v5e(
     cost = cost[0] if isinstance(cost, list) else cost
     # SGD momentum reads 137 MB, AdamW (one more state) 158 MB
     assert cost["bytes accessed"] < 200e6, cost["bytes accessed"]
+
+
+# ---------------------------------------------------------------------------
+# the hyper-connections' kernel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows", [128, 1024])
+def test_connection_kernel_compiles_for_a_v5e(topo, rows):
+    """Mosaic COMPILES ``mxtpu_hc_mix`` at the published widths of
+    ``benchmark/configs/xing4-29b-a4b-6of40.json`` (4 streams of 3584 in
+    bf16, Phi [24, 14336] float32, 20 Sinkhorn iterations) for a decode
+    step's 128 rows and a chunk's 1,024 positions: one kernel call and a
+    handful of small fusions around it, where the same maps composed by
+    XLA (``decoder.hc_maps``) are ~90 fusions a connection at any row
+    count, which is why the kernel exists."""
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.models.decoder import ModelSpec, hc_maps
+    from mxnet_tpu.serve.hc_mix import hc_mix
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    spec = ModelSpec(heads=32, norm="rmsnorm", bias=False, norm_eps=1e-6,
+                     hc_mult=4)
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((rows, 4, 3584), jnp.bfloat16), ((24, 4 * 3584), jnp.float32),
+        ((24,), jnp.float32), ((3,), jnp.float32))]
+    fusions = {}
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for name, fn in (("kernel", hc_mix), ("xla", hc_maps)):
+            comp = jax.jit(lambda *a, fn=fn: fn(spec, *a)).trace(
+                *avals).lower(lowering_platforms=("tpu",)).compile()
+            text = comp.as_text()
+            fusions[name] = text.count(" fusion(")
+            assert ("mxtpu_hc_mix" in text) == (name == "kernel")
+            assert comp.memory_analysis().temp_size_in_bytes < 4e6
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert fusions["kernel"] <= 6 and fusions["xla"] >= 60, fusions
